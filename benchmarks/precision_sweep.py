@@ -26,16 +26,14 @@ import numpy as np
 
 from benchmarks.common import emit, timeit
 from repro.core.mixtures import benchmark_mixture_16d
-from repro.kernels import autotune, ops
+from repro.kernels import autotune, interpret_mode, ops
 
 TIERS = ("f32", "bf16", "bf16x2")
 
 
-def pipeline_cells(ns=(1024, 2048), d: int = 16, seed: int = 0,
-                   interpret: bool | None = None):
+def pipeline_cells(ns=(1024, 2048), d: int = 16, seed: int = 0):
     """flash_sdkde per tier: wall ms + max rel err vs the f32 pipeline."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = interpret_mode()
     mix = benchmark_mixture_16d()
     key = jax.random.PRNGKey(seed)
     h = 0.5
@@ -45,8 +43,7 @@ def pipeline_cells(ns=(1024, 2048), d: int = 16, seed: int = 0,
         base = None
         for tier in TIERS:
             fn = lambda a, b: ops.flash_sdkde(  # noqa: E731
-                a, b, h, precision=tier, interpret=interpret
-            )
+                a, b, h, precision=tier)
             t = timeit(fn, x, y)
             dens = np.asarray(fn(x, y))
             if tier == "f32":

@@ -31,7 +31,7 @@ import numpy as np
 
 from benchmarks.common import emit, timeit
 from repro.core.mixtures import GaussianMixture
-from repro.kernels import autotune, ops, spatial
+from repro.kernels import autotune, interpret_mode, ops, spatial
 
 
 def clustered_mixture(d: int = 16, k: int = 64, spread: float = 4.0,
@@ -72,13 +72,13 @@ def _modeled_times(m, n, d, dense_blocks, pruned_blocks, occ,
 
 def smoke_cells(n: int = 8192, m: int = 1024, d: int = 8, h: float = 0.25,
                 seed: int = 0):
-    """Pruned kernels really run (interpret) and match dense at epsilon=0."""
+    """Pruned kernels really run and match dense at epsilon=0."""
     mix = clustered_mixture(d=d, k=16, spread=6.0, sigma=0.05, seed=seed)
     key = jax.random.PRNGKey(seed)
     x = mix.sample(key, n)
     y = mix.sample(jax.random.fold_in(key, 1), m)
     bm, bn = 64, 256
-    kw = dict(block_m=bm, block_n=bn, interpret=True)
+    kw = dict(block_m=bm, block_n=bn)
     t_dense = timeit(lambda: ops.flash_kde(x, y, h, prune="off", **kw))
     t_pruned = timeit(lambda: ops.flash_kde(x, y, h, prune=0.0, **kw))
     dense = np.asarray(ops.flash_kde(x, y, h, prune="off", **kw))
@@ -89,7 +89,7 @@ def smoke_cells(n: int = 8192, m: int = 1024, d: int = 8, h: float = 0.25,
          max_rel_err_eps0=f"{rel:.2e}", occupancy=round(occ, 4),
          wall_dense_ms=round(t_dense * 1e3, 1),
          wall_pruned_ms=round(t_pruned * 1e3, 1),
-         interpret=True)
+         interpret=interpret_mode())
     assert rel < 1e-5, f"epsilon=0 pruning deviated from dense: {rel}"
     return rel, occ
 
@@ -124,7 +124,7 @@ def acceptance_cells(n: int = 262144, m: int = 32768, d: int = 16,
     # re-resolve below will consult — which then *learns* that smaller
     # column tiles skip more (the autotuner's expected-occupancy term)
     ops.flash_kde(x, y, h, block_m=dense_blocks[0], block_n=dense_blocks[1],
-                  interpret=True, prune=0.0, seed=seed)
+                  prune=0.0, seed=seed)
     bm, bn = autotune.resolve_blocks("auto", "auto", m, n, d,
                                      precision="f32", measure=False,
                                      pruned=True)
@@ -147,9 +147,9 @@ def acceptance_cells(n: int = 262144, m: int = 32768, d: int = 16,
     # anchors: dense kernel (at its own tiles) and the exact-mode run
     dense_out = np.asarray(ops.flash_kde(
         x, yq, h, block_m=dense_blocks[0], block_n=dense_blocks[1],
-        interpret=True, prune="off"))
+        prune="off"))
     base = np.asarray(ops.flash_kde(x, yq, h, block_m=bm, block_n=bn,
-                                    interpret=True, prune=0.0, seed=seed))
+                                    prune=0.0, seed=seed))
     noise = float(np.max(np.abs(base - dense_out)
                          / (np.abs(dense_out) + 1e-30)))
 
@@ -161,7 +161,7 @@ def acceptance_cells(n: int = 262144, m: int = 32768, d: int = 16,
         occ = vl.occupancy
         cert = float(jnp.max(tm.err_bound))
         got = np.asarray(ops.flash_kde(x, yq, h, block_m=bm, block_n=bn,
-                                       interpret=True, prune=eps, seed=seed))
+                                       prune=eps, seed=seed))
         rel_err = float(np.max(np.abs(got - base) / (np.abs(base) + 1e-30)))
         dense_s, pruned_s = _modeled_times(m, n, d, dense_blocks, (bm, bn),
                                            occ)

@@ -38,6 +38,7 @@ from benchmarks import (
     table1_methods,
 )
 from repro import obs
+from repro.launch import enable_compile_cache
 
 BENCH_JSON = "BENCH_flash.json"
 
@@ -62,6 +63,7 @@ def _run(name: str, desc: str, fn, *args, **kw) -> None:
 
 
 def main() -> None:
+    enable_compile_cache()
     t0 = time.time()
     print("# Flash-SD-KDE benchmark suite (CPU-scaled; see EXPERIMENTS.md)")
     _run("fig1", "16-D runtime, naive vs GEMM vs flash (paper Fig. 1)",

@@ -44,7 +44,7 @@ def main(
 
     for backend in backends:
         cfg = ServeConfig(
-            backend=backend, method=method, interpret=True,
+            backend=backend, method=method,
             block_m=min(128, max(8, min(batch_sizes))),
             block_n=min(512, n),
             precision=precision,

@@ -51,7 +51,7 @@ def smoke(
     h = 0.5
 
     cfg = ServeConfig(
-        backend="pallas", method="sdkde", interpret=True,
+        backend="pallas", method="sdkde",
         block_m=8, block_n=min(512, n), min_batch=64, max_batch=256,
         stream=True, staleness_budget=staleness_budget,
     )

@@ -23,7 +23,7 @@ def main():
     print(f"SD-KDE: h={float(sdkde.h):.4f}  "
           f"density[:4]={[f'{v:.3e}' for v in density[:4]]}")
 
-    # --- same API, Pallas kernel backend (interpret=True on CPU) ---------
+    # --- same API, Pallas kernel backend (interpreted on CPU) -----------
     flash = SDKDE(config=EstimatorConfig(backend="pallas", block_m=128,
                                          block_n=512)).fit(x_train[:2048])
     print(f"Pallas backend density[0]={float(flash(x_query[:8])[0]):.3e}")

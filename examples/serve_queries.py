@@ -15,7 +15,7 @@ def main():
 
     # One engine can serve many datasets; each is debiased exactly once.
     eng = ServeEngine(ServeConfig(backend="pallas", method="sdkde",
-                                  interpret=True, block_m=32, block_n=256,
+                                  block_m=32, block_n=256,
                                   min_batch=32, max_batch=256))
     eng.register("tenant-a", mix.sample(key, 2048))
     eng.register("tenant-b", mix.sample(jax.random.fold_in(key, 1), 1024))

@@ -32,7 +32,7 @@ class EstimatorConfig:
     block: int = 1024            # streaming column-block size (jnp backend)
     block_m: "int | str" = 128   # Pallas row tile (int or "auto" = autotuned)
     block_n: "int | str" = 512   # Pallas column tile (int or "auto")
-    interpret: bool = True       # Pallas interpret mode (CPU validation)
+    interpret: Optional[bool] = None  # Pallas interpret mode (None = platform)
     score_h: Optional[float] = None  # score-estimation bandwidth (None = h)
     dtype: jnp.dtype = jnp.float32
     precision: str = "f32"       # Pallas GEMM-operand tier (kernels/precision)

@@ -27,6 +27,7 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 
 from repro.core.bandwidth import gaussian_norm_const
 
@@ -54,7 +55,7 @@ def sqdist(x: jnp.ndarray, y: jnp.ndarray) -> jnp.ndarray:
     """
     xn = jnp.sum(x * x, axis=-1)[:, None]
     yn = jnp.sum(y * y, axis=-1)[None, :]
-    g = x @ y.T
+    g = jnp.matmul(x, y.T, precision=lax.Precision.HIGHEST)
     return jnp.maximum(xn + yn - 2.0 * g, 0.0)
 
 
@@ -145,7 +146,8 @@ def score_stats(
         sq = sqdist(x_eval, xblk)                       # (m, block) via GEMM
         phi = _phi(sq, h)
         s0 = s0 + jnp.sum(phi, axis=1)                  # Σ_j φ_ij
-        s1 = s1 + phi @ xblk                            # Φ X   (MXU GEMM)
+        s1 = s1 + jnp.matmul(phi, xblk,                 # Φ X   (MXU GEMM)
+                             precision=lax.Precision.HIGHEST)
         return s0, s1
 
     init = (jnp.zeros(m), jnp.zeros((m, d)))

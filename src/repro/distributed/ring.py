@@ -30,7 +30,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core.bandwidth import gaussian_norm_const
 from repro.core.kde import PAD_VALUE, sqdist
-from repro.distributed import compat
 
 
 def default_mesh(data_axis: str = "data") -> Mesh:
@@ -44,13 +43,37 @@ def default_mesh(data_axis: str = "data") -> Mesh:
     return Mesh(np.asarray(jax.devices()), (data_axis,))
 
 
+#: Column sub-block a device consumes at once: the (rows × CHUNK) φ tile
+#: is the largest intermediate, so a shard of 256k rows stays at 1 GiB
+#: whatever the shard's column count.
+CHUNK = 1024
+
+
+def chunked_consume(rows, cols, chunk: int, body, acc):
+    """Stream ``cols`` in ``chunk`` blocks: acc = body(acc, rows, col_blk)."""
+    n = cols.shape[0]
+    if n <= chunk:
+        return body(acc, rows, cols)
+    nb = n // chunk
+    main, tail = cols[: nb * chunk], cols[nb * chunk:]
+    blocks = main.reshape(nb, chunk, cols.shape[-1])
+
+    def step(a, blk):
+        return body(a, rows, blk), None
+
+    acc, _ = lax.scan(step, acc, blocks)
+    if tail.shape[0]:
+        acc = body(acc, rows, tail)
+    return acc
+
+
 def _ring_perm(size: int):
     return [(i, (i + 1) % size) for i in range(size)]
 
 
 def _pvary(tree, axes: tuple):
     """Mark zero-init carries as varying over the ring axes (shard_map vma)."""
-    return jax.tree.map(lambda a: compat.pvary(a, axes), tree)
+    return jax.tree.map(lambda a: lax.pcast(a, axes, to="varying"), tree)
 
 
 def _ring_scan(
@@ -129,11 +152,14 @@ def ring_score_stats(
     spec = P(axes, None)
 
     def local(x_rows):
-        def consume(acc, cols):
+        def body(acc, rows, cols):
             s0, s1 = acc
-            sq = sqdist(x_rows, cols)
-            phi = _phi(sq, h)
-            return s0 + jnp.sum(phi, axis=1), s1 + phi @ cols
+            phi = _phi(sqdist(rows, cols), h)
+            return s0 + jnp.sum(phi, axis=1), s1 + jnp.matmul(
+                phi, cols, precision=lax.Precision.HIGHEST)
+
+        def consume(acc, cols):
+            return chunked_consume(x_rows, cols, CHUNK, body, acc)
 
         init = (
             jnp.zeros(x_rows.shape[0], jnp.float32),
@@ -141,7 +167,7 @@ def ring_score_stats(
         )
         return _ring_scan(x_rows, init, consume, mesh, data_axis, pod_axis)
 
-    return compat.shard_map(
+    return jax.shard_map(
         local, mesh=mesh, in_specs=(spec,), out_specs=(P(axes), spec)
     )(x)
 
@@ -188,14 +214,16 @@ def _ring_eval(
     d = x.shape[-1]
 
     def local(y_rows, x_cols):
+        def body(acc, rows, cols):
+            return acc + jnp.sum(weight_fn(sqdist(rows, cols), h, d), axis=1)
+
         def consume(acc, cols):
-            sq = sqdist(y_rows, cols)
-            return acc + jnp.sum(weight_fn(sq, h, d), axis=1)
+            return chunked_consume(y_rows, cols, CHUNK, body, acc)
 
         init = jnp.zeros(y_rows.shape[0], jnp.float32)
         return _ring_scan(x_cols, init, consume, mesh, data_axis, pod_axis)
 
-    sums = compat.shard_map(
+    sums = jax.shard_map(
         local, mesh=mesh, in_specs=(spec, spec), out_specs=P(axes)
     )(y, x)
     h = jnp.asarray(h, jnp.float32)

@@ -41,29 +41,11 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core.bandwidth import gaussian_norm_const
 from repro.core.kde import PAD_VALUE, sqdist
-from repro.distributed import compat
+from repro.distributed.ring import chunked_consume as _chunked_consume
 
 
 def _phi(sq, h):
     return jnp.exp(-sq / (2.0 * h * h))
-
-
-def _chunked_consume(rows, cols, chunk: int, body, acc):
-    """Stream ``cols`` in ``chunk`` blocks: acc = body(acc, rows, col_blk)."""
-    n = cols.shape[0]
-    if n <= chunk:
-        return body(acc, rows, cols)
-    nb = n // chunk
-    main, tail = cols[: nb * chunk], cols[nb * chunk:]
-    blocks = main.reshape(nb, chunk, cols.shape[-1])
-
-    def step(a, blk):
-        return body(a, rows, blk), None
-
-    acc, _ = lax.scan(step, acc, blocks)
-    if tail.shape[0]:
-        acc = body(acc, rows, tail)
-    return acc
 
 
 def _axes(mesh: Mesh):
@@ -87,7 +69,8 @@ def ring2d_score_stats(
         def body(acc, r, blk):
             s0, s1 = acc
             phi = _phi(sqdist(r, blk), h)
-            return s0 + jnp.sum(phi, axis=1), s1 + phi @ blk
+            return s0 + jnp.sum(phi, axis=1), s1 + jnp.matmul(
+                phi, blk, precision=lax.Precision.HIGHEST)
 
         init = (
             jnp.zeros(rows.shape[0], jnp.float32),
@@ -96,7 +79,7 @@ def ring2d_score_stats(
         s0, s1 = _chunked_consume(rows, cols, chunk, body, init)
         return lax.psum(s0, col_axes), lax.psum(s1, col_axes)
 
-    return compat.shard_map(
+    return jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(P("model", None), P(col_axes, None)),
@@ -130,7 +113,7 @@ def ring2d_kde_sums(
         acc = _chunked_consume(rows, cols, chunk, body, init)
         return lax.psum(acc, col_axes)
 
-    return compat.shard_map(
+    return jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(P("model", None), P(col_axes, None)),

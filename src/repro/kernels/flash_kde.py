@@ -19,11 +19,13 @@ Gram round-off can never turn a self-distance into exp overflow.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import compiler_params, launch_interpret
 from repro.kernels.precision import dot_f32, gram_compensated
 
 
@@ -65,7 +67,7 @@ def flash_kde_pallas(
     *,
     block_m: int = 128,
     block_n: int = 512,
-    interpret: bool = False,
+    interpret: Optional[bool] = None,
 ) -> jnp.ndarray:
     """Raw kernel launch; returns unnormalized sums (m, 1) f32."""
     m, d = y.shape
@@ -94,5 +96,6 @@ def flash_kde_pallas(
         in_specs=in_specs,
         out_specs=pl.BlockSpec((block_m, 1), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((m, 1), jnp.float32),
-        interpret=interpret,
+        interpret=launch_interpret(interpret),
+        compiler_params=compiler_params(),
     )(*args)

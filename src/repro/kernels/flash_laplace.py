@@ -21,11 +21,13 @@ exponential, and accumulators stay f32 at every tier.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import compiler_params, launch_interpret
 from repro.kernels.precision import dot_f32, gram_compensated
 
 
@@ -117,7 +119,8 @@ def _launch(kernels, y, nrm_y, xt, nrm_x, inv2h2, y_lo, xt_lo,
         in_specs=in_specs,
         out_specs=pl.BlockSpec((block_m, 1), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((m, 1), jnp.float32),
-        interpret=interpret,
+        interpret=launch_interpret(interpret),
+        compiler_params=compiler_params(),
     )(*args)
 
 
@@ -126,7 +129,7 @@ def _launch(kernels, y, nrm_y, xt, nrm_x, inv2h2, y_lo, xt_lo,
 )
 def flash_laplace_pallas(y, nrm_y, xt, nrm_x, inv2h2, y_lo=None, xt_lo=None,
                          *, block_m: int = 128, block_n: int = 512,
-                         interpret: bool = False):
+                         interpret: Optional[bool] = None):
     """Fused Laplace-corrected sums (m, 1) f32 — one quadratic pass."""
     return _launch(_LAPLACE, y, nrm_y, xt, nrm_x, inv2h2, y_lo, xt_lo,
                    block_m, block_n, interpret)
@@ -137,7 +140,7 @@ def flash_laplace_pallas(y, nrm_y, xt, nrm_x, inv2h2, y_lo=None, xt_lo=None,
 )
 def sq_moment_pallas(y, nrm_y, xt, nrm_x, inv2h2, y_lo=None, xt_lo=None,
                      *, block_m: int = 128, block_n: int = 512,
-                     interpret: bool = False):
+                     interpret: Optional[bool] = None):
     """Second pass of the non-fused baseline: Σ φ·sqd (m, 1) f32."""
     return _launch(_SQ_MOMENT, y, nrm_y, xt, nrm_x, inv2h2, y_lo, xt_lo,
                    block_m, block_n, interpret)

@@ -28,12 +28,14 @@ bodies reuse the dense kernels' compensated-Gram helpers.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import compiler_params, launch_interpret
 from repro.kernels.flash_laplace import _sq_tile
 from repro.kernels.precision import weighted_accum
 
@@ -72,6 +74,30 @@ def _make_eval_kernel(compensated: bool, laplace: bool):
 _EVAL = {(c, l): _make_eval_kernel(c, l)
          for c in (False, True) for l in (False, True)}
 
+#: Bytes of scalar-prefetched tile map one launch may hold.  The prefetched
+#: operands live in SMEM (1 MiB on a v5e core, shared with Mosaic's own
+#: scalars); a visit list that wide — a 1M-point set whose row tiles each
+#: visit hundreds of column tiles — runs as several launches over groups
+#: of row tiles instead.
+SMEM_TILE_MAP_BYTES = 256 * 1024
+
+
+def _by_row_groups(launch, counts, tile_map, rows, block_m: int):
+    """``launch(counts, tile_map, *rows)`` over row-tile groups whose tile
+    map fits :data:`SMEM_TILE_MAP_BYTES`; ``rows`` are the row-tiled
+    operands (None passes through), outputs concatenate by row."""
+    mt, visits = tile_map.shape
+    per = max(1, SMEM_TILE_MAP_BYTES // (4 * visits))
+    if per >= mt:
+        return launch(counts, tile_map, *rows)
+    outs = []
+    for g in range(0, mt, per):
+        e = min(g + per, mt)
+        part = [None if a is None else a[g * block_m:e * block_m]
+                for a in rows]
+        outs.append(launch(counts[g:e], tile_map[g:e], *part))
+    return jnp.concatenate(outs)
+
 
 @functools.partial(
     jax.jit,
@@ -92,7 +118,7 @@ def flash_kde_pallas_pruned(
     block_m: int = 128,
     block_n: int = 512,
     max_visits: int = 1,
-    interpret: bool = False,
+    interpret: Optional[bool] = None,
     laplace: bool = False,
 ) -> jnp.ndarray:
     """Pruned KDE / fused-Laplace sums (m, 1) f32 (unnormalized)."""
@@ -109,28 +135,28 @@ def flash_kde_pallas_pruned(
     col = pl.BlockSpec((d, block_n), lambda i, k, cnt, tm: (0, tm[i, k]))
     nrm_col = pl.BlockSpec((1, block_n), lambda i, k, cnt, tm: (0, tm[i, k]))
     scalar = pl.BlockSpec((1, 1), lambda i, k, cnt, tm: (0, 0))
+    out = pl.BlockSpec((block_m, 1), lambda i, k, cnt, tm: (i, 0))
+    kernel = _EVAL[(y_lo is not None, laplace)]
 
-    if y_lo is None:
-        kernel = _EVAL[(False, laplace)]
-        in_specs = [row, nrm_row, col, nrm_col, scalar]
-        args = (y, nrm_y, xt, nrm_x, inv2h2)
-    else:
-        kernel = _EVAL[(True, laplace)]
-        in_specs = [row, row, nrm_row, col, col, nrm_col, scalar]
-        args = (y, y_lo, nrm_y, xt, xt_lo, nrm_x, inv2h2)
+    def launch(cnt, tm, y, nrm_y, y_lo):
+        if y_lo is None:
+            in_specs = [row, nrm_row, col, nrm_col, scalar]
+            args = (y, nrm_y, xt, nrm_x, inv2h2)
+        else:
+            in_specs = [row, row, nrm_row, col, col, nrm_col, scalar]
+            args = (y, y_lo, nrm_y, xt, xt_lo, nrm_x, inv2h2)
+        return pl.pallas_call(
+            kernel,
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=2, grid=tm.shape, in_specs=in_specs,
+                out_specs=out),
+            out_shape=jax.ShapeDtypeStruct((y.shape[0], 1), jnp.float32),
+            interpret=launch_interpret(interpret),
+            compiler_params=compiler_params(),
+        )(cnt, tm, *args)
 
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(mt, max_visits),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((block_m, 1), lambda i, k, cnt, tm: (i, 0)),
-    )
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((m, 1), jnp.float32),
-        interpret=interpret,
-    )(counts, tile_map, *args)
+    return _by_row_groups(launch, counts, tile_map, (y, nrm_y, y_lo),
+                          block_m)
 
 
 def _make_score_kernel(compensated: bool):
@@ -186,7 +212,7 @@ def flash_score_pallas_pruned(
     block_m: int = 128,
     block_n: int = 512,
     max_visits: int = 1,
-    interpret: bool = False,
+    interpret: Optional[bool] = None,
 ) -> jnp.ndarray:
     """Pruned score statistics S1aug (n, d+1) f32."""
     n, d = x.shape
@@ -204,30 +230,30 @@ def flash_score_pallas_pruned(
     aug = pl.BlockSpec((block_n, d + 1), lambda i, k, cnt, tm: (tm[i, k], 0))
     nrm_col = pl.BlockSpec((1, block_n), lambda i, k, cnt, tm: (0, tm[i, k]))
     scalar = pl.BlockSpec((1, 1), lambda i, k, cnt, tm: (0, 0))
-
+    out = pl.BlockSpec((block_m, d + 1), lambda i, k, cnt, tm: (i, 0))
+    kernel = _SCORE[x_lo is not None]
     nrm_bcast = jnp.broadcast_to(nrm.reshape(1, -1), (1, n))
-    if x_lo is None:
-        kernel = _SCORE[False]
-        in_specs = [row, nrm_row, col, aug, nrm_col, scalar]
-        args = (x, nrm, xt, xaug, nrm_bcast, inv2h2)
-    else:
-        kernel = _SCORE[True]
-        in_specs = [row, row, nrm_row, col, col, aug, aug, nrm_col, scalar]
-        args = (x, x_lo, nrm, xt, xt_lo, xaug, xaug_lo, nrm_bcast, inv2h2)
 
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(mt, max_visits),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((block_m, d + 1),
-                               lambda i, k, cnt, tm: (i, 0)),
-    )
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((n, d + 1), jnp.float32),
-        interpret=interpret,
-    )(counts, tile_map, *args)
+    def launch(cnt, tm, x, nrm, x_lo):
+        if x_lo is None:
+            in_specs = [row, nrm_row, col, aug, nrm_col, scalar]
+            args = (x, nrm, xt, xaug, nrm_bcast, inv2h2)
+        else:
+            in_specs = [row, row, nrm_row, col, col, aug, aug, nrm_col,
+                        scalar]
+            args = (x, x_lo, nrm, xt, xt_lo, xaug, xaug_lo, nrm_bcast,
+                    inv2h2)
+        return pl.pallas_call(
+            kernel,
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=2, grid=tm.shape, in_specs=in_specs,
+                out_specs=out),
+            out_shape=jax.ShapeDtypeStruct((x.shape[0], d + 1), jnp.float32),
+            interpret=launch_interpret(interpret),
+            compiler_params=compiler_params(),
+        )(cnt, tm, *args)
+
+    return _by_row_groups(launch, counts, tile_map, (x, nrm, x_lo), block_m)
 
 
 __all__ = ["flash_kde_pallas_pruned", "flash_score_pallas_pruned"]

@@ -36,10 +36,13 @@ certificates into reach at D ≈ 8192.
 ``groups`` independent batches; the spread of the per-group estimates
 gives a standard error, and the certified relative band is
 
-    band(y) = Z · stderr(y) / max(p̂(y), TAIL_FRAC · p_scale)
+    band(y) = E(y) / max(p̂(y) − E(y), TAIL_FRAC · p_scale),
+    E(y)    = Z · stderr(y)
 
 with the same tail floor the realized-error metric uses (``p_scale`` is a
-high-percentile train density fitted once).  The serving cascade
+high-percentile train density fitted once).  The denominator is the least
+density consistent with the absolute band E, so wherever ``|p̂ − p| ≤ E``
+the band bounds ``|p̂ − p| / max(p, floor)`` — the realized error — too.  The serving cascade
 (``serve/cascade.py``) answers a query at this tier only when ``band``
 fits the request's accuracy target, so the band being *honest* — never
 exceeded by realized error — is the acceptance-gated contract
@@ -58,6 +61,7 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 import numpy as np
 
 from repro.core.bandwidth import gaussian_norm_const
@@ -232,8 +236,11 @@ def fit(points, h: float, *, n_features: int = DEFAULT_FEATURES,
     # accumulation: phase rounding perturbs the sums orders of magnitude
     # below the pilot residuals the band measures, and the XLA path is
     # what the paper's "fit is one featurization GEMM" story models
-    feat = jax.jit(lambda xb, wt: (jnp.cos(xb @ wt).sum(0),
-                                   jnp.sin(xb @ wt).sum(0)))
+    def feat_sums(xb, wt):
+        t = jnp.matmul(xb, wt, precision=lax.Precision.HIGHEST)
+        return jnp.cos(t).sum(0), jnp.sin(t).sum(0)
+
+    feat = jax.jit(feat_sums)
     x32 = jnp.asarray(x, jnp.float32)
     w32 = jnp.asarray(w.T, jnp.float32)
     z_cos = np.zeros(n_half)
@@ -380,9 +387,12 @@ def eval_density(serving: RFFServing, y: jnp.ndarray, *,
 
     p_g = (s_pilot[:, None] + per_group) / serving.norm
     p_hat = jnp.mean(p_g, axis=1)
-    stderr = jnp.std(p_g, axis=1, ddof=1) / np.sqrt(g)
-    denom = jnp.maximum(jnp.abs(p_hat), serving.p_floor)
-    band = z * stderr / denom
+    err = z * jnp.std(p_g, axis=1, ddof=1) / np.sqrt(g)   # absolute band
+    # the realized error is relative to the TRUE density, which is at least
+    # p̂ − err: dividing by p̂ itself understates the band wherever p̂
+    # overestimates (a tail query read 4x too high broke it by 1.7)
+    denom = jnp.maximum(p_hat - err, serving.p_floor)
+    band = err / denom
     return jnp.maximum(p_hat, 0.0), band
 
 

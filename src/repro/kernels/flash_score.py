@@ -34,11 +34,13 @@ and the accumulator stay f32 at every tier.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import compiler_params, launch_interpret
 from repro.kernels.precision import dot_f32, gram_compensated, weighted_accum
 
 
@@ -87,7 +89,7 @@ def flash_score_pallas(
     *,
     block_m: int = 128,
     block_n: int = 512,
-    interpret: bool = False,
+    interpret: Optional[bool] = None,
 ) -> jnp.ndarray:
     """Raw kernel launch; returns S1aug (n, d+1) f32.  See ops.flash_score_stats
     for the padded/normalized public wrapper."""
@@ -121,5 +123,6 @@ def flash_score_pallas(
         in_specs=in_specs,
         out_specs=pl.BlockSpec((block_m, d + 1), lambda m, j: (m, 0)),
         out_shape=jax.ShapeDtypeStruct((n, d + 1), jnp.float32),
-        interpret=interpret,
+        interpret=launch_interpret(interpret),
+        compiler_params=compiler_params(),
     )(*args)

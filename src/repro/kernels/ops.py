@@ -117,7 +117,7 @@ def _traced(*arrays) -> bool:
     under tracing the public wrappers silently fall back to dense — the
     pre-pruning behavior, and the only one that can stay a single jaxpr.
     """
-    return any(isinstance(a, jax.core.Tracer) for a in arrays)
+    return not all(jax.core.is_concrete(a) for a in arrays)
 
 
 # One-shot wrappers amortize the spatial prep across repeated calls on the
@@ -262,7 +262,7 @@ def _flash_score_stats_dense(
     precision: str = "f32",
     block_m=128,
     block_n=512,
-    interpret: bool = False,
+    interpret: Optional[bool] = None,
 ):
     n, d = x.shape
     mult = math.lcm(block_m, block_n)
@@ -314,7 +314,7 @@ def _score_stats_pruned(
     precision: str,
     block_m: int,
     block_n: int,
-    interpret: bool,
+    interpret: Optional[bool],
 ):
     """Pruned score pass; returns (S0, S1) in ``x``'s original row order.
 
@@ -390,7 +390,7 @@ def flash_score_stats(
     precision: str = "f32",
     block_m="auto",
     block_n="auto",
-    interpret: bool = False,
+    interpret: Optional[bool] = None,
     prune: PruneArg = "auto",
     seed: int = 0,
     plan=None,
@@ -437,7 +437,7 @@ def flash_sdkde_shift(
     precision: str = "f32",
     block_m="auto",
     block_n="auto",
-    interpret: bool = False,
+    interpret: Optional[bool] = None,
     prune: PruneArg = "auto",
     seed: int = 0,
     plan=None,
@@ -486,7 +486,7 @@ def _flash_eval_dense(
     precision: str = "f32",
     block_m=128,
     block_n=512,
-    interpret: bool = False,
+    interpret: Optional[bool] = None,
     laplace: bool = False,
 ) -> jnp.ndarray:
     """Dense KDE / fused-Laplace evaluation (normalized densities)."""
@@ -511,7 +511,7 @@ def flash_kde(
     precision: str = "f32",
     block_m="auto",
     block_n="auto",
-    interpret: bool = False,
+    interpret: Optional[bool] = None,
     prune: PruneArg = "auto",
     seed: int = 0,
     plan=None,
@@ -554,7 +554,7 @@ def flash_laplace_kde(
     precision: str = "f32",
     block_m="auto",
     block_n="auto",
-    interpret: bool = False,
+    interpret: Optional[bool] = None,
     prune: PruneArg = "auto",
     seed: int = 0,
     plan=None,
@@ -598,7 +598,7 @@ def laplace_kde_nonfused(
     precision: str = "f32",
     block_m="auto",
     block_n="auto",
-    interpret: bool = False,
+    interpret: Optional[bool] = None,
 ) -> jnp.ndarray:
     """Non-fused Laplace baseline: two quadratic kernel launches (Fig. 4).
 
@@ -821,7 +821,7 @@ def _pruned_eval_sums(
     precision: str,
     block_m: int,
     block_n: int,
-    interpret: bool,
+    interpret: Optional[bool],
     laplace: bool,
     n_real: Optional[int] = None,
 ) -> jnp.ndarray:
@@ -894,7 +894,7 @@ def _flash_kde_prepared_dense(
     precision: str = "f32",
     block_m=128,
     block_n=512,
-    interpret: bool = False,
+    interpret: Optional[bool] = None,
     laplace: bool = False,
 ) -> jnp.ndarray:
     y_hi, y_lo, nrm_y, _ = _cast_queries(yp, precision)
@@ -916,7 +916,7 @@ def flash_kde_prepared(
     precision: str = "f32",
     block_m="auto",
     block_n="auto",
-    interpret: bool = False,
+    interpret: Optional[bool] = None,
     laplace: bool = False,
     prune: PruneArg = "off",
     columns: Optional[TrainColumns] = None,
@@ -990,7 +990,7 @@ def flash_sdkde(
     precision: str = "f32",
     block_m="auto",
     block_n="auto",
-    interpret: bool = False,
+    interpret: Optional[bool] = None,
     prune: PruneArg = "auto",
     seed: int = 0,
     plan=None,

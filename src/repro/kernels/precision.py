@@ -6,7 +6,10 @@ through the systolic array.  SD-KDE's statistical guarantees survive
 reduced-precision *pairwise distances* as long as the sensitive scalar work
 stays f32, so the kernels expose three operand tiers:
 
-  * ``f32``    — operands as given (the seed behavior, full precision);
+  * ``f32``    — operands as given, multiplied at
+                 ``lax.Precision.HIGHEST`` (full f32: on a TPU the default
+                 f32 matmul precision is a single bf16 pass, so the tier
+                 asks for f32 explicitly);
   * ``bf16``   — Gram / φ@[X|1] operands cast to bfloat16 (~1e-2 relative
                  on the densities, full MXU rate, half the operand HBM
                  traffic and VMEM footprint);
@@ -17,8 +20,8 @@ stays f32, so the kernels expose three operand tiers:
                  ~16 mantissa bits → within 1e-4 of the f32 reference at 4×
                  the bf16 GEMM count — the same family as XLA's own
                  f32-as-bf16 emulation (``BF16_3X``/``BF16_6X`` passes),
-                 sitting between them, and still cheaper than the 6-pass
-                 exact lowering a full-f32 MXU GEMM costs.
+                 sitting between them, and still cheaper than the
+                 multi-pass lowering the f32 tier's HIGHEST GEMM costs.
 
 Invariant across every tier (tested in tests/test_precision_autotune.py):
 squared norms, ``sq = ‖y‖² + ‖x‖² − 2g``, the exponential, the Laplace
@@ -36,6 +39,7 @@ from __future__ import annotations
 from typing import Optional, Tuple
 
 import jax.numpy as jnp
+from jax import lax
 
 PRECISIONS = ("f32", "bf16", "bf16x2")
 Precision = str  # one of PRECISIONS; plain str keeps it jit-static-friendly
@@ -98,7 +102,16 @@ def reconstruct(hi: jnp.ndarray, lo: Optional[jnp.ndarray]) -> jnp.ndarray:
 
 
 def dot_f32(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
-    return jnp.dot(a, b, preferred_element_type=jnp.float32)
+    """GEMM with f32 accumulation; f32 operands multiply at full f32.
+
+    Without an explicit precision, an f32×f32 product on a TPU may run as
+    one bf16 pass (XLA's default), which turns ``‖y‖²+‖x‖²−2y·x`` into
+    cancellation noise.  bf16 operands (the reduced tiers) are exact in
+    one pass and keep the default.
+    """
+    f32 = a.dtype == jnp.float32 and b.dtype == jnp.float32
+    return jnp.dot(a, b, preferred_element_type=jnp.float32,
+                   precision=lax.Precision.HIGHEST if f32 else None)
 
 
 def gram_compensated(

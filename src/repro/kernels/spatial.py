@@ -61,6 +61,7 @@ from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 import numpy as np
 
 PAD_VALUE = 1.0e6   # matches ops.PAD_VALUE — kernel weight underflows to 0
@@ -132,7 +133,8 @@ class VisitLists(NamedTuple):
 def _sqdist(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
     an = jnp.sum(a * a, axis=-1)[:, None]
     bn = jnp.sum(b * b, axis=-1)[None, :]
-    return jnp.maximum(an + bn - 2.0 * (a @ b.T), 0.0)
+    g = jnp.matmul(a, b.T, precision=lax.Precision.HIGHEST)
+    return jnp.maximum(an + bn - 2.0 * g, 0.0)
 
 
 def default_n_clusters(n: int) -> int:
@@ -155,7 +157,8 @@ def _kmeans_fit(x: jnp.ndarray, key: jnp.ndarray, *, k: int,
         lab = jnp.argmin(_sqdist(x, c), axis=1)
         one = jax.nn.one_hot(lab, k, dtype=jnp.float32)     # (n, k)
         cnt = jnp.sum(one, axis=0)[:, None]                 # (k, 1)
-        sums = one.T @ x                                    # (k, d)
+        sums = jnp.matmul(one.T, x,                         # (k, d)
+                          precision=lax.Precision.HIGHEST)
         c = jnp.where(cnt > 0, sums / jnp.maximum(cnt, 1.0), c)
     return c
 
@@ -463,7 +466,12 @@ def tile_map(
         w = jnp.maximum(1.0, col_meta.max_abs)[None, :]
     else:
         w = 1.0
-    bound = w * jnp.exp(-arg)                    # per-point, per (i, j)
+    # per-point, per (i, j).  XLA flushes a subnormal exp to 0.0, but a pair
+    # below the underflow threshold can still carry subnormal mass: floor
+    # the bound at the smallest normal f32 there so the certificate holds.
+    bound = jnp.maximum(
+        w * jnp.exp(-arg),
+        jnp.where(arg < UNDERFLOW_ARG, jnp.finfo(jnp.float32).tiny, 0.0))
     skip = (arg >= UNDERFLOW_ARG) | (col_meta.counts == 0)[None, :]
     skip = skip | ((eps > 0.0) & (bound <= eps))
     keep = ~skip

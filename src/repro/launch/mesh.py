@@ -16,7 +16,15 @@ all-reduces for the nested (pod, data) spec).
 
 from __future__ import annotations
 
-from repro.distributed.compat import make_auto_mesh
+import jax
+from jax.sharding import AxisType
+
+
+def make_auto_mesh(shape, axes, devices=None):
+    """``jax.make_mesh`` with every axis marked ``AxisType.Auto``."""
+    kw = {} if devices is None else {"devices": devices}
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes),
+                         **kw)
 
 
 def make_production_mesh(*, multi_pod: bool = False):

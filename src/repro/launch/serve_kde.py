@@ -21,6 +21,7 @@ import numpy as np
 from repro import obs
 from repro.core import kde as ref
 from repro.core.mixtures import mixture_for_dim
+from repro.launch import enable_compile_cache
 from repro.serve import QueryRequest, ServeConfig, ServeEngine
 
 
@@ -133,6 +134,7 @@ def main():
                          "(repro.obs; also enables jax.profiler "
                          "annotations on real devices)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.trace:
         obs.configure(trace=True)
@@ -161,7 +163,7 @@ def main():
         if v is not None:
             knobs[name] = v
     cfg = ServeConfig(
-        method=args.method, interpret=True,
+        method=args.method,
         min_batch=args.min_batch, max_batch=args.max_batch,
         stream=args.stream, plan=args.plan,
         accuracy_target=args.accuracy_target, **knobs,
@@ -297,7 +299,8 @@ def main():
         # registered one — the whole point of the updates
         x_ref = (eng.registry.get("traffic").stream.x
                  if args.stream else x)
-        want = np.asarray(ref_fn(x_ref, yv, prep.h, block=1024))
+        with jax.default_matmul_precision("highest"):
+            want = np.asarray(ref_fn(x_ref, yv, prep.h, block=1024))
         cascaded = vans.rff_hits or rff_hits
         if cascaded:
             # cascade verification: the certified per-row bound must
@@ -588,7 +591,8 @@ def _run_resilient(args, cfg, x, pool) -> None:
                                      deadline_s=60.0))
         ref_fn = {"kde": ref.kde_eval, "sdkde": ref.sdkde_eval,
                   "laplace": ref.laplace_kde_eval}[args.method]
-        want = np.asarray(ref_fn(x, yv, table.h, block=1024))
+        with jax.default_matmul_precision("highest"):
+            want = np.asarray(ref_fn(x, yv, table.h, block=1024))
         rtol = {"f32": 1e-5, "bf16": 5e-2,
                 "bf16x2": 5e-4}[cfg.exact_precision]
         np.testing.assert_allclose(

@@ -88,7 +88,7 @@ class ModelConfig:
     kv_quant: bool = False
     # Mamba path: use the chunked Pallas selective-scan kernel
     # (kernels/selective_scan.py) instead of the XLA associative scan.
-    # interpret=True on CPU (validation); compiled on TPU.
+    # interpreted on CPU (validation); compiled on TPU.
     ssm_kernel: bool = False
 
     @property

@@ -38,9 +38,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.distributed.compat import axis_size as _axis_size
-from repro.distributed.compat import optimization_barrier as _opt_barrier
-from repro.distributed.compat import shard_map as _shard_map
 from repro.models.common import ModelConfig
 from repro.models.layers import _act
 
@@ -132,7 +129,7 @@ def _moe_ffn_sharded(x, lp, cfg: ModelConfig, mesh, dp):
                     # optimization_barrier: stops XLA from hoisting the
                     # einsum's bf16→f32 convert ABOVE this gather, which
                     # would double the wire bytes (measured §Perf kimi#2).
-                    w_loc[name] = _opt_barrier(
+                    w_loc[name] = lax.optimization_barrier(
                         lax.all_gather(
                             w_loc[name], "data", axis=axis, tiled=True
                         )
@@ -140,7 +137,7 @@ def _moe_ffn_sharded(x, lp, cfg: ModelConfig, mesh, dp):
         out, aux = _moe_ffn_manual(x_loc, w_loc, cfg, ep=ep)
         return out, lax.pmean(aux, tuple(manual))
 
-    return _shard_map(
+    return jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(P(dp, None), wspecs),
@@ -176,7 +173,7 @@ def _moe_ffn_stationary(x, lp, cfg: ModelConfig, mesh):
                                    psum_axes=reduce_axes)
         return out, lax.pmean(aux, axes)
 
-    return _shard_map(
+    return jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(P(None, None), wspecs),
@@ -195,7 +192,7 @@ def _moe_ffn_manual(x, lp, cfg: ModelConfig, *, ep: bool, psum_axes=None):
     """
     t, d = x.shape
     e, k = cfg.n_experts, cfg.top_k
-    mp = _axis_size("model")
+    mp = lax.axis_size("model")
     e_loc = lp["experts_up"].shape[0]
 
     logits = x.astype(jnp.float32) @ lp["router"].astype(jnp.float32)
@@ -266,14 +263,14 @@ def _moe_ffn_manual(x, lp, cfg: ModelConfig, *, ep: bool, psum_axes=None):
         extra = 1
         for a in axes:
             if a != "model":
-                extra *= _axis_size(a)
+                extra *= lax.axis_size(a)
         out = out + (shared / extra if extra > 1 else shared)
 
     # ONE combine psum: merges EP expert-locality masking and/or f-slice
     # partial sums (and the f-sliced shared expert) in a single collective.
     # The stationary (decode) path reduces over every weight-sharded axis.
     axes = psum_axes if psum_axes is not None else ("model",)
-    if any(_axis_size(a) > 1 for a in axes):
+    if any(lax.axis_size(a) > 1 for a in axes):
         out = lax.psum(out.astype(jnp.float32), axes).astype(x.dtype)
     return out, aux
 

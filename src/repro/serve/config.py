@@ -43,7 +43,7 @@ class ServeConfig:
     block: int = 1024            # jnp streaming column-block size
     block_m: BlockArg = 128      # Pallas row tile (int or "auto" = autotuned)
     block_n: BlockArg = 512      # Pallas column tile (int or "auto")
-    interpret: bool = True       # Pallas interpret mode (CPU validation)
+    interpret: Optional[bool] = None  # Pallas interpret mode (None = platform)
     score_h: Optional[float] = None
     # Default serving tier: a GEMM-operand tier (kernels/precision.py) or
     # "rff", the random-feature fast tier (kernels/flash_rff.py).  A

@@ -30,6 +30,7 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 import numpy as np
 
 
@@ -40,7 +41,8 @@ def _phi_cross(a: jnp.ndarray, b: jnp.ndarray, inv2h2) -> jnp.ndarray:
     b = jnp.asarray(b, jnp.float32)
     an = jnp.sum(a * a, axis=-1)[:, None]
     bn = jnp.sum(b * b, axis=-1)[None, :]
-    sq = jnp.maximum(an + bn - 2.0 * (a @ b.T), 0.0)
+    g = jnp.matmul(a, b.T, precision=lax.Precision.HIGHEST)
+    sq = jnp.maximum(an + bn - 2.0 * g, 0.0)
     return jnp.exp(-sq * inv2h2)
 
 

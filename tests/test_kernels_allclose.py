@@ -126,3 +126,19 @@ def test_vmem_budget_rejects_oversized_tiles():
     with pytest.raises(ValueError, match="VMEM"):
         ops.flash_kde(jnp.zeros((1024, 16)), jnp.zeros((64, 16)), 1.0,
                       block_m=2048, block_n=2048, interpret=True)
+
+
+def test_interpret_mode_derived_from_platform():
+    """``interpret=None`` (every config's default) interprets off a TPU and
+    compiles on one; each traced kernel program is counted under its mode."""
+    from repro import obs
+    from repro.kernels import interpret_mode
+
+    assert interpret_mode() == (jax.default_backend() != "tpu")
+    assert interpret_mode(True) is True and interpret_mode(False) is False
+    mode = "interpret" if interpret_mode() else "compiled"
+    traces = obs.counter("kernels.pallas_traces", labels={"mode": mode})
+    before = traces.value
+    x, y = _data(72, 24, 3, seed=7)       # a shape no other test traces
+    ops.flash_kde(x, y, 0.9, block_m=8, block_n=128, prune="off")
+    assert traces.value > before

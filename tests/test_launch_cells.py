@@ -13,7 +13,7 @@ import pytest
 
 _CHILD = r"""
 import jax, jax.numpy as jnp, dataclasses
-from repro.distributed.compat import make_auto_mesh
+from repro.launch.mesh import make_auto_mesh
 mesh = make_auto_mesh((2, 2, 2), ('pod', 'data', 'model'))
 from repro.configs import get_arch, ShapeCfg
 from repro.launch.steps import build_cell

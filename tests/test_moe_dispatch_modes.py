@@ -18,7 +18,7 @@ import pytest
 _CHILD = r"""
 import jax, jax.numpy as jnp, numpy as np, dataclasses
 from jax.sharding import NamedSharding, PartitionSpec as P
-from repro.distributed.compat import make_auto_mesh
+from repro.launch.mesh import make_auto_mesh
 mesh = make_auto_mesh((2, 2, 2), ('pod', 'data', 'model'))
 from repro.models.common import ModelConfig, init_params
 from repro.models import moe

@@ -142,22 +142,24 @@ def test_flash_kde_prepared_tiers_and_laplace(tier, laplace):
 @pytest.mark.parametrize("tier", TIERS)
 def test_padding_contributes_exactly_zero_per_tier(tier):
     """Sentinel train columns add exactly 0.0 to real rows at every tier,
-    and query padding never changes real rows: heavier padding (smaller
-    bucket tiles vs a 4× padded layout) must give bit-identical sums."""
+    and query padding never changes real rows: heavier padding (a 4×
+    padded layout and query bucket) must give bit-identical sums.  Both
+    launches stream the same 32-wide tiles, so the real columns are summed
+    in the same order and only the padding differs."""
     x, y = _data(96, 24, 8, seed=5)
     h = 0.7
     light = ops.prepare_train_columns(x, block_n=32, precision=tier)
     heavy = ops.prepare_train_columns(x, block_n=256, precision=tier)
     assert heavy.xt.shape[1] == 256 > light.xt.shape[1]
 
-    kw = dict(precision=tier, block_m=8, interpret=True)
+    kw = dict(precision=tier, block_m=8, block_n=32, interpret=True)
     yp_light = ops._pad_to(y, 8)
     yp_heavy = ops._pad_to(y, 64)
     s_light = ops.flash_kde_prepared(
-        yp_light, light.xt, light.nrm_x, h, light.xt_lo, block_n=32, **kw
+        yp_light, light.xt, light.nrm_x, h, light.xt_lo, **kw
     )
     s_heavy = ops.flash_kde_prepared(
-        yp_heavy, heavy.xt, heavy.nrm_x, h, heavy.xt_lo, block_n=64, **kw
+        yp_heavy, heavy.xt, heavy.nrm_x, h, heavy.xt_lo, **kw
     )
     np.testing.assert_array_equal(np.asarray(s_light[: y.shape[0]]),
                                   np.asarray(s_heavy[: y.shape[0]]))

@@ -124,7 +124,7 @@ def test_checkpoint_ignores_torn_writes():
 
 
 def test_checkpoint_restore_with_sharding():
-    from repro.distributed.compat import make_auto_mesh
+    from repro.launch.mesh import make_auto_mesh
 
     mesh = make_auto_mesh((1,), ("data",))
     from jax.sharding import NamedSharding, PartitionSpec as P
