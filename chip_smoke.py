@@ -6,8 +6,8 @@ and the ``SDKDE`` estimator) at the paper's sizes, with compiled Pallas
 kernels, and checks every answer against the plain ``core/kde.py``
 reference at ``jax.default_matmul_precision("highest")``:
 
-  python chip_smoke.py               # one chip: Table 1 serving, streaming,
-                                     # RFF cascade
+  python chip_smoke.py               # one chip: f32 GEMM precision probe,
+                                     # Table 1 serving, streaming, RFF cascade
   python chip_smoke.py --paper-1m    # + the paper's 1M x 16-d fit and
                                      # 131k-query evaluation
   python chip_smoke.py --chips 4     # only the ring backend at 1M x 16-d
@@ -36,6 +36,16 @@ sys.path.insert(0, str(Path(__file__).resolve().parent / "src"))
 #: (deep-tail densities) — the bars ``serve_kde --verify`` applies.
 TIER_RTOL = {"f32": 1e-5, "bf16": 5e-2, "bf16x2": 5e-4}
 TIER_ATOL_FRAC = {"f32": 1e-6, "bf16": 5e-3, "bf16x2": 1e-5}
+
+
+class SmokeFailure(RuntimeError):
+    """A phase's check failed; uncaught, it ends the run nonzero."""
+
+
+def require(ok: bool, msg: str) -> None:
+    """A phase check that ``python -O`` cannot strip."""
+    if not ok:
+        raise SmokeFailure(msg)
 
 
 class Compiles:
@@ -94,14 +104,60 @@ def check_close(what: str, got, want, tier: str) -> float:
     relative error over rows above the absolute floor."""
     got = np.asarray(got, np.float64)
     want = np.asarray(want, np.float64)
-    assert got.shape == want.shape, (what, got.shape, want.shape)
-    assert np.isfinite(got).all(), f"{what}: non-finite densities"
+    require(got.shape == want.shape,
+            f"{what}: shape {got.shape} vs reference {want.shape}")
+    require(bool(np.isfinite(got).all()), f"{what}: non-finite densities")
     atol = TIER_ATOL_FRAC[tier] * float(np.max(np.abs(want)))
     np.testing.assert_allclose(got, want, rtol=TIER_RTOL[tier], atol=atol,
                                err_msg=what)
     big = np.abs(want) > atol
     rel = np.abs(got - want)[big] / np.abs(want)[big]
     return float(rel.max()) if rel.size else 0.0
+
+
+def phase_precision(seed: int, *, m=512, k=1024, bar=1e-6):
+    """What an f32 GEMM gets on this device: XLA and Mosaic at their
+    default precision, and at the precision the repo asks for
+    (``Precision.HIGHEST``; ``precision.dot_f32`` inside a kernel), each
+    against an f64 product on the host.  Errors are max abs error over
+    max |ab|; the repo's two must stay under ``bar``."""
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+    from jax.experimental import pallas as pl
+
+    from repro.kernels import compiler_params, launch_interpret
+    from repro.kernels.precision import dot_f32
+
+    ka, kb = jax.random.split(jax.random.PRNGKey(seed))
+    a = jax.random.normal(ka, (m, k), jnp.float32)
+    b = jax.random.normal(kb, (k, m), jnp.float32)
+    exact = np.asarray(a, np.float64) @ np.asarray(b, np.float64)
+    scale = float(np.max(np.abs(exact)))
+
+    def in_kernel(dot):
+        def body(a_ref, b_ref, o_ref):
+            o_ref[...] = dot(a_ref[...], b_ref[...])
+        return pl.pallas_call(
+            body, out_shape=jax.ShapeDtypeStruct((m, m), jnp.float32),
+            interpret=launch_interpret(None),
+            compiler_params=compiler_params())(a, b)
+
+    def err(c):
+        return float(np.max(np.abs(np.asarray(c, np.float64) - exact))) / scale
+
+    errs = {
+        "xla default": err(jnp.dot(a, b)),
+        "xla HIGHEST": err(jnp.dot(a, b, precision=lax.Precision.HIGHEST)),
+        "mosaic default": err(in_kernel(
+            lambda x, y: jnp.dot(x, y, preferred_element_type=jnp.float32))),
+        "mosaic dot_f32": err(in_kernel(dot_f32)),
+    }
+    print(f"precision: f32 ({m}x{k})@({k}x{m}), max abs err / max |ab| "
+          + "; ".join(f"{n} {e:.3e}" for n, e in errs.items()))
+    for name in ("xla HIGHEST", "mosaic dot_f32"):
+        require(errs[name] <= bar,
+                f"precision: {name} error {errs[name]:.3e} over {bar:g}")
 
 
 def phase_table1(seed: int, compiles: Compiles, *, n=None, m=None, d=None,
@@ -142,7 +198,8 @@ def phase_table1(seed: int, compiles: Compiles, *, n=None, m=None, d=None,
         worst = 0.0
         for r, k in zip(reqs, sizes):
             ans = eng.query(r)
-            assert ans.tier == tier, (ans.tier, tier)
+            require(ans.tier == tier,
+                    f"table1: answered at {ans.tier}, asked for {tier}")
             worst = max(worst, check_close(
                 f"table1 {tier} m={k}", ans.value, want[:k], tier))
         s = eng.latency.summary()
@@ -211,15 +268,16 @@ def phase_cascade(seed: int, *, n=262144, d=2, sizes=(64, 500, 2048)):
     for k in sizes:
         ans = eng.query(QueryRequest(key="cascade", points=pool[:k]))
         got = np.asarray(ans.value, np.float64)
-        assert np.isfinite(got).all(), "cascade: non-finite densities"
+        require(bool(np.isfinite(got).all()),
+                "cascade: non-finite densities")
         realized = flash_rff.realized_error(got, want[:k], p_scale)
         bounds = np.asarray(ans.rel_err_bounds, np.float64)
         worst = max(worst, float((realized - bounds).max()))
         hits += ans.rff_hits
         total += ans.rff_hits + ans.escalated
-    assert worst <= 1e-6, (
-        f"cascade: realized error exceeds the certified band by {worst:.2e}")
-    assert hits > 0, "cascade: no row resolved at the RFF tier"
+    require(worst <= 1e-6, f"cascade: realized error exceeds the "
+            f"certified band by {worst:.2e}")
+    require(hits > 0, "cascade: no row resolved at the RFF tier")
     print(f"cascade: n={n} d={d} fit={fit_s:.2f}s; {hits}/{total} rows at "
           f"the RFF tier; certified bands dominate realized error (worst "
           f"slack {-worst:.3e})")
@@ -227,7 +285,8 @@ def phase_cascade(seed: int, *, n=262144, d=2, sizes=(64, 500, 2048)):
 
 def phase_paper_1m(seed: int, *, n=None, m=None, d=None, verify=1024):
     """Paper §7: a 1M x 16-d SDKDE fit and evaluation on 131k queries
-    through the estimator API, 1024 rows verified."""
+    through the estimator API; the verified rows are spread evenly over
+    all queries, so every launch the evaluation splits into is checked."""
     from repro.configs import KDE_WORKLOADS
     from repro.core.estimator import SDKDE, EstimatorConfig
 
@@ -241,13 +300,16 @@ def phase_paper_1m(seed: int, *, n=None, m=None, d=None, verify=1024):
     t0 = time.perf_counter()
     dens = np.asarray(est.evaluate(y))
     eval_s = time.perf_counter() - t0
-    assert dens.shape == (m,) and np.isfinite(dens).all(), "paper_1m: bad"
+    require(dens.shape == (m,) and bool(np.isfinite(dens).all()),
+            f"paper_1m: shape {dens.shape} or non-finite densities")
+    rows = np.arange(verify) * (m // verify)
     t0 = time.perf_counter()
-    want = reference_sdkde(x, y[:verify], est.h)
+    want = reference_sdkde(x, np.asarray(y)[rows], est.h)
     ref_s = time.perf_counter() - t0
-    worst = check_close("paper_1m f32", dens[:verify], want, "f32")
+    worst = check_close("paper_1m f32", dens[rows], want, "f32")
     print(f"paper_1m: n={n} d={d} queries={m} fit={fit_s:.2f}s "
-          f"evaluate={eval_s:.2f}s reference({verify} rows)={ref_s:.2f}s; "
+          f"evaluate={eval_s:.2f}s reference({verify} rows, stride "
+          f"{m // verify})={ref_s:.2f}s; "
           f"max rel err {worst:.3e}")
 
 
@@ -311,7 +373,8 @@ def main(argv=None) -> int:
     if args.chips == 4:
         phases = [("ring", lambda: phase_ring(args.seed))]
     else:
-        phases = [("table1", lambda: phase_table1(args.seed, compiles)),
+        phases = [("precision", lambda: phase_precision(args.seed)),
+                  ("table1", lambda: phase_table1(args.seed, compiles)),
                   ("stream", lambda: phase_stream(args.seed)),
                   ("cascade", lambda: phase_cascade(args.seed))]
         if args.paper_1m:
@@ -325,9 +388,9 @@ def main(argv=None) -> int:
     print(f"pallas kernel programs: compiled={compiled:g} "
           f"interpreted={interpreted:g}; XLA programs={compiles.n}; "
           f"total {time.perf_counter() - t_all:.1f}s")
-    assert interpreted == 0, "a Pallas kernel ran in interpret mode"
+    require(interpreted == 0, "a Pallas kernel ran in interpret mode")
     if args.chips == 1:
-        assert compiled > 0, "no compiled Pallas kernel was launched"
+        require(compiled > 0, "no compiled Pallas kernel was launched")
     print(json.dumps({"ok": True, "device": {
         "platform": dev.platform, "kind": dev.device_kind,
         "count": len(devices)}}))

@@ -157,9 +157,19 @@ def _pad_to(x: jnp.ndarray, mult: int, value: float = PAD_VALUE) -> jnp.ndarray:
                    constant_values=value)
 
 
+@jax.jit
 def _norms(x: jnp.ndarray) -> jnp.ndarray:
+    # Rounded squares summed left to right, so every program gets the same
+    # bits: a reduce may be reordered, and a square may be contracted into
+    # an FMA (the max() blocks that), depending on what XLA fuses around
+    # it.  The eager pruned prep and the jitted dense programs must agree
+    # for eps=0 pruning to reproduce dense sums.
     x32 = x.astype(jnp.float32)
-    return jnp.sum(x32 * x32, axis=-1, keepdims=True)
+    sq = jnp.maximum(x32 * x32, 0.0)
+    acc = sq[:, 0]
+    for k in range(1, sq.shape[-1]):
+        acc = acc + sq[:, k]
+    return acc[:, None]
 
 
 def _tier_norms(hi: jnp.ndarray, lo: Optional[jnp.ndarray]) -> jnp.ndarray:

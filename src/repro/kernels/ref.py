@@ -8,24 +8,7 @@ computes (same inputs, same outputs, same padding semantics), with no tiling
 from __future__ import annotations
 
 import jax.numpy as jnp
-import numpy as np
 from jax import lax
-
-
-def norm_ulp_rtol(x, y, h, ulps: int = 4) -> float:
-    """How far two evaluations of the same kernel sums may differ in
-    relative terms when only the summation order of their f32 squared
-    norms differs.
-
-    One ulp of ``‖x‖²`` moves ``sq = ‖y‖²+‖x‖²−2y·x``, and with it the
-    exponent ``sq/(2h²)``, by ``ulp(max‖·‖²)/(2h²)``; ``ulps`` such steps
-    (two norms and the Gram) bound the relative change of each term.
-    Programs that fuse the norm reduction differently (a jitted dense pass
-    vs. the pruned path's eager prep) differ by exactly this much.
-    """
-    sq = max(float(jnp.max(jnp.sum(jnp.square(jnp.asarray(a, jnp.float32)),
-                                   axis=-1))) for a in (x, y))
-    return ulps * float(np.spacing(np.float32(sq))) / (2.0 * h * h)
 
 
 def _sqdist(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:

@@ -27,7 +27,6 @@ import pytest
 
 from repro import obs
 from repro.kernels import autotune, ops
-from repro.kernels import ref as kref
 from repro.plan import (
     DEFAULT_ACCURACY,
     EPS_SAFETY,
@@ -402,10 +401,9 @@ def _eps0_dense_case(seed, h):
     dense = ops.flash_kde(x, y, h, interpret=True, prune="off",
                           block_m=8, block_n=128)
     # the eps=0 oracle bar from tests/test_pruning.py: identical up to
-    # summation order (of the accumulators and of the squared norms)
-    np.testing.assert_allclose(
-        np.asarray(pruned), np.asarray(dense), atol=1e-20,
-        rtol=max(1e-6, kref.norm_ulp_rtol(x, y, h)))
+    # summation order
+    np.testing.assert_allclose(np.asarray(pruned), np.asarray(dense),
+                               rtol=1e-6, atol=1e-20)
 
 
 if _HAVE_HYPOTHESIS:
