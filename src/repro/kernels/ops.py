@@ -133,10 +133,13 @@ def _cached_columns(x, *, block_n: int, precision: str,
     key = (id(x), int(block_n), precision, seed)
     with _COLUMNS_LOCK:
         hit = _COLUMNS_CACHE.get(key)
-        if hit is not None and hit[0]() is x:
-            return hit[1]
-    cols = prepare_train_columns(x, block_n=block_n, precision=precision,
-                                 clustered=True, seed=seed)
+        cols = hit[1] if hit is not None and hit[0]() is x else None
+    with obs.span("kernels.prune.columns",
+                  cache="miss" if cols is None else "hit"):
+        if cols is not None:
+            return cols
+        cols = prepare_train_columns(x, block_n=block_n, precision=precision,
+                                     clustered=True, seed=seed)
     try:
         ref = weakref.ref(x)
     except TypeError:            # not weakref-able: skip caching
@@ -310,7 +313,7 @@ def _record_occupancy_profile(rows, col_counts, d, launch_occ, block_n,
         return
     fine_tm = spatial.tile_map(yrec, meta_fine, inv2h2, epsilon,
                                block_m=block_m, kind=kind)
-    fine_occ = float(jnp.mean(fine_tm.keep))
+    fine_occ = float(spatial.to_host(jnp.mean(fine_tm.keep)))
     for n_key in col_counts:
         autotune.record_occupancy(rows, n_key, d, fine_occ, block_n=fine)
 
@@ -333,44 +336,50 @@ def _score_stats_pruned(
     the output rows come straight back through the layout's slot map.  The
     certificate uses the score kind — per-point bound exp(-arg)·max(1,
     max|x|) — because the accumulator weights are the [X | 1] columns.
+    Each host step runs in its own ``kernels.prune.*`` span.
     """
     n, d = x.shape
-    layout = spatial.cluster_layout(
-        jnp.asarray(x, jnp.float32), index.labels, block_n,
-        total_multiple=math.lcm(block_m, block_n),
-    )
+    with obs.span("kernels.prune.layout"):
+        layout = spatial.cluster_layout(
+            jnp.asarray(x, jnp.float32), index.labels, block_n,
+            total_multiple=math.lcm(block_m, block_n),
+        )
     xp = layout.points
-    x_ops, xt_ops, xaug_ops, nrm, xrec = _score_operands(xp, precision)
-    col_meta = spatial.tile_metadata(xrec, layout.real, block=block_n)
-    tm = spatial.tile_map(xrec, col_meta, _inv2h2(h), epsilon,
-                          block_m=block_m, kind="score")
-    vl = spatial.visit_lists(tm.keep)
-    fine_meta = None
-    if block_n > autotune.FINE_PROBE_BLOCK \
-            and xp.shape[0] % autotune.FINE_PROBE_BLOCK == 0 \
-            and not autotune.has_occupancy(n, n, d,
-                                           autotune.FINE_PROBE_BLOCK):
-        fine_meta = spatial.tile_metadata(xrec, layout.real,
-                                          block=autotune.FINE_PROBE_BLOCK)
-    _record_occupancy_profile(n, {n}, d, vl.occupancy, block_n, xrec,
-                              fine_meta, _inv2h2(h), epsilon, block_m,
-                              "score")
-    _note_pruned_launch("score", vl, tm, epsilon)
+    with obs.span("kernels.prune.operands"):
+        x_ops, xt_ops, xaug_ops, nrm, xrec = _score_operands(xp, precision)
+        col_meta = spatial.tile_metadata(xrec, layout.real, block=block_n)
+    with obs.span("kernels.prune.tile_map"):
+        tm = spatial.tile_map(xrec, col_meta, _inv2h2(h), epsilon,
+                              block_m=block_m, kind="score")
+    with obs.span("kernels.prune.visit_lists"):
+        vl = spatial.visit_lists(tm.keep)
+    with obs.span("kernels.prune.profile"):
+        fine_meta = None
+        if block_n > autotune.FINE_PROBE_BLOCK \
+                and xp.shape[0] % autotune.FINE_PROBE_BLOCK == 0 \
+                and not autotune.has_occupancy(n, n, d,
+                                               autotune.FINE_PROBE_BLOCK):
+            fine_meta = spatial.tile_metadata(
+                xrec, layout.real, block=autotune.FINE_PROBE_BLOCK)
+        _record_occupancy_profile(n, {n}, d, vl.occupancy, block_n, xrec,
+                                  fine_meta, _inv2h2(h), epsilon, block_m,
+                                  "score")
+        _note_pruned_launch("score", vl, tm)
     with obs.span("kernels.pruned_score", rows=n,
-                  occupancy=round(vl.occupancy, 4)), \
-            obs.annotate("flash_score_pruned"):
+                  occupancy=round(vl.occupancy, 4)):
         s1aug = flash_pruned.flash_score_pallas_pruned(
             vl.counts, vl.tile_map, x_ops[0], nrm, xt_ops[0], xaug_ops[0],
             _inv2h2(h), x_ops[1], xt_ops[1], xaug_ops[1],
             block_m=block_m, block_n=block_n, max_visits=vl.max_visits,
             interpret=interpret,
         )
-    rows = s1aug[layout.slots]
-    return rows[:, d], rows[:, :d]
+    with obs.span("kernels.prune.gather"):
+        rows = s1aug[layout.slots]
+        return rows[:, d], rows[:, :d]
 
 
 def _note_pruned_launch(kind: str, vl: spatial.VisitLists,
-                        tm: spatial.TileMap, epsilon) -> None:
+                        tm: spatial.TileMap) -> None:
     """Record one pruned pass: visit fraction (= 1 − skip rate) and the
     certified error budget actually spent, so serving telemetry can show
     how sparse traffic really is and how close certificates run to their
@@ -383,14 +392,41 @@ def _note_pruned_launch(kind: str, vl: spatial.VisitLists,
     obs.histogram("kernels.prune.visit_fraction",
                   "column tiles visited / total per pruned pass",
                   lo=1e-3, hi=1.0).observe(vl.occupancy)
-    err = float(jnp.max(tm.err_bound)) if tm.err_bound.size else 0.0
+    err = float(spatial.to_host(jnp.max(tm.err_bound))) \
+        if tm.err_bound.size else 0.0
     obs.histogram("kernels.prune.cert_budget",
                   "max certified abs error of the unnormalized "
                   "accumulator per pruned pass",
                   lo=1e-30, hi=1.0, per_decade=1).observe(err)
-    obs.gauge("kernels.prune.epsilon",
-              "per-point contribution threshold of the last pruned "
-              "pass").set(float(epsilon))
+
+
+def _prune_pass(kind: str, rows: int, cols: int):
+    """The span around one whole pruned pass, train-side prep included;
+    its steps are the ``kernels.prune.*`` spans opened inside it."""
+    return obs.span("kernels.prune.pass", kind=kind, rows=rows, cols=cols)
+
+
+def _score_stats(x, h, epsilon: Optional[float], *, precision: str,
+                 block_m: int, block_n: int, interpret: Optional[bool],
+                 seed: int):
+    """(S0, S1, index): the dense score pass (index None) when
+    ``epsilon`` is None, else the pruned one and the clustering it built."""
+    n = x.shape[0]
+    if epsilon is None:
+        with obs.span("kernels.dense_score", rows=n, cols=n):
+            s0, s1 = _flash_score_stats_dense(
+                x, h, precision=precision, block_m=block_m,
+                block_n=block_n, interpret=interpret,
+            )
+        return s0, s1, None
+    with _prune_pass("score", n, n):
+        with obs.span("kernels.prune.index"):
+            index = spatial.build_index(x, seed=seed)
+        s0, s1 = _score_stats_pruned(
+            x, h, epsilon, index, precision=precision, block_m=block_m,
+            block_n=block_n, interpret=interpret,
+        )
+    return s0, s1, index
 
 
 def flash_score_stats(
@@ -418,17 +454,10 @@ def flash_score_stats(
         block_m, block_n, n, n, d, out_width=d + 1, precision=precision,
         interpret=interpret, pruned=prune != "off",
     )
-    eps = resolve_prune(prune, n, block_n)
-    if eps is None:
-        return _flash_score_stats_dense(
-            x, h, precision=precision, block_m=block_m, block_n=block_n,
-            interpret=interpret,
-        )
-    index = spatial.build_index(x, seed=seed)
-    return _score_stats_pruned(
-        x, h, eps, index, precision=precision, block_m=block_m,
-        block_n=block_n, interpret=interpret,
-    )
+    s0, s1, _ = _score_stats(
+        x, h, resolve_prune(prune, n, block_n), precision=precision,
+        block_m=block_m, block_n=block_n, interpret=interpret, seed=seed)
+    return s0, s1
 
 
 def _apply_score_shift(x32: jnp.ndarray, s0, s1, h, sh) -> jnp.ndarray:
@@ -513,20 +542,9 @@ def _flash_eval_dense(
     return sums[:m, 0] / (n * gaussian_norm_const(d, 1.0) * h**d)
 
 
-def flash_kde(
-    x: jnp.ndarray,
-    y: jnp.ndarray,
-    h,
-    *,
-    precision: str = "f32",
-    block_m="auto",
-    block_n="auto",
-    interpret: Optional[bool] = None,
-    prune: PruneArg = "auto",
-    seed: int = 0,
-    plan=None,
-) -> jnp.ndarray:
-    """Normalized Gaussian KDE densities at ``y`` (train set ``x``)."""
+def _flash_eval(x, y, h, *, precision, block_m, block_n, interpret, prune,
+                seed, plan, laplace: bool) -> jnp.ndarray:
+    """Normalized KDE (or fused Laplace-KDE) densities at ``y``."""
     prec.validate(precision)
     n, d = x.shape
     m = y.shape[0]
@@ -541,19 +559,41 @@ def flash_kde(
         interpret=interpret, pruned=prune != "off",
     )
     eps = resolve_prune(prune, n, block_n)
+    kind = "laplace" if laplace else "kde"
     if eps is None:
-        return _flash_eval_dense(
-            x, y, h, precision=precision, block_m=block_m, block_n=block_n,
-            interpret=interpret, laplace=False,
+        with obs.span("kernels.dense_eval", rows=m, cols=n, kind=kind):
+            return _flash_eval_dense(
+                x, y, h, precision=precision, block_m=block_m,
+                block_n=block_n, interpret=interpret, laplace=laplace,
+            )
+    with _prune_pass(kind, m, n):
+        cols = _cached_columns(x, block_n=block_n, precision=precision,
+                               seed=seed)
+        sums = _pruned_eval_sums(
+            y, cols, h, eps, precision=precision, block_m=block_m,
+            block_n=block_n, interpret=interpret, laplace=laplace,
         )
-    cols = _cached_columns(x, block_n=block_n, precision=precision,
-                           seed=seed)
-    sums = _pruned_eval_sums(
-        y, cols, h, eps, precision=precision, block_m=block_m,
-        block_n=block_n, interpret=interpret, laplace=False,
-    )
     h = jnp.asarray(h, jnp.float32)
     return sums / (n * gaussian_norm_const(d, 1.0) * h**d)
+
+
+def flash_kde(
+    x: jnp.ndarray,
+    y: jnp.ndarray,
+    h,
+    *,
+    precision: str = "f32",
+    block_m="auto",
+    block_n="auto",
+    interpret: Optional[bool] = None,
+    prune: PruneArg = "auto",
+    seed: int = 0,
+    plan=None,
+) -> jnp.ndarray:
+    """Normalized Gaussian KDE densities at ``y`` (train set ``x``)."""
+    return _flash_eval(x, y, h, precision=precision, block_m=block_m,
+                       block_n=block_n, interpret=interpret, prune=prune,
+                       seed=seed, plan=plan, laplace=False)
 
 
 def flash_laplace_kde(
@@ -570,33 +610,9 @@ def flash_laplace_kde(
     plan=None,
 ) -> jnp.ndarray:
     """Fused Flash-Laplace-KDE densities at ``y`` — single quadratic pass."""
-    prec.validate(precision)
-    n, d = x.shape
-    m = y.shape[0]
-    precision, block_m, block_n, prune = _apply_plan(
-        plan, n, m, d, precision=precision, block_m=block_m,
-        block_n=block_n, prune=prune,
-    )
-    if _traced(x, y):
-        prune = "off"            # pruning host-syncs; stay traceable
-    block_m, block_n = _resolve(
-        block_m, block_n, m, n, d, out_width=1, precision=precision,
-        interpret=interpret, pruned=prune != "off",
-    )
-    eps = resolve_prune(prune, n, block_n)
-    if eps is None:
-        return _flash_eval_dense(
-            x, y, h, precision=precision, block_m=block_m, block_n=block_n,
-            interpret=interpret, laplace=True,
-        )
-    cols = _cached_columns(x, block_n=block_n, precision=precision,
-                           seed=seed)
-    sums = _pruned_eval_sums(
-        y, cols, h, eps, precision=precision, block_m=block_m,
-        block_n=block_n, interpret=interpret, laplace=True,
-    )
-    h = jnp.asarray(h, jnp.float32)
-    return sums / (n * gaussian_norm_const(d, 1.0) * h**d)
+    return _flash_eval(x, y, h, precision=precision, block_m=block_m,
+                       block_n=block_n, interpret=interpret, prune=prune,
+                       seed=seed, plan=plan, laplace=True)
 
 
 @functools.partial(jax.jit, static_argnames=_STATIC)
@@ -691,22 +707,21 @@ def prepare_train_columns(
             128, "auto", rows=4096, cols=x.shape[0], d=x.shape[-1],
             precision=precision, measure=False,
         )
-    real = None
-    if clustered:
-        if index is None:
+    if not clustered:
+        return columns_from_layout(_pad_to(x, block_n), None, None,
+                                   block_n=block_n, precision=precision)
+    if index is None:
+        with obs.span("kernels.prune.index"):
             index = spatial.build_index(x, seed=seed)
+    with obs.span("kernels.prune.layout"):
         labels = index.labels if (
             index.labels is not None
             and index.labels.shape[0] == x.shape[0]
         ) else spatial.assign(x, index)
         layout = spatial.cluster_layout(jnp.asarray(x), labels, block_n)
-        xp, real = layout.points, layout.real
-    else:
-        xp = _pad_to(x, block_n)
-    return columns_from_layout(
-        xp, real, index if clustered else None,
-        block_n=block_n, precision=precision,
-    )
+    with obs.span("kernels.prune.operands"):
+        return columns_from_layout(layout.points, layout.real, index,
+                                   block_n=block_n, precision=precision)
 
 
 def columns_from_layout(
@@ -841,7 +856,9 @@ def _pruned_eval_sums(
     path); only real rows enter the query layout.  This is the pruned
     path's one host-sync orchestration: assign queries to the train
     clusters → scatter into a cluster-aligned layout → bounds prepass →
-    compact visit lists (host) → launch → gather back to request order.
+    compact visit lists (host) → launch → gather back to request order,
+    each step in its own ``kernels.prune.*`` span.  Callers open the
+    ``kernels.prune.pass`` span around it and the train-side prep.
     """
     if cols.meta is None or cols.index is None:
         raise ValueError(
@@ -859,38 +876,44 @@ def _pruned_eval_sums(
     nr = m_in if n_real is None else min(n_real, m_in)
     # scatter the real queries into their own cluster-aligned layout
     # (assigned against the train centroids) so row tiles stay coherent
-    labels = spatial.assign(y[:nr], cols.index)
-    qlayout = spatial.cluster_layout(
-        jnp.asarray(y[:nr], jnp.float32), labels, block_m, bucket_rows=True
-    )
-    yp = qlayout.points
-    y_hi, y_lo, nrm_y, yrec = _cast_queries(yp, precision)
+    with obs.span("kernels.prune.layout"):
+        labels = spatial.assign(y[:nr], cols.index)
+        qlayout = spatial.cluster_layout(
+            jnp.asarray(y[:nr], jnp.float32), labels, block_m,
+            bucket_rows=True,
+        )
+    with obs.span("kernels.prune.operands"):
+        y_hi, y_lo, nrm_y, yrec = _cast_queries(qlayout.points, precision)
     kind = "laplace" if laplace else "kde"
-    tm = spatial.tile_map(yrec, cols.meta, _inv2h2(h), epsilon,
-                          block_m=block_m, kind=kind)
-    vl = spatial.visit_lists(tm.keep)
-    # record under BOTH column counts a later resolve may key on: the
-    # true train count (flash_kde / flash_sdkde resolve pre-padding) and
-    # the padded layout length (the prepared serving path)
-    n_true = int(cols.meta.counts.sum())
-    _record_occupancy_profile(m_in, {n_true, cols.xt.shape[1]}, d,
-                              vl.occupancy, block_n, yrec, cols.meta_fine,
-                              _inv2h2(h), epsilon, block_m, kind)
-    _note_pruned_launch(kind, vl, tm, epsilon)
+    with obs.span("kernels.prune.tile_map"):
+        tm = spatial.tile_map(yrec, cols.meta, _inv2h2(h), epsilon,
+                              block_m=block_m, kind=kind)
+    with obs.span("kernels.prune.visit_lists"):
+        vl = spatial.visit_lists(tm.keep)
+    with obs.span("kernels.prune.profile"):
+        # record under BOTH column counts a later resolve may key on: the
+        # true train count (flash_kde / flash_sdkde resolve pre-padding)
+        # and the padded layout length (the prepared serving path)
+        n_true = int(spatial.to_host(cols.meta.counts.sum()))
+        _record_occupancy_profile(m_in, {n_true, cols.xt.shape[1]}, d,
+                                  vl.occupancy, block_n, yrec,
+                                  cols.meta_fine, _inv2h2(h), epsilon,
+                                  block_m, kind)
+        _note_pruned_launch(kind, vl, tm)
     with obs.span("kernels.pruned_eval", rows=nr, kind=kind,
                   occupancy=round(vl.occupancy, 4),
-                  max_visits=vl.max_visits), \
-            obs.annotate("flash_kde_pruned"):
+                  max_visits=vl.max_visits):
         sums = flash_pruned.flash_kde_pallas_pruned(
             vl.counts, vl.tile_map, y_hi, nrm_y, cols.xt, cols.nrm_x,
             _inv2h2(h), y_lo, cols.xt_lo,
             block_m=block_m, block_n=block_n, max_visits=vl.max_visits,
             interpret=interpret, laplace=laplace,
         )
-    out = sums[qlayout.slots, 0]                 # back to request order
-    if nr < m_in:                                # caller's sentinel tail
-        out = jnp.concatenate([out, jnp.zeros((m_in - nr,), out.dtype)])
-    return out
+    with obs.span("kernels.prune.gather"):
+        out = sums[qlayout.slots, 0]             # back to request order
+        if nr < m_in:                            # caller's sentinel tail
+            out = jnp.concatenate([out, jnp.zeros((m_in - nr,), out.dtype)])
+        return out
 
 
 @functools.partial(jax.jit, static_argnames=_STATIC + ("laplace",))
@@ -968,8 +991,9 @@ def flash_kde_prepared(
         pruned=prune != "off",
     )
     eps = resolve_prune(prune, n, block_n)
+    kind = "laplace" if laplace else "kde"
     if eps is None:
-        with obs.annotate("flash_kde_prepared_dense"):
+        with obs.span("kernels.dense_eval", rows=m, cols=n, kind=kind):
             return _flash_kde_prepared_dense(
                 yp, xt, nrm_x, h, xt_lo, precision=precision,
                 block_m=block_m, block_n=block_n, interpret=interpret,
@@ -980,10 +1004,12 @@ def flash_kde_prepared(
             "flash_kde_prepared(prune=...) needs columns= (the clustered "
             "TrainColumns) for the tile metadata"
         )
-    return _pruned_eval_sums(
-        yp, columns, h, eps, precision=precision, block_m=block_m,
-        block_n=block_n, interpret=interpret, laplace=laplace, n_real=n_real,
-    )
+    with _prune_pass(kind, m if n_real is None else min(n_real, m), n):
+        return _pruned_eval_sums(
+            yp, columns, h, eps, precision=precision, block_m=block_m,
+            block_n=block_n, interpret=interpret, laplace=laplace,
+            n_real=n_real,
+        )
 
 
 # ---------------------------------------------------------------------------
@@ -1035,38 +1061,36 @@ def flash_sdkde(
     k_eps = resolve_prune(prune, n, k_bn)
 
     x32 = jnp.asarray(x, jnp.float32)
-    index = None
-    if s_eps is not None or k_eps is not None:
-        index = spatial.build_index(x32, seed=seed)
-    if s_eps is None:
-        s0, s1 = _flash_score_stats_dense(
-            x32, sh, precision=precision, block_m=s_bm, block_n=s_bn,
-            interpret=interpret,
-        )
-    else:
-        s0, s1 = _score_stats_pruned(
-            x32, sh, s_eps, index, precision=precision, block_m=s_bm,
-            block_n=s_bn, interpret=interpret,
-        )
+    s0, s1, index = _score_stats(
+        x32, sh, s_eps, precision=precision, block_m=s_bm, block_n=s_bn,
+        interpret=interpret, seed=seed)
     x_sd = _apply_score_shift(x32, s0, s1, h, sh)
 
-    # one shared eval-side prep, reusing the clustering: the labels fitted
-    # on x stay valid row-for-row for the O(h²)-shifted x_sd
-    cols = prepare_train_columns(
-        x_sd, block_n=k_bn, precision=precision,
-        clustered=k_eps is not None, index=index if k_eps is not None
-        else None,
-    )
     if k_eps is None:
+        cols = prepare_train_columns(x_sd, block_n=k_bn, precision=precision)
         yp = _pad_to(jnp.asarray(y), k_bm)
-        sums = _flash_kde_prepared_dense(
-            yp, cols.xt, cols.nrm_x, h, cols.xt_lo, precision=precision,
-            block_m=k_bm, block_n=k_bn, interpret=interpret, laplace=False,
-        )[:m]
+        with obs.span("kernels.dense_eval", rows=m, cols=n, kind="kde"):
+            sums = _flash_kde_prepared_dense(
+                yp, cols.xt, cols.nrm_x, h, cols.xt_lo, precision=precision,
+                block_m=k_bm, block_n=k_bn, interpret=interpret,
+                laplace=False,
+            )[:m]
     else:
-        sums = _pruned_eval_sums(
-            y, cols, h, k_eps, precision=precision, block_m=k_bm,
-            block_n=k_bn, interpret=interpret, laplace=False,
-        )
+        with _prune_pass("kde", m, n):
+            # one shared eval-side prep, reusing the clustering: the
+            # labels fitted on x stay valid row-for-row for the
+            # O(h²)-shifted x_sd
+            with obs.span("kernels.prune.columns"):
+                if index is None:
+                    with obs.span("kernels.prune.index"):
+                        index = spatial.build_index(x32, seed=seed)
+                cols = prepare_train_columns(
+                    x_sd, block_n=k_bn, precision=precision, clustered=True,
+                    index=index,
+                )
+            sums = _pruned_eval_sums(
+                y, cols, h, k_eps, precision=precision, block_m=k_bm,
+                block_n=k_bn, interpret=interpret, laplace=False,
+            )
     h = jnp.asarray(h, jnp.float32)
     return sums / (n * gaussian_norm_const(d, 1.0) * h**d)

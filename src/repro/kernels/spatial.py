@@ -64,6 +64,8 @@ import jax.numpy as jnp
 from jax import lax
 import numpy as np
 
+from repro import obs
+
 PAD_VALUE = 1.0e6   # matches ops.PAD_VALUE — kernel weight underflows to 0
 
 # f32 exp(-x) is exactly 0.0 for x > 150·ln2 ≈ 103.97 (subnormal rounding).
@@ -75,6 +77,10 @@ UNDERFLOW_ARG = 105.0
 MARGIN = 0.9
 
 KINDS = ("kde", "laplace", "score")
+
+#: Histogram of the pruned orchestration's device-to-host fetches: its
+#: count is the number of syncs, its sum the bytes moved.
+HOST_SYNC_BYTES = "kernels.prune.host_sync_bytes"
 
 
 class SpatialIndex(NamedTuple):
@@ -123,6 +129,19 @@ class VisitLists(NamedTuple):
     tile_map: jnp.ndarray    # (mt, max_visits) int32 column-tile indices
     max_visits: int          # static grid extent (pow2-bucketed)
     occupancy: float         # mean(counts) / n_tiles — the skip-rate stat
+
+
+def to_host(a) -> np.ndarray:
+    """``a`` as a host array.  Fetching a device array waits for it: each
+    fetch observes its bytes in :data:`HOST_SYNC_BYTES` and adds them to
+    the enclosing span's ``sync_bytes``.  A host array passes through."""
+    out = np.asarray(a)
+    if isinstance(a, jax.Array):
+        obs.histogram(HOST_SYNC_BYTES, "bytes of one device-to-host fetch "
+                      "of the pruned orchestration", lo=1,
+                      hi=1e10).observe(out.nbytes)
+        obs.current_span().add(sync_bytes=out.nbytes)
+    return out
 
 
 # ---------------------------------------------------------------------------
@@ -324,7 +343,7 @@ def cluster_layout(x: jnp.ndarray, labels, block: int, *,
     """
     x = jnp.asarray(x)
     n, d = x.shape
-    lab = np.asarray(labels)
+    lab = to_host(labels)
     slots = cluster_slots(lab, block, slack=slack)
     _, caps = cluster_capacities(lab, block, slack=slack)
     total = int(caps.sum())
@@ -493,7 +512,7 @@ def visit_lists(keep, *, bucket_visits: bool = True) -> VisitLists:
     row's count are masked out in-kernel (they replay the row's first kept
     tile, keeping the DMA stream warm and valid).
     """
-    k = np.asarray(keep)
+    k = to_host(keep)
     mt, t = k.shape
     counts = k.sum(axis=1).astype(np.int32)
     kmax = max(int(counts.max(initial=0)), 1)
@@ -581,7 +600,8 @@ def epsilon_for_density_error(abs_err: float, d: int, h: float) -> float:
 
 
 __all__ = [
-    "PAD_VALUE", "UNDERFLOW_ARG", "MARGIN", "KINDS", "SpatialIndex",
+    "PAD_VALUE", "UNDERFLOW_ARG", "MARGIN", "KINDS", "HOST_SYNC_BYTES",
+    "to_host", "SpatialIndex",
     "ClusterLayout", "TileMeta", "TileMap", "VisitLists",
     "default_n_clusters", "build_index", "assign", "cluster_capacities",
     "cluster_slots", "place_points", "cluster_layout", "tile_metadata",

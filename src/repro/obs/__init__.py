@@ -13,8 +13,10 @@ One process-wide layer every subsystem reports into:
 Metrics (counters / gauges / fixed log-bucketed histograms — bounded
 state, no sample lists) are ON by default; trace spans (bounded ring
 buffer, parent ids, monotonic µs timestamps) are OFF by default and cost
-one branch per ``span()`` call while off.  ``obs.configure(metrics=...,
-trace=...)`` flips either plane at runtime.
+one branch per ``span()`` call while off.  While on, every span is also a
+profiler ``TraceAnnotation`` carrying its ``span_id``, so under
+``jax.profiler.trace`` it lies on the device timeline's clock.
+``obs.configure(metrics=..., trace=...)`` flips either plane at runtime.
 
 Export surfaces:
 
@@ -28,8 +30,9 @@ Instrumented layers: ``serve/engine.py`` (request → dispatch → bucket →
 compile spans, latency + staleness + pad-ratio histograms),
 ``serve/batching.py`` (bucket-cache hit/miss/eviction counters),
 ``stream/estimator.py`` (append/evict/flush/rebuild spans, dirty-tile and
-slack-occupancy gauges), ``kernels/ops.py`` (prune visit fraction,
-certificate budgets, kernel-launch profiler annotations) and
+slack-occupancy gauges), ``kernels/ops.py`` and ``kernels/spatial.py``
+(a ``kernels.prune.pass`` span with one span per host step, dense launch
+spans, prune visit fraction, certificate budgets, host-sync bytes) and
 ``kernels/autotune.py`` (resolve decisions, probe timings, occupancy
 updates).  See docs/architecture.md § Observability for the span
 taxonomy and metric names.
@@ -53,8 +56,8 @@ from repro.obs import state
 from repro.obs.state import configure, enabled
 from repro.obs.trace import (
     Span,
-    annotate,
     clear_trace,
+    current_span,
     set_trace_capacity,
     span,
     span_tree,
@@ -67,6 +70,6 @@ __all__ = [
     "counter", "gauge", "histogram",
     "log_bucket_bounds", "lint_prometheus",
     "metrics_snapshot", "prometheus_text",
-    "Span", "span", "annotate",
+    "Span", "span", "current_span",
     "trace_events", "clear_trace", "set_trace_capacity", "span_tree",
 ]

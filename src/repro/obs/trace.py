@@ -17,10 +17,10 @@ event carries:
 When tracing is disabled (the default) ``span()`` returns one shared
 no-op context manager: the hot loop pays an attribute read and a branch.
 
-``annotate(name)`` additionally brackets a region with
-``jax.profiler.TraceAnnotation`` when tracing is on and a profiler is
-available, so kernel launches line up with device timelines in
-``jax.profiler.trace`` captures; it degrades to a no-op everywhere else.
+Every live span is also a ``jax.profiler.TraceAnnotation`` named like the
+span and carrying its ``span_id``, ``parent_id`` and the attributes given
+at entry, so under ``jax.profiler.trace`` the host steps sit on the same
+clock as the device timeline and join the ring-buffer events by id.
 """
 
 from __future__ import annotations
@@ -30,6 +30,8 @@ import threading
 import time
 from collections import deque
 from typing import Deque, Dict, List, Optional
+
+from jax.profiler import TraceAnnotation
 
 from repro.obs import state
 
@@ -42,7 +44,7 @@ _EVENTS: Deque[dict] = deque(maxlen=DEFAULT_CAPACITY)
 _TLS = threading.local()
 
 
-def _stack() -> List[int]:
+def _stack() -> List["Span"]:
     st = getattr(_TLS, "stack", None)
     if st is None:
         st = _TLS.stack = []
@@ -73,6 +75,9 @@ class _NullSpan:
     def set(self, **attrs):
         return self
 
+    def add(self, **amounts):
+        return self
+
 
 _NULL_SPAN = _NullSpan()
 
@@ -80,7 +85,7 @@ _NULL_SPAN = _NullSpan()
 class Span:
     """One live span; use via ``with obs.span("serve.dispatch", ...):``."""
 
-    __slots__ = ("name", "attrs", "id", "parent", "_t0")
+    __slots__ = ("name", "attrs", "id", "parent", "_t0", "_annotation")
 
     def __init__(self, name: str, attrs: Dict):
         self.name = name
@@ -88,6 +93,7 @@ class Span:
         self.id = next(_SEQ)
         self.parent: Optional[int] = None
         self._t0 = 0
+        self._annotation = None
 
     def set(self, **attrs) -> "Span":
         """Attach attributes discovered mid-span (e.g. cache hit/miss)."""
@@ -95,17 +101,30 @@ class Span:
             self.attrs[k] = _safe(v)
         return self
 
+    def add(self, **amounts) -> "Span":
+        """Add to numeric attributes (absent ones start at 0)."""
+        for k, v in amounts.items():
+            self.attrs[k] = self.attrs.get(k, 0) + _safe(v)
+        return self
+
     def __enter__(self) -> "Span":
         st = _stack()
-        self.parent = st[-1] if st else None
-        st.append(self.id)
+        self.parent = st[-1].id if st else None
+        st.append(self)
+        if state.trace_on:
+            self._annotation = TraceAnnotation(
+                self.name, span_id=self.id, parent_id=self.parent or 0,
+                **self.attrs)
+            self._annotation.__enter__()
         self._t0 = time.perf_counter_ns()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
         dur_ns = time.perf_counter_ns() - self._t0
+        if self._annotation is not None:
+            self._annotation.__exit__(exc_type, exc, tb)
         st = _stack()
-        if st and st[-1] == self.id:
+        if st and st[-1] is self:
             st.pop()
         if exc_type is not None:
             self.attrs["error"] = exc_type.__name__
@@ -126,6 +145,12 @@ def span(name: str, **attrs):
     if not state.trace_on:
         return _NULL_SPAN
     return Span(name, attrs)
+
+
+def current_span():
+    """The innermost span open on this thread (the shared no-op if none)."""
+    st = _stack()
+    return st[-1] if st else _NULL_SPAN
 
 
 def trace_events() -> List[dict]:
@@ -155,38 +180,7 @@ def span_tree(events: Optional[List[dict]] = None) -> Dict[Optional[int],
     return by_parent
 
 
-class _Annotation:
-    """TraceAnnotation when available + tracing on; no-op otherwise."""
-
-    __slots__ = ("_inner",)
-
-    def __init__(self, name: str):
-        self._inner = None
-        if state.trace_on:
-            try:
-                from jax.profiler import TraceAnnotation
-
-                self._inner = TraceAnnotation(name)
-            except Exception:  # noqa: BLE001 - profiler optional everywhere
-                self._inner = None
-
-    def __enter__(self):
-        if self._inner is not None:
-            self._inner.__enter__()
-        return self
-
-    def __exit__(self, *exc):
-        if self._inner is not None:
-            self._inner.__exit__(*exc)
-        return False
-
-
-def annotate(name: str) -> _Annotation:
-    """Bracket a kernel launch for ``jax.profiler`` device timelines."""
-    return _Annotation(name)
-
-
 __all__ = [
-    "DEFAULT_CAPACITY", "Span", "span", "annotate",
+    "DEFAULT_CAPACITY", "Span", "span", "current_span",
     "trace_events", "clear_trace", "set_trace_capacity", "span_tree",
 ]

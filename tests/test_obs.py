@@ -264,6 +264,67 @@ def test_trace_disabled_is_null_span_and_records_nothing():
     assert obs.span("x") is obs.span("y")  # one shared no-op object
 
 
+def test_span_is_a_profiler_host_event_joined_by_id(tmp_path):
+    from jax.profiler import ProfileData
+
+    obs.configure(trace=True)
+    obs.clear_trace()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with obs.span("t.parent", rows=3, kind="kde"):
+            with obs.span("t.child") as child:
+                child.set(late=1)
+    finally:
+        jax.profiler.stop_trace()
+    ring = {e["name"]: e for e in obs.trace_events()}
+    (path,) = tmp_path.rglob("*.xplane.pb")
+    host = {e.name: e
+            for plane in ProfileData.from_file(str(path)).planes
+            if plane.name == "/host:CPU"
+            for line in plane.lines for e in line.events
+            if e.name.startswith("t.")}
+    assert set(host) == {"t.parent", "t.child"}
+    parent, child = (dict(host[n].stats) for n in ("t.parent", "t.child"))
+    assert parent["span_id"] == ring["t.parent"]["id"]
+    assert parent["parent_id"] == 0 and ring["t.parent"]["parent"] is None
+    assert child["span_id"] == ring["t.child"]["id"]
+    assert child["parent_id"] == ring["t.parent"]["id"]
+    assert parent["rows"] == 3 and parent["kind"] == "kde"
+    assert "late" not in child                  # attributes at entry only
+    p, c = host["t.parent"], host["t.child"]
+    assert p.start_ns <= c.start_ns
+    assert c.start_ns + c.duration_ns <= p.start_ns + p.duration_ns
+
+
+def test_disabled_span_constructs_no_profiler_annotation(monkeypatch):
+    from repro.obs import trace
+
+    made = []
+
+    class Counting:
+        def __init__(self, name, **kw):
+            made.append(name)
+
+        def __enter__(self):
+            return self
+
+        def __exit__(self, *exc):
+            return False
+
+    monkeypatch.setattr(trace, "TraceAnnotation", Counting)
+    with obs.span("t.off", a=1):
+        pass
+    assert made == [] and obs.span("t.x") is trace._NULL_SPAN
+    obs.configure(trace=True)
+    with obs.span("t.on"):
+        pass
+    assert made == ["t.on"]
+
+
+def test_annotate_is_no_longer_exported():
+    assert not hasattr(obs, "annotate") and "annotate" not in obs.__all__
+
+
 # ---------------------------------------------------------------------------
 # Streaming: staleness histogram agrees with the engine's summary.
 # ---------------------------------------------------------------------------
@@ -345,8 +406,12 @@ def test_streaming_soak_trace_reconstruction(data):
         assert b["cache"] in ("hit", "miss")
         assert b["pad_ratio"] == pytest.approx(b["bucket"] / b["rows"],
                                                rel=1e-3)
-        # bucket -> pruned kernel launch: per-request prune occupancy
-        kern = [c for c in tree.get(buck[0]["id"], ())
+        # bucket -> pruned pass -> kernel launch: per-request occupancy
+        pas = [c for c in tree.get(buck[0]["id"], ())
+               if c["name"] == "kernels.prune.pass"]
+        assert len(pas) == 1, "one pruned pass under each bucket span"
+        assert pas[0]["attrs"]["rows"] == b["rows"]
+        kern = [c for c in tree.get(pas[0]["id"], ())
                 if c["name"] == "kernels.pruned_eval"]
         assert kern, "pruned launch span missing under bucket span"
         assert 0.0 < kern[0]["attrs"]["occupancy"] <= 1.0
