@@ -250,8 +250,18 @@ def _resolve(block_m, block_n, rows, cols, d, *, out_width, precision,
 # ---------------------------------------------------------------------------
 
 
-def _score_operands(xp: jnp.ndarray, precision: str):
-    """(x_ops, xt_ops, xaug_ops, nrm, xrec) for a padded train set."""
+def _score_operands(xp: jnp.ndarray, precision: str, packed: bool = False):
+    """(x_ops, xt_ops, xaug_ops, nrm, xrec) for a padded train set.
+
+    ``packed`` (the pruned path at the f32 tier, ``prec.packs``): the
+    rows are ``prec.pack_rows``, the columns ``prec.column_planes``, which
+    are the [X | 1] weights too (``xaug_ops`` is (None, None)); norms stay
+    the f32 points'."""
+    if packed:
+        x32 = xp.astype(jnp.float32)
+        return ((prec.pack_rows(x32), None),
+                (prec.column_planes(x32), None), (None, None),
+                _norms(x32), x32)
     npad = xp.shape[0]
     xaug = jnp.concatenate([xp, jnp.ones((npad, 1), xp.dtype)], axis=1)
     if precision == "f32":
@@ -346,7 +356,9 @@ def _score_stats_pruned(
         )
     xp = layout.points
     with obs.span("kernels.prune.operands"):
-        x_ops, xt_ops, xaug_ops, nrm, xrec = _score_operands(xp, precision)
+        packed = prec.packs(precision)
+        x_ops, xt_ops, xaug_ops, nrm, xrec = _score_operands(
+            xp, precision, packed=packed)
         col_meta = spatial.tile_metadata(xrec, layout.real, block=block_n)
     with obs.span("kernels.prune.tile_map"):
         tm = spatial.tile_map(xrec, col_meta, _inv2h2(h), epsilon,
@@ -371,7 +383,7 @@ def _score_stats_pruned(
             vl.counts, vl.tile_map, x_ops[0], nrm, xt_ops[0], xaug_ops[0],
             _inv2h2(h), x_ops[1], xt_ops[1], xaug_ops[1],
             block_m=block_m, block_n=block_n, max_visits=vl.max_visits,
-            interpret=interpret,
+            interpret=interpret, packed=packed,
         )
     with obs.span("kernels.prune.gather"):
         rows = s1aug[layout.slots]
@@ -674,6 +686,9 @@ class TrainColumns(NamedTuple):
     index: Optional[spatial.SpatialIndex] = None
     meta_fine: Optional[spatial.TileMeta] = None
     block_n: int = 0                # prepare-time column-tile width
+    # (plane_rows(d), n_padded) bf16 ``prec.column_planes``: what the pruned
+    # kernels stream at the f32 tier (``prec.packs``); None elsewhere
+    planes: Optional[jnp.ndarray] = None
 
 
 def prepare_train_columns(
@@ -738,7 +753,8 @@ def columns_from_layout(
     and calls this to (re)build the per-tier cast planes + norms + tile
     metadata; ``prepare_train_columns`` routes through here too, so both
     paths share one casting/metadata recipe.  ``real=None`` means a plain
-    tail-padded (non-clustered) layout: no metadata is attached.
+    tail-padded (non-clustered) layout: no metadata is attached, and no
+    packed planes (only the pruned kernels read them).
     """
     prec.validate(precision)
     if precision == "f32":
@@ -750,13 +766,16 @@ def columns_from_layout(
         xt, xt_lo = x_hi.T, None if x_lo is None else x_lo.T
         xrec = prec.reconstruct(x_hi, x_lo)
         nrm_x = _norms(xrec).reshape(1, -1)
-    meta = meta_fine = None
+    meta = meta_fine = planes = None
     if real is not None:
         meta = spatial.tile_metadata(xrec, real, block=block_n)
         fine = autotune.FINE_PROBE_BLOCK
         if block_n > fine and xp.shape[0] % fine == 0:
             meta_fine = spatial.tile_metadata(xrec, real, block=fine)
-    return TrainColumns(xt, xt_lo, nrm_x, meta, index, meta_fine, block_n)
+        if prec.packs(precision):
+            planes = prec.column_planes(xrec)
+    return TrainColumns(xt, xt_lo, nrm_x, meta, index, meta_fine, block_n,
+                        planes)
 
 
 def update_train_columns(
@@ -800,6 +819,9 @@ def update_train_columns(
         cols.xt_lo.at[:, rows].set(lo.T)
     )
     nrm_x = cols.nrm_x.at[0, rows].set(_norms(rec)[:, 0])
+    planes = cols.planes if cols.planes is None else (
+        cols.planes.at[:, rows].set(prec.column_planes(rec))
+    )
     meta, meta_fine = cols.meta, cols.meta_fine
     if meta is not None:
         mask = jnp.asarray(real)[rows]
@@ -823,11 +845,15 @@ def update_train_columns(
                 ),
             )
     return cols._replace(xt=xt, xt_lo=xt_lo, nrm_x=nrm_x, meta=meta,
-                         meta_fine=meta_fine)
+                         meta_fine=meta_fine, planes=planes)
 
 
-def _cast_queries(yp: jnp.ndarray, precision: str):
-    """(y_hi, y_lo, nrm_y, yrec) for a padded query block at one tier."""
+def _cast_queries(yp: jnp.ndarray, precision: str, packed: bool = False):
+    """(y_hi, y_lo, nrm_y, yrec) for a padded query block at one tier;
+    ``packed``: y_hi is ``prec.pack_rows`` of the f32 queries."""
+    if packed:
+        yrec = yp.astype(jnp.float32)
+        return prec.pack_rows(yrec), None, _norms(yrec), yrec
     if precision == "f32":
         y_hi, y_lo = yp, None
         yrec = yp.astype(jnp.float32)
@@ -882,8 +908,16 @@ def _pruned_eval_sums(
             jnp.asarray(y[:nr], jnp.float32), labels, block_m,
             bucket_rows=True,
         )
+    packed = prec.packs(precision)
+    if (cols.planes is not None) != packed:
+        raise ValueError(
+            f"pruned evaluation at precision {precision!r} needs columns "
+            f"prepared at that tier ({'with' if packed else 'without'} the "
+            "packed f32 planes)"
+        )
     with obs.span("kernels.prune.operands"):
-        y_hi, y_lo, nrm_y, yrec = _cast_queries(qlayout.points, precision)
+        y_hi, y_lo, nrm_y, yrec = _cast_queries(qlayout.points, precision,
+                                                packed=packed)
     kind = "laplace" if laplace else "kde"
     with obs.span("kernels.prune.tile_map"):
         tm = spatial.tile_map(yrec, cols.meta, _inv2h2(h), epsilon,
@@ -904,10 +938,11 @@ def _pruned_eval_sums(
                   occupancy=round(vl.occupancy, 4),
                   max_visits=vl.max_visits):
         sums = flash_pruned.flash_kde_pallas_pruned(
-            vl.counts, vl.tile_map, y_hi, nrm_y, cols.xt, cols.nrm_x,
+            vl.counts, vl.tile_map, y_hi, nrm_y,
+            cols.planes if packed else cols.xt, cols.nrm_x,
             _inv2h2(h), y_lo, cols.xt_lo,
             block_m=block_m, block_n=block_n, max_visits=vl.max_visits,
-            interpret=interpret, laplace=laplace,
+            interpret=interpret, laplace=laplace, packed=packed,
         )
     with obs.span("kernels.prune.gather"):
         out = sums[qlayout.slots, 0]             # back to request order

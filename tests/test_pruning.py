@@ -213,40 +213,70 @@ def _tol(tier):
     return dict(rtol=1e-6, atol=1e-20)
 
 
+def _exact_reference(tier, run, dense, visit_every_tile):
+    """What eps=0 pruned sums must equal to the bar of ``_tol``: the dense
+    kernels' sums where the tier shares their GEMM arithmetic, else (the
+    f32 tier, whose pruned kernels pack their GEMMs, ``prec.packs``) the
+    same pruned kernels' sums over every column tile.  ``run()`` runs
+    the pruned path."""
+    if not prec.packs(tier):
+        return dense
+    with visit_every_tile():
+        return run()
+
+
 @pytest.mark.parametrize("tier", TIERS)
-def test_exact_mode_kde_matches_dense(tier):
+def test_exact_mode_kde_matches_dense(tier, visit_every_tile, f64,
+                                      assert_as_accurate):
     x, y = _clustered(900, 6, seed=20), _clustered(300, 6, seed=21)
     kw = dict(precision=tier, block_m=32, block_n=128, interpret=True)
     dense = ops.flash_kde(x, y, 0.35, prune="off", **kw)
-    pruned = ops.flash_kde(x, y, 0.35, prune=0.0, **kw)
-    np.testing.assert_allclose(np.asarray(pruned), np.asarray(dense),
+    run = lambda: ops.flash_kde(x, y, 0.35, prune=0.0, **kw)  # noqa: E731
+    pruned = run()
+    ref = _exact_reference(tier, run, dense, visit_every_tile)
+    np.testing.assert_allclose(np.asarray(pruned), np.asarray(ref),
                                **_tol(tier))
+    if ref is not dense:
+        assert_as_accurate(pruned, dense, f64.kde(x, y, 0.35))
 
 
 @pytest.mark.parametrize("tier", TIERS)
-def test_exact_mode_laplace_matches_dense(tier):
+def test_exact_mode_laplace_matches_dense(tier, visit_every_tile, f64,
+                                          assert_as_accurate):
     x, y = _clustered(900, 6, seed=22), _clustered(300, 6, seed=23)
     kw = dict(precision=tier, block_m=32, block_n=128, interpret=True)
     dense = ops.flash_laplace_kde(x, y, 0.35, prune="off", **kw)
-    pruned = ops.flash_laplace_kde(x, y, 0.35, prune=0.0, **kw)
+    run = lambda: ops.flash_laplace_kde(  # noqa: E731
+        x, y, 0.35, prune=0.0, **kw)
+    pruned = run()
+    ref = _exact_reference(tier, run, dense, visit_every_tile)
     # Laplace sums cross zero; bound the deviation against the row scale
-    scale = float(np.max(np.abs(np.asarray(dense)))) + 1e-30
+    scale = float(np.max(np.abs(np.asarray(ref)))) + 1e-30
     np.testing.assert_allclose(np.asarray(pruned) / scale,
-                               np.asarray(dense) / scale,
+                               np.asarray(ref) / scale,
                                rtol=0, atol=2e-6)
+    if ref is not dense:
+        assert_as_accurate(pruned, dense, f64.laplace(x, y, 0.35))
 
 
 @pytest.mark.parametrize("tier", TIERS)
-def test_exact_mode_score_stats_match_dense(tier):
+def test_exact_mode_score_stats_match_dense(tier, visit_every_tile, f64,
+                                            assert_as_accurate):
     x = _clustered(700, 5, seed=24)
     kw = dict(precision=tier, block_m=32, block_n=128, interpret=True)
     s0d, s1d = ops.flash_score_stats(x, 0.5, prune="off", **kw)
-    s0p, s1p = ops.flash_score_stats(x, 0.5, prune=0.0, **kw)
-    np.testing.assert_allclose(np.asarray(s0p), np.asarray(s0d), rtol=1e-6,
+    run = lambda: ops.flash_score_stats(x, 0.5, prune=0.0, **kw)  # noqa
+    s0p, s1p = run()
+    s0r, s1r = _exact_reference(tier, run, (s0d, s1d), visit_every_tile)
+    np.testing.assert_allclose(np.asarray(s0p), np.asarray(s0r), rtol=1e-6,
                                atol=1e-20)
-    scale = float(np.max(np.abs(np.asarray(s1d)))) + 1e-30
+    scale = float(np.max(np.abs(np.asarray(s1r)))) + 1e-30
     np.testing.assert_allclose(np.asarray(s1p) / scale,
-                               np.asarray(s1d) / scale, rtol=0, atol=2e-6)
+                               np.asarray(s1r) / scale, rtol=0, atol=2e-6)
+    if prec.packs(tier):
+        w0, w1 = f64.score(x, 0.5)
+        assert_as_accurate(s0p, s0d, w0)
+        assert_as_accurate(s1p, s1d, w1)
 
 
 def test_exact_mode_far_queries_underflow_consistent():
@@ -260,15 +290,17 @@ def test_exact_mode_far_queries_underflow_consistent():
     np.testing.assert_array_equal(pruned, 0.0)
 
 
+@pytest.mark.parametrize("d", [4, 16])
 @pytest.mark.parametrize("kind", ["kde", "score"])
 @pytest.mark.parametrize("tier", ["f32", "bf16x2"])
-def test_row_group_launches_match_one_launch(monkeypatch, kind, tier):
+def test_row_group_launches_match_one_launch(monkeypatch, kind, tier, d):
     """A tile map too wide for SMEM runs as one launch per group of row
-    tiles; every group's rows must land where one launch puts them."""
+    tiles; every group's rows must land where one launch puts them (at
+    the f32 tier, the packed kernels' rows and planes too)."""
     from repro import obs
     from repro.kernels import flash_pruned
 
-    x, y = _clustered(640, 4, seed=38), _clustered(200, 4, seed=39)
+    x, y = _clustered(640, d, seed=38), _clustered(200, d, seed=39)
     kw = dict(precision=tier, block_m=32, block_n=128, interpret=True,
               prune=0.0)
     kernel = (flash_pruned.flash_kde_pallas_pruned if kind == "kde"
@@ -291,6 +323,90 @@ def test_row_group_launches_match_one_launch(monkeypatch, kind, tier):
     assert one == 1 and traces.value - before > 2  # one launch per row tile
     for a, b in zip(grouped, whole):
         np.testing.assert_array_equal(a, b)
+
+
+@pytest.mark.parametrize("d", [2, 5, 16, 22])
+@pytest.mark.parametrize("kind", ["kde", "laplace", "score"])
+def test_packed_pruned_kernels_match_dense_highest(kind, d, f64,
+                                                   assert_as_accurate):
+    """At the f32 tier the pruned kernels run their GEMMs packed and the
+    dense kernels at HIGHEST: eps=0 pruned sums are as accurate, against
+    float64, as the dense ones."""
+    from repro import obs
+    from repro.kernels import flash_pruned
+
+    assert prec.packs("f32")
+    x, y = _clustered(600, d, seed=50 + d), _clustered(160, d, seed=60 + d)
+    h = 0.35
+    kw = dict(precision="f32", block_m=32, block_n=128, interpret=True)
+    kernel = (flash_pruned.flash_score_pallas_pruned if kind == "score"
+              else flash_pruned.flash_kde_pallas_pruned)
+    packed = obs.counter("kernels.f32_gemm_path", labels={
+        "kernel": kernel.__name__, "gemm": "gram", "path": "packed"})
+    before = packed.value
+    kernel.clear_cache()          # the path is counted at trace time
+    if kind == "score":
+        s0d, s1d = ops.flash_score_stats(x, h, prune="off", **kw)
+        s0p, s1p = ops.flash_score_stats(x, h, prune=0.0, **kw)
+        w0, w1 = f64.score(x, h)
+        assert_as_accurate(s0p, s0d, w0)
+        assert_as_accurate(s1p, s1d, w1)
+    else:
+        fn = ops.flash_kde if kind == "kde" else ops.flash_laplace_kde
+        dense = fn(x, y, h, prune="off", **kw)
+        pruned = fn(x, y, h, prune=0.0, **kw)
+        want = (f64.kde if kind == "kde" else f64.laplace)(x, y, h)
+        assert_as_accurate(pruned, dense, want)
+    assert packed.value > before
+
+
+def test_packed_planes_follow_a_streaming_append(f64, assert_as_accurate):
+    """A streaming append re-casts the touched tiles' columns: the f32
+    tier's packed planes stay in step, and the pruned sums over the new
+    live set are as accurate as the dense HIGHEST ones."""
+    d, h = 4, 0.35
+    x, xa = _clustered(700, d, seed=70), _clustered(24, d, seed=71)
+    y = _clustered(96, d, seed=72)
+    cols = ops.prepare_train_columns(x, block_n=128, precision="f32",
+                                     clustered=True)
+    assert cols.planes is not None
+    layout = spatial.cluster_layout(jnp.asarray(x),
+                                    np.asarray(cols.index.labels), 128)
+    xp = np.asarray(layout.points).copy()
+    real = np.asarray(layout.real).copy()
+    slack = np.flatnonzero(~real)[:len(xa)]       # free slots take xa
+    xp[slack], real[slack] = xa, True
+    tiles = np.unique(slack // 128)
+    upd = ops.update_train_columns(cols, jnp.asarray(xp), jnp.asarray(real),
+                                   tiles, precision="f32")
+    np.testing.assert_array_equal(
+        np.asarray(upd.planes),
+        np.asarray(prec.column_planes(jnp.asarray(xp))))
+    live = xp[real]
+    yp = ops._pad_to(jnp.asarray(y, jnp.float32), 32)
+    kw = dict(precision="f32", block_m=32, block_n=128, interpret=True)
+    pruned = ops.flash_kde_prepared(yp, upd.xt, upd.nrm_x, h, prune=0.0,
+                                    columns=upd, n_real=len(y), **kw)
+    norm = len(live) * (2 * math.pi) ** (d / 2) * h ** d
+    dense = ops.flash_kde(live, y, h, prune="off", **kw)
+    assert_as_accurate(np.asarray(pruned)[:len(y)] / norm, dense,
+                       f64.kde(live, y, h))
+
+
+@pytest.mark.parametrize("prepared,asked", [("f32", "bf16"), ("bf16", "f32")])
+def test_pruned_eval_refuses_columns_of_another_tier(prepared, asked):
+    """The f32 tier's pruned kernels read the packed planes, which only
+    f32 columns carry: columns prepared at another tier are refused."""
+    x, y = _clustered(512, 4, seed=73), _clustered(64, 4, seed=74)
+    cols = ops.prepare_train_columns(x, block_n=128, precision=prepared,
+                                     clustered=True)
+    assert (cols.planes is not None) == prec.packs(prepared)
+    yp = ops._pad_to(jnp.asarray(y, jnp.float32), 32)
+    with pytest.raises(ValueError, match="columns prepared at that tier"):
+        ops.flash_kde_prepared(yp, cols.xt, cols.nrm_x, 0.35, cols.xt_lo,
+                               prune=0.0, columns=cols, n_real=len(y),
+                               precision=asked, block_m=32, block_n=128,
+                               interpret=True)
 
 
 def test_epsilon_error_within_loose_budget():
@@ -482,7 +598,8 @@ def test_serve_pruned_matches_reference():
     assert cols_bf16.index is cols_f32.index
 
 
-def test_serve_prune_off_unchanged():
+def test_serve_prune_off_unchanged(visit_every_tile, f64,
+                                   assert_as_accurate):
     from repro.serve import ServeConfig, ServeEngine
 
     x = _clustered(512, 4, seed=36)
@@ -495,9 +612,13 @@ def test_serve_prune_off_unchanged():
                                   prune="off", min_batch=32, max_batch=128))
     on.register("k", x, h=0.3)
     off.register("k", x, h=0.3)
-    np.testing.assert_allclose(np.asarray(_q(on, "k", y)),
-                               np.asarray(_q(off, "k", y)),
-                               rtol=1e-6, atol=1e-20)
+    got, dense = np.asarray(_q(on, "k", y)), np.asarray(_q(off, "k", y))
+    # f32 at d=4: the pruned kernels pack their GEMMs, the dense ones not
+    assert prec.packs(on.config.precision)
+    with visit_every_tile():
+        every = np.asarray(_q(on, "k", y))
+    np.testing.assert_allclose(got, every, rtol=1e-6, atol=1e-20)
+    assert_as_accurate(got, dense, f64.kde(x, y, 0.3))
 
 
 def test_serve_config_validates_prune():

@@ -177,6 +177,10 @@ def test_update_train_columns_matches_fresh_prepare(data):
         if tier == "bf16x2":
             np.testing.assert_array_equal(np.asarray(upd.xt_lo),
                                           np.asarray(fresh.xt_lo))
+        else:                   # the f32 tier's packed planes, in step
+            assert upd.planes is not None
+            np.testing.assert_array_equal(np.asarray(upd.planes),
+                                          np.asarray(fresh.planes))
         np.testing.assert_array_equal(np.asarray(upd.nrm_x),
                                       np.asarray(fresh.nrm_x))
         for f in spatial.TileMeta._fields:

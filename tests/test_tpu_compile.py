@@ -8,7 +8,12 @@ unsupported Mosaic ops — before any chip time is spent.
 Shapes: the paper's Table 1 deployment (32768 x 16 train, 4096 queries)
 and its §7 workload (1048576 x 16 train, 131072 queries), at the tiles the
 autotuner's cost model picks for them (``measure=False``) and, at 1M, the
-estimator's default 128 x 512 launch.  Every precision tier.
+estimator's default 128 x 512 launch.  Every precision tier; the pruned
+kernels at the f32 tier take the packed operands ops builds for them
+(``precision.pack_rows`` / ``column_planes``), and the
+``kernels.f32_gemm_path`` counter shows they lowered the packed GEMMs —
+at D=16 and, at the Table 1 size, at dimensions whose planes do not sit
+on whole bf16 sublane tiles (2, 5) or take two MXU passes (22, 32).
 """
 
 import os
@@ -18,7 +23,9 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro import obs
 from repro.kernels import autotune
+from repro.kernels import precision as prec
 from repro.kernels.flash_kde import flash_kde_pallas
 from repro.kernels.flash_pruned import (flash_kde_pallas_pruned,
                                         flash_score_pallas_pruned)
@@ -69,7 +76,12 @@ def _visits(n, bn):
     return min(n // bn, 128)
 
 
-def _args(kernel, tier, size, bm, bn, sharding):
+def _packed(kernel, tier):
+    """The f32 tier's pruned kernels run their GEMMs packed."""
+    return kernel.endswith("pruned") and prec.packs(tier)
+
+
+def _args(kernel, tier, size, bm, bn, sharding, d=D):
     """ShapeDtypeStructs for one launch, in the launcher's positional order."""
     n, m = SIZES[size]
     op = jnp.float32 if tier == "f32" else jnp.bfloat16
@@ -80,17 +92,22 @@ def _args(kernel, tier, size, bm, bn, sharding):
 
     score = kernel.startswith("score")
     rows = n if score else m
-    args = [s((rows, D), op), s((rows, 1)), s((D, n), op)]
+    if _packed(kernel, tier):
+        bf = jnp.bfloat16
+        args = [s((rows, 6 * d), bf), s((rows, 1)),
+                s((prec.plane_rows(d), n), bf)]
+    else:
+        args = [s((rows, d), op), s((rows, 1)), s((d, n), op)]
     if score:
-        args.append(s((n, D + 1), op))
+        args.append(None if _packed(kernel, tier) else s((n, d + 1), op))
     else:
         args.append(s((1, n)))
     args.append(s((1, 1)))
     if score:
-        args += [s((n, D), op), s((D, n), op), s((n, D + 1), op)] if lo \
+        args += [s((n, d), op), s((d, n), op), s((n, d + 1), op)] if lo \
             else [None, None, None]
     else:
-        args += [s((m, D), op), s((D, n), op)] if lo else [None, None]
+        args += [s((m, d), op), s((d, n), op)] if lo else [None, None]
     if kernel.endswith("pruned"):
         visits = _visits(n, bn)
         args = [s((rows // bm,), jnp.int32),
@@ -110,5 +127,29 @@ def test_kernel_compiles_for_v5e(kernel, tier, size, tiles, one_chip):
     fn = {"kde": flash_kde_pallas, "score": flash_score_pallas,
           "kde_pruned": flash_kde_pallas_pruned,
           "score_pruned": flash_score_pallas_pruned}[kernel]
+    if kernel.endswith("pruned"):
+        kw["packed"] = _packed(kernel, tier)
+    _compiles_packed_as_counted(fn, args, kw, _packed(kernel, tier))
+
+
+def _compiles_packed_as_counted(fn, args, kw, packed):
+    def packed_traces():
+        return obs.counter("kernels.f32_gemm_path", labels={
+            "kernel": fn.__name__, "gemm": "gram", "path": "packed"}).value
+
+    before = packed_traces()
     compiled = fn.lower(*args, **kw).compile()
     assert "tpu_custom_call" in compiled.as_text()
+    assert (packed_traces() > before) == packed
+
+
+@pytest.mark.parametrize("d", [2, 5, 22, 32])
+@pytest.mark.parametrize("kernel", ["kde_pruned", "score_pruned"])
+def test_packed_kernels_compile_for_v5e_at_any_d(kernel, d, one_chip):
+    bm, bn = 128, 512
+    args = _args(kernel, "f32", "32k", bm, bn, one_chip, d=d)
+    fn = {"kde_pruned": flash_kde_pallas_pruned,
+          "score_pruned": flash_score_pallas_pruned}[kernel]
+    kw = dict(block_m=bm, block_n=bn, interpret=False, packed=True,
+              max_visits=_visits(SIZES["32k"][0], bn))
+    _compiles_packed_as_counted(fn, args, kw, True)
