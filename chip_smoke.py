@@ -10,8 +10,10 @@ reference at ``jax.default_matmul_precision("highest")``:
                                      # Table 1 serving, streaming, RFF cascade
   python chip_smoke.py --paper-1m    # + the paper's 1M x 16-d fit and
                                      # 131k-query evaluation
-  python chip_smoke.py --chips 4     # only the ring backend at 1M x 16-d
-                                     # across four chips vs one-device ref
+  python chip_smoke.py --chips 4     # only the ring backend across four
+                                     # chips: SDKDE at 64k x 16-d, then
+                                     # ServeEngine at 1M x 16-d, each vs
+                                     # the one-device reference
 
 Exits nonzero when JAX finds no TPU, or when any phase fails.  On success
 the last line of stdout is one JSON object naming the device.  Everything
@@ -313,6 +315,28 @@ def phase_paper_1m(seed: int, *, n=None, m=None, d=None, verify=1024):
           f"max rel err {worst:.3e}")
 
 
+def phase_ring_estimator(seed: int, *, n=65536, d=16, m=4096):
+    """SDKDE(backend="ring"): the Flash kernels on every device, rows
+    sharded (pruned at this n), every answer verified."""
+    import jax
+
+    from repro.core.estimator import SDKDE, EstimatorConfig
+
+    x, y = sample(d, n, m, seed)
+    est = SDKDE(config=EstimatorConfig(backend="ring"))
+    t0 = time.perf_counter()
+    est.fit(x).x_sd.block_until_ready()
+    fit_s = time.perf_counter() - t0
+    t0 = time.perf_counter()
+    dens = np.asarray(est.evaluate(y))
+    eval_s = time.perf_counter() - t0
+    want = reference_sdkde(x, y, est.h)
+    worst = check_close("ring estimator f32", dens, want, "f32")
+    print(f"ring estimator: n={n} d={d} queries={m} devices="
+          f"{len(jax.devices())} fit={fit_s:.2f}s evaluate={eval_s:.2f}s "
+          f"(first calls, compile included); max rel err {worst:.3e}")
+
+
 def phase_ring(seed: int, *, n=1048576, d=16, m=1024):
     """The multi-chip SD-KDE path: ServeEngine(backend="ring") over every
     device, verified against the one-device reference."""
@@ -371,7 +395,8 @@ def main(argv=None) -> int:
 
     t_all = time.perf_counter()
     if args.chips == 4:
-        phases = [("ring", lambda: phase_ring(args.seed))]
+        phases = [("ring_estimator", lambda: phase_ring_estimator(args.seed)),
+                  ("ring", lambda: phase_ring(args.seed))]
     else:
         phases = [("precision", lambda: phase_precision(args.seed)),
                   ("table1", lambda: phase_table1(args.seed, compiles)),

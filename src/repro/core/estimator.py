@@ -5,7 +5,9 @@ Backends:
   * ``pallas`` — the Flash kernels (``repro.kernels``): explicit VMEM tiling,
                  MXU GEMMs, sequential-grid streaming accumulation.  On CPU
                  they run in interpret mode (validation); on TPU, compiled.
-  * ``ring``   — multi-device ring-sharded execution (``repro.distributed``).
+  * ``ring``   — the Flash kernels on every local device
+                 (``repro.distributed.shard``): rows sharded, every chip
+                 holding all columns; one device is the ``pallas`` path.
 
 This is the "paper's contribution as a composable JAX module": estimators are
 pytrees of arrays + static config, usable under jit/vmap/shard_map.
@@ -71,9 +73,13 @@ class KDE:
                 interpret=cfg.interpret, prune=cfg.prune,
             )
         if cfg.backend == "ring":
-            from repro.distributed import ring
+            from repro.distributed import shard
 
-            return ring.ring_kde(x, y, self.h)
+            return shard.flash_kde(
+                x, y, self.h, precision=cfg.precision,
+                block_m=cfg.block_m, block_n=cfg.block_n,
+                interpret=cfg.interpret, prune=cfg.prune,
+            )
         return ref.kde_eval(x, y, self.h, block=cfg.block)
 
     __call__ = evaluate
@@ -115,10 +121,13 @@ class SDKDE(KDE):
                 interpret=cfg.interpret, prune=cfg.prune,
             )
         elif cfg.backend == "ring":
-            from repro.distributed import ring
+            from repro.distributed import shard
 
-            self.x_sd = ring.ring_sdkde_shift(
-                self.x_train, self.h, score_h=cfg.score_h
+            self.x_sd = shard.flash_sdkde_shift(
+                self.x_train, self.h, score_h=cfg.score_h,
+                precision=cfg.precision,
+                block_m=cfg.block_m, block_n=cfg.block_n,
+                interpret=cfg.interpret, prune=cfg.prune,
             )
         else:
             self.x_sd = ref.sdkde_shift(

@@ -1,4 +1,7 @@
-"""Ring-sharded SD-KDE: the paper's streaming accumulation, at mesh scale.
+"""Ring-sharded KDE evaluation: the paper's streaming accumulation, at mesh
+scale.  ``ServeEngine(backend="ring")`` evaluates here and
+``LaplaceKDE(backend="ring")`` too; the estimators' SD-KDE fit and KDE
+evaluation shard rows over the Flash kernels instead (``shard.py``).
 
 The single-chip Flash kernels stream column tiles HBM→VMEM; this module
 applies the same idea one level up the hierarchy: point-set *shards* are
@@ -20,7 +23,6 @@ reference path to float tolerance (tested in tests/test_distributed_kde.py).
 
 from __future__ import annotations
 
-import functools
 from typing import Callable, Sequence
 
 import jax
@@ -130,69 +132,6 @@ def _phi(sq, h):
 
 
 # ---------------------------------------------------------------------------
-# Ring score statistics (train × train).
-# ---------------------------------------------------------------------------
-
-
-def ring_score_stats(
-    x: jnp.ndarray,
-    h,
-    *,
-    mesh: Mesh | None = None,
-    data_axis: str = "data",
-    pod_axis: str | None = None,
-):
-    """(S0, S1) with rows and streamed columns sharded over the ring.
-
-    ``x`` must be evenly shardable over the ring axes (pad with
-    ``repro.core.kde.pad_rows`` first — sentinel rows contribute exactly 0).
-    """
-    mesh = default_mesh(data_axis) if mesh is None else mesh
-    axes = _row_axes(mesh, data_axis, pod_axis)
-    spec = P(axes, None)
-
-    def local(x_rows):
-        def body(acc, rows, cols):
-            s0, s1 = acc
-            phi = _phi(sqdist(rows, cols), h)
-            return s0 + jnp.sum(phi, axis=1), s1 + jnp.matmul(
-                phi, cols, precision=lax.Precision.HIGHEST)
-
-        def consume(acc, cols):
-            return chunked_consume(x_rows, cols, CHUNK, body, acc)
-
-        init = (
-            jnp.zeros(x_rows.shape[0], jnp.float32),
-            jnp.zeros(x_rows.shape, jnp.float32),
-        )
-        return _ring_scan(x_rows, init, consume, mesh, data_axis, pod_axis)
-
-    return jax.shard_map(
-        local, mesh=mesh, in_specs=(spec,), out_specs=(P(axes), spec)
-    )(x)
-
-
-def ring_sdkde_shift(
-    x: jnp.ndarray,
-    h,
-    *,
-    score_h=None,
-    mesh: Mesh | None = None,
-    data_axis: str = "data",
-    pod_axis: str | None = None,
-    eps: float = 1e-30,
-) -> jnp.ndarray:
-    """Debiased samples, rows staying sharded over the ring axes."""
-    mesh = default_mesh(data_axis) if mesh is None else mesh
-    sh = h if score_h is None else score_h
-    s0, s1 = ring_score_stats(
-        x, sh, mesh=mesh, data_axis=data_axis, pod_axis=pod_axis
-    )
-    score = (s1 - x * s0[:, None]) / (sh * sh * s0[:, None] + eps)
-    return x + 0.5 * h * h * score
-
-
-# ---------------------------------------------------------------------------
 # Ring KDE / Laplace evaluation (train × query).
 # ---------------------------------------------------------------------------
 
@@ -268,33 +207,6 @@ def ring_laplace_kde(
     return _ring_eval(
         x, y, h, w,
         n_true=n_true, mesh=mesh, data_axis=data_axis, pod_axis=pod_axis,
-    )
-
-
-def ring_sdkde(
-    x: jnp.ndarray,
-    y: jnp.ndarray,
-    h,
-    *,
-    score_h=None,
-    n_true: int | None = None,
-    mesh: Mesh | None = None,
-    data_axis: str = "data",
-    pod_axis: str | None = None,
-) -> jnp.ndarray:
-    """Full distributed SD-KDE: ring score pass → local shift → ring KDE.
-
-    This is the compiled program behind the ``flash_sdkde_*`` dry-run cells:
-    the paper's 1M-point workload sharded over a (pod, data, model) mesh.
-    """
-    n_true = int(x.shape[0]) if n_true is None else n_true
-    x_sd = ring_sdkde_shift(
-        x, h, score_h=score_h, mesh=mesh,
-        data_axis=data_axis, pod_axis=pod_axis,
-    )
-    return ring_kde(
-        x_sd, y, h, n_true=n_true, mesh=mesh,
-        data_axis=data_axis, pod_axis=pod_axis,
     )
 
 

@@ -38,6 +38,7 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -125,21 +126,46 @@ _EVAL = {(g, l): _make_eval_kernel(g, l)
 SMEM_TILE_MAP_BYTES = 256 * 1024
 
 
-def _by_row_groups(launch, counts, tile_map, rows, block_m: int):
+def _by_row_groups(launch, counts, tile_map, rows, block_m: int, name: str,
+                   packed: bool):
     """``launch(counts, tile_map, *rows)`` over row-tile groups whose tile
     map fits :data:`SMEM_TILE_MAP_BYTES`; ``rows`` are the row-tiled
-    operands (None passes through), outputs concatenate by row."""
+    operands (None passes through), outputs concatenate by row.  The whole
+    groups run as one ``lax.scan`` over a launch traced once (a program of
+    hundreds of unrolled launches took ~10 s to trace and lower), a shorter
+    last group as one more launch."""
     mt, visits = tile_map.shape
     per = max(1, SMEM_TILE_MAP_BYTES // (4 * visits))
     if per >= mt:
         return launch(counts, tile_map, *rows)
-    outs = []
-    for g in range(0, mt, per):
-        e = min(g + per, mt)
-        part = [None if a is None else a[g * block_m:e * block_m]
+    full = mt // per
+
+    def group(carry, g):
+        t0 = g * per
+        part = [None if a is None else
+                lax.dynamic_slice_in_dim(a, t0 * block_m, per * block_m)
                 for a in rows]
-        outs.append(launch(counts[g:e], tile_map[g:e], *part))
-    return jnp.concatenate(outs)
+        out = launch(lax.dynamic_slice_in_dim(counts, t0, per),
+                     lax.dynamic_slice_in_dim(tile_map, t0, per), *part)
+        # The compiler fuses the launch into the scan's stacking of the
+        # outputs.  At the bf16x2 tier's 512 x 4096 tiles that fusion ran
+        # out of scoped VMEM (compiled for a described v5e), so the
+        # unpacked tiers write a buffer of their own; the packed f32
+        # kernels keep the fusion, which ran 6% faster per step on a
+        # v5e than unrolled launches.
+        return carry, out if packed else lax.optimization_barrier(out)
+
+    # the launch keeps its kernel's name in the compiled program (the
+    # device trace names each kernel operation by it)
+    group = jax.named_call(group, name=name)
+    _, outs = lax.scan(group, None, jnp.arange(full))
+    out = outs.reshape(full * per * block_m, outs.shape[-1])
+    t0 = full * per
+    if t0 == mt:
+        return out
+    tail = launch(counts[t0:], tile_map[t0:],
+                  *[None if a is None else a[t0 * block_m:] for a in rows])
+    return jnp.concatenate([out, tail])
 
 
 @functools.partial(
@@ -206,7 +232,7 @@ def flash_kde_pallas_pruned(
         )(cnt, tm, *args)
 
     return _by_row_groups(launch, counts, tile_map, (y, nrm_y, y_lo),
-                          block_m)
+                          block_m, "flash_kde_pallas_pruned", packed)
 
 
 def _make_score_kernel(compensated: bool):
@@ -276,30 +302,35 @@ _SCORE = {"dot": _make_score_kernel(False), "split2": _make_score_kernel(True),
 def flash_score_pallas_pruned(
     counts: jnp.ndarray,     # (nt_rows,) int32
     tile_map: jnp.ndarray,   # (nt_rows, max_visits) int32
-    x: jnp.ndarray,          # (n, d) padded to block_m/block_n multiples
-    nrm: jnp.ndarray,        # (n, 1) f32
-    xt: jnp.ndarray,         # (d, n)
+    x: jnp.ndarray,          # (m, d) rows, padded to a block_m multiple
+    nrm: jnp.ndarray,        # (m, 1) f32
+    xt: jnp.ndarray,         # (d, n) columns, padded to a block_n multiple
     xaug: Optional[jnp.ndarray],  # (n, d+1) [X | 1]; None when packed:
-                             # x (n, 6d) and xt (plane_rows(d), n)
+                             # x (m, 6d) and xt (plane_rows(d), n)
     inv2h2: jnp.ndarray,     # (1, 1) f32
     x_lo: jnp.ndarray | None = None,
     xt_lo: jnp.ndarray | None = None,
     xaug_lo: jnp.ndarray | None = None,
     *,
+    nrm_cols: jnp.ndarray | None = None,  # (1, n) f32; None: rows == columns
     block_m: int = 128,
     block_n: int = 512,
     max_visits: int = 1,
     interpret: Optional[bool] = None,
     packed: bool = False,
 ) -> jnp.ndarray:
-    """Pruned score statistics S1aug (n, d+1) f32; ``packed``: the f32
-    tier's packed operands."""
-    n, width = x.shape
-    assert n % block_m == 0 and n % block_n == 0, (n, block_m, block_n)
+    """Pruned score statistics S1aug (m, d+1) f32 of the rows against the
+    columns; ``packed``: the f32 tier's packed operands.  Without
+    ``nrm_cols`` the rows are the columns (the train set against itself);
+    with it, the rows are a contiguous range of the column layout."""
+    m, width = x.shape
+    n = xt.shape[1]
+    assert m % block_m == 0 and n % block_n == 0, (m, n, block_m, block_n)
+    assert nrm_cols is not None or m == n, (m, n)
     los = (x_lo, xt_lo, xaug_lo)
     assert all(v is None for v in los) or all(v is not None for v in los), \
         "bf16x2 needs all three lo planes"
-    mt = n // block_m
+    mt = m // block_m
     assert counts.shape == (mt,) and tile_map.shape == (mt, max_visits), (
         counts.shape, tile_map.shape, mt, max_visits)
     gemm, d = _gemm(packed, x, xt, x_lo)
@@ -316,7 +347,8 @@ def flash_score_pallas_pruned(
     scalar = pl.BlockSpec((1, 1), lambda i, k, cnt, tm: (0, 0))
     out = pl.BlockSpec((block_m, out_w), lambda i, k, cnt, tm: (i, 0))
     kernel = _SCORE[gemm]
-    nrm_bcast = jnp.broadcast_to(nrm.reshape(1, -1), (1, n))
+    nrm_bcast = jnp.broadcast_to(nrm.reshape(1, -1), (1, n)) \
+        if nrm_cols is None else nrm_cols
 
     def launch(cnt, tm, x, nrm, x_lo):
         if packed:
@@ -343,7 +375,8 @@ def flash_score_pallas_pruned(
             compiler_params=compiler_params(),
         )(cnt, tm, *args)
 
-    acc = _by_row_groups(launch, counts, tile_map, (x, nrm, x_lo), block_m)
+    acc = _by_row_groups(launch, counts, tile_map, (x, nrm, x_lo), block_m,
+                         "flash_score_pallas_pruned", packed)
     return prec.reduce_planes(acc, d) if packed else acc
 
 

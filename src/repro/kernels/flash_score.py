@@ -87,18 +87,23 @@ def flash_score_pallas(
     xt_lo: jnp.ndarray | None = None,    # (d, n)   bf16 lo plane (bf16x2)
     xaug_lo: jnp.ndarray | None = None,  # (n, d+1) bf16 lo plane (bf16x2)
     *,
+    nrm_cols: jnp.ndarray | None = None,  # (1, n) f32; None: rows == columns
     block_m: int = 128,
     block_n: int = 512,
     interpret: Optional[bool] = None,
 ) -> jnp.ndarray:
-    """Raw kernel launch; returns S1aug (n, d+1) f32.  See ops.flash_score_stats
-    for the padded/normalized public wrapper."""
-    n, d = x.shape
-    assert n % block_m == 0 and n % block_n == 0, (n, block_m, block_n)
+    """Raw kernel launch; returns S1aug (m, d+1) f32 of the m rows of ``x``
+    against the n columns of ``xt``.  Without ``nrm_cols`` the rows are the
+    columns; with it, ``x`` may be a contiguous range of the column layout.
+    See ops.flash_score_stats for the padded/normalized public wrapper."""
+    m, d = x.shape
+    n = xt.shape[1]
+    assert m % block_m == 0 and n % block_n == 0, (m, n, block_m, block_n)
+    assert nrm_cols is not None or m == n, (m, n)
     los = (x_lo, xt_lo, xaug_lo)
     assert all(v is None for v in los) or all(v is not None for v in los), \
         "bf16x2 needs all three lo planes"
-    grid = (n // block_m, n // block_n)
+    grid = (m // block_m, n // block_n)
 
     row = pl.BlockSpec((block_m, d), lambda m, j: (m, 0))
     nrm_row = pl.BlockSpec((block_m, 1), lambda m, j: (m, 0))
@@ -107,7 +112,8 @@ def flash_score_pallas(
     nrm_col = pl.BlockSpec((1, block_n), lambda m, j: (0, j))
     scalar = pl.BlockSpec((1, 1), lambda m, j: (0, 0))
 
-    nrm_bcast = jnp.broadcast_to(nrm.reshape(1, -1), (1, n))
+    nrm_bcast = jnp.broadcast_to(nrm.reshape(1, -1), (1, n)) \
+        if nrm_cols is None else nrm_cols
     if x_lo is None:
         kernel = _score_kernel
         in_specs = [row, nrm_row, col, aug, nrm_col, scalar]
@@ -122,7 +128,7 @@ def flash_score_pallas(
         grid=grid,
         in_specs=in_specs,
         out_specs=pl.BlockSpec((block_m, d + 1), lambda m, j: (m, 0)),
-        out_shape=jax.ShapeDtypeStruct((n, d + 1), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((m, d + 1), jnp.float32),
         interpret=launch_interpret(interpret),
         compiler_params=compiler_params(),
     )(*args)

@@ -33,7 +33,7 @@ import functools
 import math
 import threading
 import weakref
-from typing import NamedTuple, Optional, Union
+from typing import NamedTuple, Optional, Tuple, Union
 
 import jax
 import jax.numpy as jnp
@@ -301,6 +301,30 @@ def _flash_score_stats_dense(
     return s0, s1
 
 
+@functools.partial(jax.jit, static_argnames=_STATIC + ("rows",))
+def dense_score_rows(
+    xp: jnp.ndarray,
+    h,
+    *,
+    rows: Tuple[int, int],
+    precision: str = "f32",
+    block_m=128,
+    block_n=512,
+    interpret: Optional[bool] = None,
+):
+    """Dense S1aug (r1 - r0, d+1) of the rows ``rows = (r0, r1)`` of a
+    padded train set against all of its columns (a row range of
+    ``_flash_score_stats_dense``)."""
+    r0, r1 = rows
+    x_ops, xt_ops, xaug_ops, nrm, _ = _score_operands(xp, precision)
+    lo = None if x_ops[1] is None else x_ops[1][r0:r1]
+    return flash_score_pallas(
+        x_ops[0][r0:r1], nrm[r0:r1], xt_ops[0], xaug_ops[0], _inv2h2(h),
+        lo, xt_ops[1], xaug_ops[1], nrm_cols=nrm.reshape(1, -1),
+        block_m=block_m, block_n=block_n, interpret=interpret,
+    )
+
+
 def _record_occupancy_profile(rows, col_counts, d, launch_occ, block_n,
                               yrec, meta_fine, inv2h2, epsilon, block_m,
                               kind):
@@ -326,6 +350,77 @@ def _record_occupancy_profile(rows, col_counts, d, launch_occ, block_n,
     fine_occ = float(spatial.to_host(jnp.mean(fine_tm.keep)))
     for n_key in col_counts:
         autotune.record_occupancy(rows, n_key, d, fine_occ, block_n=fine)
+
+
+class ScorePrep(NamedTuple):
+    """A pruned score pass's operands on one device: rows of a padded
+    cluster-aligned layout (all of them, or a contiguous range on one chip)
+    and every column of it, at the tier (``_score_operands``)."""
+
+    x_ops: tuple                 # row operands (hi, lo)
+    nrm: jnp.ndarray             # (R, 1) f32 squared norms of the rows
+    xrec: jnp.ndarray            # (R, d) f32 rows the kernels see
+    xt_ops: tuple                # column operands (hi, lo)
+    xaug_ops: tuple              # [X | 1] weights (hi, lo); None packed
+    nrm_cols: Optional[jnp.ndarray]  # (1, N); None: the rows are all N
+    meta: spatial.TileMeta       # column tiles at the launch width
+    meta_fine: Optional[spatial.TileMeta]  # at the tuner's probe width,
+    #                            # until its profile holds this regime
+
+
+def score_prep(xp: jnp.ndarray, real: jnp.ndarray, *, n: int,
+               precision: str, block_n: int) -> ScorePrep:
+    """Operands and column metadata of a padded cluster layout of ``n``
+    points, every row against every column, in the
+    ``kernels.prune.operands`` span."""
+    d = xp.shape[1]
+    fine = autotune.FINE_PROBE_BLOCK
+    with obs.span("kernels.prune.operands"):
+        x_ops, xt_ops, xaug_ops, nrm, xrec = _score_operands(
+            xp, precision, packed=prec.packs(precision))
+        meta = spatial.tile_metadata(xrec, real, block=block_n)
+        meta_fine = None
+        if block_n > fine and xp.shape[0] % fine == 0 \
+                and not autotune.has_occupancy(n, n, d, fine):
+            meta_fine = spatial.tile_metadata(xrec, real, block=fine)
+    return ScorePrep(x_ops, nrm, xrec, xt_ops, xaug_ops, None, meta,
+                     meta_fine)
+
+
+def score_tile_map(prep: ScorePrep, h, epsilon: float, *,
+                   block_m: int) -> spatial.TileMap:
+    """The bounds prepass of the prepared rows against every column tile,
+    in the ``kernels.prune.tile_map`` span."""
+    with obs.span("kernels.prune.tile_map"):
+        return spatial.tile_map(prep.xrec, prep.meta, _inv2h2(h), epsilon,
+                                block_m=block_m, kind="score")
+
+
+def score_launch(prep: ScorePrep, tm: spatial.TileMap, h, epsilon: float, *,
+                 n: int, real_rows: int, precision: str, block_m: int,
+                 block_n: int, interpret: Optional[bool],
+                 chip: int = 0) -> jnp.ndarray:
+    """Visit lists, tuner record and launch of one pruned score pass:
+    S1aug (rows, d+1) of the prepared rows against every column.  ``n``
+    is the train set's point count, ``real_rows`` the non-sentinel rows;
+    ``chip`` names the launch's device in its span."""
+    d = prep.xrec.shape[1]
+    with obs.span("kernels.prune.visit_lists"):
+        vl = spatial.visit_lists(tm.keep)
+    with obs.span("kernels.prune.profile"):
+        _record_occupancy_profile(n, {n}, d, vl.occupancy, block_n,
+                                  prep.xrec, prep.meta_fine, _inv2h2(h),
+                                  epsilon, block_m, "score")
+        _note_pruned_launch("score", vl, tm)
+    with obs.span("kernels.pruned_score", rows=real_rows,
+                  occupancy=round(vl.occupancy, 4), chip=chip):
+        return flash_pruned.flash_score_pallas_pruned(
+            vl.counts, vl.tile_map, prep.x_ops[0], prep.nrm,
+            prep.xt_ops[0], prep.xaug_ops[0], _inv2h2(h), prep.x_ops[1],
+            prep.xt_ops[1], prep.xaug_ops[1], nrm_cols=prep.nrm_cols,
+            block_m=block_m, block_n=block_n, max_visits=vl.max_visits,
+            interpret=interpret, packed=prec.packs(precision),
+        )
 
 
 def _score_stats_pruned(
@@ -354,37 +449,12 @@ def _score_stats_pruned(
             jnp.asarray(x, jnp.float32), index.labels, block_n,
             total_multiple=math.lcm(block_m, block_n),
         )
-    xp = layout.points
-    with obs.span("kernels.prune.operands"):
-        packed = prec.packs(precision)
-        x_ops, xt_ops, xaug_ops, nrm, xrec = _score_operands(
-            xp, precision, packed=packed)
-        col_meta = spatial.tile_metadata(xrec, layout.real, block=block_n)
-    with obs.span("kernels.prune.tile_map"):
-        tm = spatial.tile_map(xrec, col_meta, _inv2h2(h), epsilon,
-                              block_m=block_m, kind="score")
-    with obs.span("kernels.prune.visit_lists"):
-        vl = spatial.visit_lists(tm.keep)
-    with obs.span("kernels.prune.profile"):
-        fine_meta = None
-        if block_n > autotune.FINE_PROBE_BLOCK \
-                and xp.shape[0] % autotune.FINE_PROBE_BLOCK == 0 \
-                and not autotune.has_occupancy(n, n, d,
-                                               autotune.FINE_PROBE_BLOCK):
-            fine_meta = spatial.tile_metadata(
-                xrec, layout.real, block=autotune.FINE_PROBE_BLOCK)
-        _record_occupancy_profile(n, {n}, d, vl.occupancy, block_n, xrec,
-                                  fine_meta, _inv2h2(h), epsilon, block_m,
-                                  "score")
-        _note_pruned_launch("score", vl, tm)
-    with obs.span("kernels.pruned_score", rows=n,
-                  occupancy=round(vl.occupancy, 4)):
-        s1aug = flash_pruned.flash_score_pallas_pruned(
-            vl.counts, vl.tile_map, x_ops[0], nrm, xt_ops[0], xaug_ops[0],
-            _inv2h2(h), x_ops[1], xt_ops[1], xaug_ops[1],
-            block_m=block_m, block_n=block_n, max_visits=vl.max_visits,
-            interpret=interpret, packed=packed,
-        )
+    prep = score_prep(layout.points, layout.real, n=n, precision=precision,
+                      block_n=block_n)
+    tm = score_tile_map(prep, h, epsilon, block_m=block_m)
+    s1aug = score_launch(prep, tm, h, epsilon, n=n, real_rows=n,
+                         precision=precision, block_m=block_m,
+                         block_n=block_n, interpret=interpret)
     with obs.span("kernels.prune.gather"):
         rows = s1aug[layout.slots]
         return rows[:, d], rows[:, :d]
@@ -863,6 +933,93 @@ def _cast_queries(yp: jnp.ndarray, precision: str, packed: bool = False):
     return y_hi, y_lo, _norms(yrec), yrec
 
 
+def _check_pruned_columns(cols: TrainColumns, precision: str,
+                         block_n: int) -> None:
+    """Raise unless ``cols`` can serve a pruned launch at ``precision``
+    and ``block_n``."""
+    if cols.meta is None:
+        raise ValueError(
+            "pruned evaluation needs spatially prepared train columns "
+            "(prepare_train_columns(..., clustered=True))"
+        )
+    if cols.block_n != block_n:
+        raise ValueError(
+            "pruned launch block_n must match the width the columns were "
+            f"prepared at: launch {block_n} vs prepared {cols.block_n} — "
+            "the tile metadata and visit lists address tiles of that width"
+        )
+    packed = prec.packs(precision)
+    if (cols.planes is not None) != packed:
+        raise ValueError(
+            f"pruned evaluation at precision {precision!r} needs columns "
+            f"prepared at that tier ({'with' if packed else 'without'} the "
+            "packed f32 planes)"
+        )
+
+
+class EvalRows(NamedTuple):
+    """Padded, cluster-ordered query rows cast to a tier
+    (``_cast_queries``)."""
+
+    y_hi: jnp.ndarray
+    y_lo: Optional[jnp.ndarray]
+    nrm_y: jnp.ndarray
+    yrec: jnp.ndarray
+
+
+def eval_rows(yp: jnp.ndarray, precision: str) -> EvalRows:
+    """The tier casts of padded query rows, in the
+    ``kernels.prune.operands`` span."""
+    with obs.span("kernels.prune.operands"):
+        return EvalRows(*_cast_queries(yp, precision,
+                                       packed=prec.packs(precision)))
+
+
+def eval_tile_map(q: EvalRows, cols: TrainColumns, h, epsilon: float, *,
+                  block_m: int, kind: str) -> spatial.TileMap:
+    """The bounds prepass of query rows against prepared columns, in the
+    ``kernels.prune.tile_map`` span."""
+    with obs.span("kernels.prune.tile_map"):
+        return spatial.tile_map(q.yrec, cols.meta, _inv2h2(h), epsilon,
+                                block_m=block_m, kind=kind)
+
+
+def eval_launch(q: EvalRows, tm: spatial.TileMap, cols: TrainColumns, h,
+                epsilon: float, *, rows_key: int, real_rows: int,
+                precision: str, block_m: int, block_n: int,
+                interpret: Optional[bool], laplace: bool,
+                chip: int = 0) -> jnp.ndarray:
+    """Visit lists, tuner record and launch of one pruned density pass:
+    the (rows, 1) kernel sums of the query rows.  ``rows_key`` is the
+    query count the tuner's profile is recorded under, ``real_rows`` the
+    non-sentinel rows; ``chip`` names the launch's device in its span."""
+    d = q.yrec.shape[1]
+    kind = "laplace" if laplace else "kde"
+    packed = prec.packs(precision)
+    with obs.span("kernels.prune.visit_lists"):
+        vl = spatial.visit_lists(tm.keep)
+    with obs.span("kernels.prune.profile"):
+        # record under BOTH column counts a later resolve may key on: the
+        # true train count (flash_kde / flash_sdkde resolve pre-padding)
+        # and the padded layout length (the prepared serving path)
+        n_true = int(spatial.to_host(cols.meta.counts.sum()))
+        _record_occupancy_profile(rows_key, {n_true, cols.xt.shape[1]}, d,
+                                  vl.occupancy, block_n, q.yrec,
+                                  cols.meta_fine, _inv2h2(h), epsilon,
+                                  block_m, kind)
+        _note_pruned_launch(kind, vl, tm)
+    with obs.span("kernels.pruned_eval", rows=real_rows, kind=kind,
+                  occupancy=round(vl.occupancy, 4),
+                  max_visits=vl.max_visits, chip=chip):
+        return flash_pruned.flash_kde_pallas_pruned(
+            vl.counts, vl.tile_map, q.y_hi, q.nrm_y,
+            cols.planes if packed else cols.xt, cols.nrm_x,
+            _inv2h2(h), q.y_lo, cols.xt_lo,
+            block_m=block_m, block_n=block_n, max_visits=vl.max_visits,
+            interpret=interpret, laplace=laplace, packed=packed,
+        )
+
+
 def _pruned_eval_sums(
     y: jnp.ndarray,
     cols: TrainColumns,
@@ -886,16 +1043,11 @@ def _pruned_eval_sums(
     each step in its own ``kernels.prune.*`` span.  Callers open the
     ``kernels.prune.pass`` span around it and the train-side prep.
     """
-    if cols.meta is None or cols.index is None:
+    _check_pruned_columns(cols, precision, block_n)
+    if cols.index is None:
         raise ValueError(
             "pruned evaluation needs spatially prepared train columns "
             "(prepare_train_columns(..., clustered=True))"
-        )
-    if cols.block_n != block_n:
-        raise ValueError(
-            "pruned launch block_n must match the width the columns were "
-            f"prepared at: launch {block_n} vs prepared {cols.block_n} — "
-            "the tile metadata and visit lists address tiles of that width"
         )
     y = jnp.asarray(y)
     m_in, d = y.shape
@@ -908,42 +1060,13 @@ def _pruned_eval_sums(
             jnp.asarray(y[:nr], jnp.float32), labels, block_m,
             bucket_rows=True,
         )
-    packed = prec.packs(precision)
-    if (cols.planes is not None) != packed:
-        raise ValueError(
-            f"pruned evaluation at precision {precision!r} needs columns "
-            f"prepared at that tier ({'with' if packed else 'without'} the "
-            "packed f32 planes)"
-        )
-    with obs.span("kernels.prune.operands"):
-        y_hi, y_lo, nrm_y, yrec = _cast_queries(qlayout.points, precision,
-                                                packed=packed)
-    kind = "laplace" if laplace else "kde"
-    with obs.span("kernels.prune.tile_map"):
-        tm = spatial.tile_map(yrec, cols.meta, _inv2h2(h), epsilon,
-                              block_m=block_m, kind=kind)
-    with obs.span("kernels.prune.visit_lists"):
-        vl = spatial.visit_lists(tm.keep)
-    with obs.span("kernels.prune.profile"):
-        # record under BOTH column counts a later resolve may key on: the
-        # true train count (flash_kde / flash_sdkde resolve pre-padding)
-        # and the padded layout length (the prepared serving path)
-        n_true = int(spatial.to_host(cols.meta.counts.sum()))
-        _record_occupancy_profile(m_in, {n_true, cols.xt.shape[1]}, d,
-                                  vl.occupancy, block_n, yrec,
-                                  cols.meta_fine, _inv2h2(h), epsilon,
-                                  block_m, kind)
-        _note_pruned_launch(kind, vl, tm)
-    with obs.span("kernels.pruned_eval", rows=nr, kind=kind,
-                  occupancy=round(vl.occupancy, 4),
-                  max_visits=vl.max_visits):
-        sums = flash_pruned.flash_kde_pallas_pruned(
-            vl.counts, vl.tile_map, y_hi, nrm_y,
-            cols.planes if packed else cols.xt, cols.nrm_x,
-            _inv2h2(h), y_lo, cols.xt_lo,
-            block_m=block_m, block_n=block_n, max_visits=vl.max_visits,
-            interpret=interpret, laplace=laplace, packed=packed,
-        )
+    q = eval_rows(qlayout.points, precision)
+    tm = eval_tile_map(q, cols, h, epsilon, block_m=block_m,
+                       kind="laplace" if laplace else "kde")
+    sums = eval_launch(q, tm, cols, h, epsilon, rows_key=m_in, real_rows=nr,
+                       precision=precision, block_m=block_m,
+                       block_n=block_n, interpret=interpret,
+                       laplace=laplace)
     with obs.span("kernels.prune.gather"):
         out = sums[qlayout.slots, 0]             # back to request order
         if nr < m_in:                            # caller's sentinel tail

@@ -15,11 +15,13 @@ Three layers of guarantees, each tested directly:
 """
 
 import math
+from types import SimpleNamespace
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax import lax
 
 from repro.kernels import autotune, ops, spatial
 from repro.kernels import precision as prec
@@ -317,10 +319,21 @@ def test_row_group_launches_match_one_launch(monkeypatch, kind, tier, d):
     before = traces.value
     whole = run()
     one = traces.value - before
+    groups = []
+
+    def scan(f, init, xs):
+        groups.append(len(xs))
+        return lax.scan(f, init, xs)
+
     monkeypatch.setattr(flash_pruned, "SMEM_TILE_MAP_BYTES", 1)
+    monkeypatch.setattr(flash_pruned, "lax", SimpleNamespace(
+        scan=scan, dynamic_slice_in_dim=lax.dynamic_slice_in_dim,
+        optimization_barrier=lax.optimization_barrier))
     before = traces.value
     grouped = run()
-    assert one == 1 and traces.value - before > 2  # one launch per row tile
+    # one launch per row tile, in a loop over a launch traced once
+    assert one == 1 and traces.value - before == 1
+    assert groups and min(groups) > 2
     for a, b in zip(grouped, whole):
         np.testing.assert_array_equal(a, b)
 

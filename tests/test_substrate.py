@@ -204,7 +204,7 @@ def test_density_weighting_pipeline_stage():
 # -- estimator API -----------------------------------------------------------------
 
 
-@pytest.mark.parametrize("backend", ["jnp", "pallas"])
+@pytest.mark.parametrize("backend", ["jnp", "pallas", "ring"])
 def test_estimator_backends_agree(backend):
     key = jax.random.PRNGKey(0)
     x = jax.random.normal(key, (200, 8))

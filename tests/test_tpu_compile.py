@@ -153,3 +153,37 @@ def test_packed_kernels_compile_for_v5e_at_any_d(kernel, d, one_chip):
     kw = dict(block_m=bm, block_n=bn, interpret=False, packed=True,
               max_visits=_visits(SIZES["32k"][0], bn))
     _compiles_packed_as_counted(fn, args, kw, True)
+
+
+#: One chip's share of the four-chip cell: 2^19 rows of a 2^21-point
+#: layout against all of its columns, and a quarter of 131072 queries.
+SHARD_ROWS, SHARD_COLS, SHARD_QUERIES = 1 << 19, 1 << 21, 1 << 15
+
+
+@pytest.mark.parametrize("kernel", ["kde_pruned", "score_pruned"])
+def test_pruned_kernels_compile_for_one_chips_row_range(kernel, one_chip):
+    """The ``ring`` backend's launches: a row range against a whole column
+    layout, the score kernel taking the columns' norms apart from the
+    rows'."""
+    bm, bn, d = 128, 512, D
+    score = kernel == "score_pruned"
+    rows = SHARD_ROWS if score else SHARD_QUERIES
+    visits = _visits(SHARD_COLS, bn)
+
+    def s(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    bf = jnp.bfloat16
+    args = [s((rows // bm,), jnp.int32), s((rows // bm, visits), jnp.int32),
+            s((rows, 6 * d), bf), s((rows, 1)),
+            s((prec.plane_rows(d), SHARD_COLS), bf)]
+    kw = dict(block_m=bm, block_n=bn, interpret=False, packed=True,
+              max_visits=visits)
+    if score:
+        args += [None, s((1, 1))]
+        kw["nrm_cols"] = s((1, SHARD_COLS))
+        fn = flash_score_pallas_pruned
+    else:
+        args += [s((1, SHARD_COLS)), s((1, 1))]
+        fn = flash_kde_pallas_pruned
+    _compiles_packed_as_counted(fn, args, kw, True)
