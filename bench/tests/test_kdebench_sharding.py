@@ -173,4 +173,5 @@ def test_a_traced_run_reports_the_sharding_layer(rehearsal):
     got = rehearsal["traced"]
     assert got["correct"] is True
     assert {"shard.imbalance.offline", "shard.idle_ms.offline",
-            "spatial.visit_fraction"} <= set(got["metrics"])
+            "spatial.visit_fraction", "pruning.idle_ms.offline",
+            "pruning.host_ms.offline"} <= set(got["metrics"])

@@ -13,9 +13,11 @@ the rows are sharded, every chip holds every column, and nothing rotates.
               shifts the points (x_sd) and prepares them for evaluation as
               the one-chip path's evaluate does.
   evaluate    every chip gets all the debiased columns and one equal
-              contiguous range of the queries' cluster layout (not bucketed,
-              so no chip gets only sentinel tiles); the sums come back in
-              request order.
+              contiguous range of the queries' cluster layout, whose length
+              depends on the query count alone (``_query_rows``: no draw
+              compiles new programs; not bucketed to a power of two, which
+              would leave the last chips only sentinel tiles); the sums
+              come back in request order.
 
 The pruned passes compact their visit lists on the host, per chip, with
 data-dependent extents, so each chip gets its own launches from the host,
@@ -26,8 +28,9 @@ the first chip would wait behind the first chip's kernel, and the chips
 would run one after another.  Each row's answer comes from the same row
 tile, visit list and column order as on one chip, so it is the one-chip
 ``pallas`` path's answer, bit for bit; with one device this is that path,
-launch for launch.  The dense passes (``prune="off"``, or below the
-``"auto"`` threshold) shard the same way without the layout.
+launch for launch but for the query layout's length.  The dense passes
+(``prune="off"``, or below the ``"auto"`` threshold) shard the same way
+without the layout.
 
 Spans and counters (``repro.obs``):
   ``distributed.shard.pass {kind, chips, rows, cols}``  one sharded pass;
@@ -37,8 +40,13 @@ Spans and counters (``repro.obs``):
       for: every chip's part to it, or every chip's results to the first;
   histogram ``distributed.shard.transfer_bytes``  one observation per
       array moved between chips.
-The host steps keep their ``kernels.prune.*`` spans, and each launch span
+The host steps keep their ``kernels.prune.*`` spans, under a
+``kernels.prune.pass`` span that closes once the last chip's launch is
+dispatched (the wait for the chips is the gather's), and each launch span
 (``kernels.pruned_*``, ``kernels.dense_*``) names its ``chip``.
+
+The chips are every local device, ``jax.devices()``: a process that should
+use fewer has to see fewer.
 """
 
 from __future__ import annotations
@@ -119,35 +127,37 @@ def _pruned_score(x32, h, eps, devs, *, precision, block_m, block_n,
                   interpret, seed):
     n, d = x32.shape
     chips = len(devs)
-    with obs.span("kernels.prune.index"):
-        index = spatial.build_index(x32, seed=seed)
-    with obs.span("kernels.prune.layout"):
-        layout = spatial.cluster_layout(
-            x32, index.labels, block_n,
-            total_multiple=math.lcm(block_m * chips, block_n))
-        real = _real_rows(layout.real, chips, n)
-    prep = ops.score_prep(layout.points, layout.real, n=n,
-                          precision=precision, block_n=block_n)
-    if chips > 1:
-        # each chip: its range of the rows against every column
-        rows = _split((prep.x_ops, prep.nrm, prep.xrec), chips)
-        prep = prep._replace(nrm_cols=prep.nrm.reshape(1, -1))
-        preps = _place([prep._replace(x_ops=x_ops, nrm=nrm, xrec=xrec)
-                        for x_ops, nrm, xrec in rows], devs)
-    else:
-        preps = [prep]
-    tms = []
-    for prep, dev in zip(preps, devs):
-        with jax.default_device(dev):
-            tms.append(ops.score_tile_map(prep, h, eps, block_m=block_m))
-    parts = []
-    for k, (prep, tm, dev) in enumerate(zip(preps, tms, devs)):
-        with obs.span("distributed.shard.dispatch", chip=k, rows=real[k]), \
-                jax.default_device(dev):
-            parts.append(ops.score_launch(
-                prep, tm, h, eps, n=n, real_rows=real[k],
-                precision=precision, block_m=block_m, block_n=block_n,
-                interpret=interpret, chip=k))
+    with ops._prune_pass("score", n, n):
+        with obs.span("kernels.prune.index"):
+            index = spatial.build_index(x32, seed=seed)
+        with obs.span("kernels.prune.layout"):
+            layout = spatial.cluster_layout(
+                x32, index.labels, block_n,
+                total_multiple=math.lcm(block_m * chips, block_n))
+            real = _real_rows(layout.real, chips, n)
+        prep = ops.score_prep(layout.points, layout.real, n=n,
+                              precision=precision, block_n=block_n)
+        if chips > 1:
+            # each chip: its range of the rows against every column
+            rows = _split((prep.x_ops, prep.nrm, prep.xrec), chips)
+            prep = prep._replace(nrm_cols=prep.nrm.reshape(1, -1))
+            preps = _place([prep._replace(x_ops=x_ops, nrm=nrm, xrec=xrec)
+                            for x_ops, nrm, xrec in rows], devs)
+        else:
+            preps = [prep]
+        tms = []
+        for prep, dev in zip(preps, devs):
+            with jax.default_device(dev):
+                tms.append(ops.score_tile_map(prep, h, eps,
+                                              block_m=block_m))
+        parts = []
+        for k, (prep, tm, dev) in enumerate(zip(preps, tms, devs)):
+            with obs.span("distributed.shard.dispatch", chip=k,
+                          rows=real[k]), jax.default_device(dev):
+                parts.append(ops.score_launch(
+                    prep, tm, h, eps, n=n, real_rows=real[k],
+                    precision=precision, block_m=block_m, block_n=block_n,
+                    interpret=interpret, chip=k))
     s1aug = _collect(parts, devs[0])
     with obs.span("kernels.prune.gather"):
         rows = s1aug[layout.slots]
@@ -218,39 +228,51 @@ def flash_sdkde_shift(
 # ---------------------------------------------------------------------------
 
 
+def _query_rows(m: int, k: int, block_m: int, chips: int) -> int:
+    """Rows of the query layout: ``m`` queries in ``k`` clusters, each
+    cluster's rows padded to whole tiles, take at most m + k·(block_m − 1)
+    rows, whatever the draw, so every draw of ``m`` queries compiles the
+    same programs; rounded up to equal ranges of whole tiles per chip."""
+    rows = m + min(k, m) * (block_m - 1)
+    return -(-rows // (block_m * chips)) * block_m * chips
+
+
 def _pruned_kde(x32, y32, h, eps, devs, *, precision, block_m, block_n,
                 interpret, seed):
     m = y32.shape[0]
     chips = len(devs)
-    with obs.span("kernels.prune.columns"):
-        cols = ops.prepare_train_columns(x32, block_n=block_n,
-                                         precision=precision, clustered=True,
-                                         seed=seed)
-    with obs.span("kernels.prune.layout"):
-        labels = spatial.assign(y32, cols.index)
-        qlayout = spatial.cluster_layout(y32, labels, block_m,
-                                         total_multiple=block_m * chips)
-        real = _real_rows(qlayout.real, chips, m)
-    q = ops.eval_rows(qlayout.points, precision)
-    if chips > 1:
-        # every chip: all the debiased columns and its range of the queries
-        placed = _place([(cols._replace(index=None), qk)
-                         for qk in _split(q, chips)], devs)
-    else:
-        placed = [(cols, q)]
-    tms = []
-    for (ck, qk), dev in zip(placed, devs):
-        with jax.default_device(dev):
-            tms.append(ops.eval_tile_map(qk, ck, h, eps, block_m=block_m,
-                                         kind="kde"))
-    parts = []
-    for k, ((ck, qk), tm, dev) in enumerate(zip(placed, tms, devs)):
-        with obs.span("distributed.shard.dispatch", chip=k, rows=real[k]), \
-                jax.default_device(dev):
-            parts.append(ops.eval_launch(
-                qk, tm, ck, h, eps, rows_key=m, real_rows=real[k],
-                precision=precision, block_m=block_m, block_n=block_n,
-                interpret=interpret, laplace=False, chip=k))
+    with ops._prune_pass("kde", m, x32.shape[0]):
+        with obs.span("kernels.prune.columns"):
+            cols = ops.prepare_train_columns(
+                x32, block_n=block_n, precision=precision, clustered=True,
+                seed=seed)
+        with obs.span("kernels.prune.layout"):
+            labels = spatial.assign(y32, cols.index)
+            qlayout = spatial.cluster_layout(
+                y32, labels, block_m, total_multiple=_query_rows(
+                    m, cols.index.centroids.shape[0], block_m, chips))
+            real = _real_rows(qlayout.real, chips, m)
+        q = ops.eval_rows(qlayout.points, precision)
+        if chips > 1:
+            # every chip: all the debiased columns and its range of the
+            # queries
+            placed = _place([(cols._replace(index=None), qk)
+                             for qk in _split(q, chips)], devs)
+        else:
+            placed = [(cols, q)]
+        tms = []
+        for (ck, qk), dev in zip(placed, devs):
+            with jax.default_device(dev):
+                tms.append(ops.eval_tile_map(qk, ck, h, eps,
+                                             block_m=block_m, kind="kde"))
+        parts = []
+        for k, ((ck, qk), tm, dev) in enumerate(zip(placed, tms, devs)):
+            with obs.span("distributed.shard.dispatch", chip=k,
+                          rows=real[k]), jax.default_device(dev):
+                parts.append(ops.eval_launch(
+                    qk, tm, ck, h, eps, rows_key=m, real_rows=real[k],
+                    precision=precision, block_m=block_m, block_n=block_n,
+                    interpret=interpret, laplace=False, chip=k))
     sums = _collect(parts, devs[0])
     with obs.span("kernels.prune.gather"):
         return sums[qlayout.slots, 0]

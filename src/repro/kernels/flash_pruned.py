@@ -126,18 +126,30 @@ _EVAL = {(g, l): _make_eval_kernel(g, l)
 SMEM_TILE_MAP_BYTES = 256 * 1024
 
 
-def _by_row_groups(launch, counts, tile_map, rows, block_m: int, name: str,
-                   packed: bool):
+def _by_row_groups(launch, counts, tile_map, rows, block_m: int, *,
+                   scan_as: Optional[str] = None):
     """``launch(counts, tile_map, *rows)`` over row-tile groups whose tile
     map fits :data:`SMEM_TILE_MAP_BYTES`; ``rows`` are the row-tiled
-    operands (None passes through), outputs concatenate by row.  The whole
-    groups run as one ``lax.scan`` over a launch traced once (a program of
-    hundreds of unrolled launches took ~10 s to trace and lower), a shorter
-    last group as one more launch."""
+    operands (None passes through), outputs concatenate by row.
+
+    One launch per group by default.  With ``scan_as`` (the kernel's
+    name) the whole groups run as one ``lax.scan`` over a launch lowered
+    once, a shorter last group as one more launch: the score pass's 256
+    unrolled launches at 1M took ~10 s to lower, once per chip on the
+    ``ring`` backend.  The eval kernel stays unrolled: in a scan it ran
+    15% slower on a v5e, where the score kernel ran as fast."""
     mt, visits = tile_map.shape
     per = max(1, SMEM_TILE_MAP_BYTES // (4 * visits))
     if per >= mt:
         return launch(counts, tile_map, *rows)
+    if scan_as is None:
+        outs = []
+        for g in range(0, mt, per):
+            e = min(g + per, mt)
+            part = [None if a is None else a[g * block_m:e * block_m]
+                    for a in rows]
+            outs.append(launch(counts[g:e], tile_map[g:e], *part))
+        return jnp.concatenate(outs)
     full = mt // per
 
     def group(carry, g):
@@ -147,17 +159,14 @@ def _by_row_groups(launch, counts, tile_map, rows, block_m: int, name: str,
                 for a in rows]
         out = launch(lax.dynamic_slice_in_dim(counts, t0, per),
                      lax.dynamic_slice_in_dim(tile_map, t0, per), *part)
-        # The compiler fuses the launch into the scan's stacking of the
-        # outputs.  At the bf16x2 tier's 512 x 4096 tiles that fusion ran
-        # out of scoped VMEM (compiled for a described v5e), so the
-        # unpacked tiers write a buffer of their own; the packed f32
-        # kernels keep the fusion, which ran 6% faster per step on a
-        # v5e than unrolled launches.
-        return carry, out if packed else lax.optimization_barrier(out)
+        # each launch writes a buffer of its own, as an unrolled launch
+        # does: fused into the scan's stacking of the outputs, a launch at
+        # the bf16x2 tier's 512 x 4096 tiles ran out of scoped VMEM
+        return carry, lax.optimization_barrier(out)
 
     # the launch keeps its kernel's name in the compiled program (the
     # device trace names each kernel operation by it)
-    group = jax.named_call(group, name=name)
+    group = jax.named_call(group, name=scan_as)
     _, outs = lax.scan(group, None, jnp.arange(full))
     out = outs.reshape(full * per * block_m, outs.shape[-1])
     t0 = full * per
@@ -232,7 +241,7 @@ def flash_kde_pallas_pruned(
         )(cnt, tm, *args)
 
     return _by_row_groups(launch, counts, tile_map, (y, nrm_y, y_lo),
-                          block_m, "flash_kde_pallas_pruned", packed)
+                          block_m)
 
 
 def _make_score_kernel(compensated: bool):
@@ -376,7 +385,7 @@ def flash_score_pallas_pruned(
         )(cnt, tm, *args)
 
     acc = _by_row_groups(launch, counts, tile_map, (x, nrm, x_lo), block_m,
-                         "flash_score_pallas_pruned", packed)
+                         scan_as="flash_score_pallas_pruned")
     return prec.reduce_planes(acc, d) if packed else acc
 
 

@@ -77,13 +77,30 @@ for precision in ("f32", "bf16x2"):
                      for a in (est.x_sd, dens)],
     }
     if precision == "f32":
-        out["spans"] = [[e["name"], e["attrs"]] for e in events]
+        by_id = {e["id"]: e["name"] for e in events}
+        out["spans"] = [[e["name"], e["attrs"], by_id.get(e["parent"])]
+                        for e in events]
 
 with jax.transfer_guard_device_to_device("disallow"):
     est, dens = job("ring", x, prune="off")
 pal, pal_dens = job("pallas", x, prune="off")
 out["dense_equal"] = [bool(np.array_equal(est.x_sd, pal.x_sd)),
                       bool(np.array_equal(dens, pal_dens))]
+
+# a second draw of as many queries compiles nothing new: the query
+# layout's length depends on the count alone
+compiled = []
+jax.monitoring.register_event_duration_secs_listener(
+    lambda name, secs, **kw: compiled.append(name)
+    if name == "/jax/core/compile/backend_compile_duration" else None)
+est, _ = job("ring", x, prune=0.0)
+# (drawn near one mode: fewer clusters, a shorter layout to each draw)
+y2 = 0.3 * jax.random.normal(jax.random.PRNGKey(7), (M, D)) + 3.0
+with jax.default_matmul_precision("highest"):
+    want2 = ref.sdkde_eval(x, y2, est.h, block=512)
+compiled.clear()
+dens2 = est.evaluate(y2)
+out["new_draw"] = {"compiles": len(compiled), "ref": rel(dens2, want2)}
 
 small = x[:150]
 est, dens = job("ring", small, prune=0.0)
@@ -161,6 +178,35 @@ def test_one_device_is_the_pallas_path():
         np.testing.assert_array_equal(a, b)
 
 
+def test_a_new_draw_of_queries_compiles_nothing(child):
+    got = child["new_draw"]
+    assert got["compiles"] == 0
+    assert got["ref"][0] < 1e-4
+
+
+def test_query_layout_length_depends_on_the_count_alone():
+    """Any draw of m queries over k clusters fits the sharded eval's query
+    layout, which is therefore one length for every draw; the worst case,
+    every cluster one row past whole tiles, fills it."""
+    from repro.distributed import shard
+    from repro.kernels import spatial
+
+    rng = np.random.default_rng(0)
+    k, bm, chips = 37, 64, 4
+    m = k * (bm + 1)
+    rows = shard._query_rows(m, k, bm, chips)
+    assert rows % (bm * chips) == 0
+    worst = np.repeat(np.arange(k), bm + 1)
+    draws = [worst] + [rng.choice(k, size=m, p=rng.dirichlet(np.full(k, a)))
+                       for a in (100.0, 1.0, 0.01)]
+    for labels in draws:
+        y = rng.normal(size=(m, 3)).astype(np.float32)
+        lay = spatial.cluster_layout(y, labels, bm, total_multiple=rows)
+        assert lay.points.shape[0] == rows
+    _, caps = spatial.cluster_capacities(worst, bm)
+    assert rows - caps.sum() < bm * chips
+
+
 def test_devices_left_with_sentinel_rows_only(child):
     assert child["small_equal"]
 
@@ -171,17 +217,36 @@ def test_answers_come_back_to_the_first_device(child):
 
 def test_sharded_passes_have_their_spans(child):
     spans = child["spans"]
-    passes = [a for n, a in spans if n == "distributed.shard.pass"]
+    passes = [a for n, a, _ in spans if n == "distributed.shard.pass"]
     assert passes == [{"kind": "score", "chips": 4, "rows": N, "cols": N},
                       {"kind": "kde", "chips": 4, "rows": M, "cols": N}]
-    dispatch = [a for n, a in spans if n == "distributed.shard.dispatch"]
+    dispatch = [a for n, a, _ in spans if n == "distributed.shard.dispatch"]
     assert [a["chip"] for a in dispatch] == [0, 1, 2, 3] * 2
     assert sum(a["rows"] for a in dispatch[:4]) == N
     assert sum(a["rows"] for a in dispatch[4:]) == M
     for name in ("kernels.pruned_score", "kernels.pruned_eval"):
-        launches = [a for n, a in spans if n == name]
+        launches = [a for n, a, _ in spans if n == name]
         assert [a["chip"] for a in launches] == [0, 1, 2, 3]
-    gathers = [a["bytes"] for n, a in spans
+    gathers = [(a["bytes"], p) for n, a, p in spans
                if n == "distributed.shard.gather"]
-    assert len(gathers) == 4 and all(b > 0 for b in gathers)
+    assert len(gathers) == 4 and all(b > 0 for b, _ in gathers)
     assert child["transfers"] > 0
+
+
+def test_the_host_steps_sit_under_a_pruned_pass(child):
+    """The pruning layer's readers find the sharded passes' host steps:
+    each pass's steps and per-chip dispatches lie under a
+    ``kernels.prune.pass``, which closes before the wait for the chips."""
+    spans = child["spans"]
+    passes = [(a, p) for n, a, p in spans if n == "kernels.prune.pass"]
+    assert passes == [
+        ({"kind": "score", "rows": N, "cols": N}, "distributed.shard.pass"),
+        ({"kind": "kde", "rows": M, "cols": N}, "distributed.shard.pass")]
+    assert all(p == "kernels.prune.pass" for n, _, p in spans
+               if n == "distributed.shard.dispatch")
+    assert {n for n, _, p in spans if p == "distributed.shard.dispatch"} \
+        == {"kernels.prune.visit_lists", "kernels.prune.profile",
+            "kernels.pruned_score", "kernels.pruned_eval"}
+    # placing the parts, under the pass; collecting them, after it
+    assert [p for n, _, p in spans if n == "distributed.shard.gather"] == [
+        "kernels.prune.pass", "distributed.shard.pass"] * 2

@@ -139,8 +139,18 @@ def _compiles_packed_as_counted(fn, args, kw, packed):
 
     before = packed_traces()
     compiled = fn.lower(*args, **kw).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    assert _kernel_ops(compiled.as_text()) == {fn.__name__}
     assert (packed_traces() > before) == packed
+
+
+def _kernel_ops(hlo: str) -> set:
+    """Names of the compiled program's Mosaic kernel operations, without
+    their ``.N`` suffix: the device trace names each launch by them, and
+    the benchmark's kernel readers find a kernel by that name, also where
+    its row groups run in a loop."""
+    return {line.split(" = ", 1)[0].strip().lstrip("%").rsplit(".", 1)[0]
+            for line in hlo.splitlines()
+            if 'custom_call_target="tpu_custom_call"' in line}
 
 
 @pytest.mark.parametrize("d", [2, 5, 22, 32])
