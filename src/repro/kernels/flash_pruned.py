@@ -21,6 +21,12 @@ body masks their accumulation with ``pl.when(k < counts[i])``, so bucketed
 ``k == 0`` — the visit axis is the innermost sequential grid dimension,
 same revisiting-output-block scheme as the dense kernels.
 
+The score kernel runs V visit slots a step (:func:`score_slots`): its grid
+is ``(mt, ceil(max_visits / V))``, step k streams the column tiles of slots
+``V·k … V·k + V − 1`` through V BlockSpecs of the same arrays, and adds
+them in slot order: under one branch where all V lie within the row's
+count, else each under its own mask.
+
 Precision tiers: the ``*_lo`` planes ride along and the bodies reuse the
 dense kernels' compensated-Gram helpers.  At the f32 tier the GEMMs run
 packed (kernels/precision.py), which the caller says with the static
@@ -44,6 +50,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro import obs
 from repro.kernels import compiler_params, launch_interpret
+from repro.kernels import tuning
 from repro.kernels import precision as prec
 from repro.kernels.flash_laplace import _sq_tile
 
@@ -244,63 +251,115 @@ def flash_kde_pallas_pruned(
                           block_m)
 
 
-def _make_score_kernel(compensated: bool):
+#: Most visit slots one grid step of the score kernel runs.  A step pays a
+#: cost of its own that does not scale with its tiles, and its slots run
+#: under one branch where the compiler overlaps them.  Set from a sweep of
+#: V in {1, 2, 4, 8} on the 1M x 16-d launches on a v5e (PERF.md).
+SCORE_SLOTS_MAX = 8
+
+
+def score_slots(max_visits: int, *, block_m: int, block_n: int, d: int,
+                itemsize: int) -> int:
+    """Visit slots V per grid step of the score kernel: the largest power
+    of two up to :data:`SCORE_SLOTS_MAX` and ``max_visits`` whose V
+    sub-slots fit the tile model's VMEM budget (``tuning.VMEM_BUDGET``),
+    each priced as one step of the model (``tuning.pair_pass_cost``: its
+    operand tiles, φ tile and accumulator) with a second copy of its
+    column tiles for the double buffer.  ``itemsize`` is the tier's
+    operand bytes (``precision.operand_bytes``).
+
+    The count is conservative: compiled for a v5e, the sub-slots share
+    one body's Gram and φ tiles and add only their column buffers.  It
+    keeps the large tiles, whose steps have little fixed cost to share,
+    at one slot a step (bf16x2's 512 x 4096 tiles: V = 1)."""
+    step = tuning.pair_pass_cost(block_m, block_n, d, block_m=block_m,
+                                 block_n=block_n, out_width=d + 1,
+                                 itemsize=itemsize)
+    cols = itemsize * block_n * (2 * d + 1) + 4 * block_n
+    slots = SCORE_SLOTS_MAX
+    while slots > 1 and (slots > max_visits or
+                         slots * (step.vmem_bytes + cols)
+                         > tuning.VMEM_BUDGET):
+        slots //= 2
+    return slots
+
+
+#: Column refs of one visit slot, and row refs, by GEMM form.
+_SLOT_REFS = {"packed": 2, "dot": 3, "split2": 5}
+_ROW_REFS = {"packed": 2, "dot": 2, "split2": 3}
+
+
+def _score_slot(gemm: str, rows, cols, inv2h2_ref):
+    """One visit slot's contribution to the score accumulator: S1aug
+    (block_m, d+1), or at the packed f32 tier the transposed plane sums
+    (plane_rows, block_m)."""
+    if gemm == "packed":
+        (rows_ref, nrm_m_ref), (planes_ref, nrm_n_ref) = rows, cols
+        sq = _sq_packed(rows_ref, nrm_m_ref, planes_ref, nrm_n_ref)
+        phi = jnp.exp(-sq * inv2h2_ref[0, 0])
+        return prec.weighted_accum_packed(phi, planes_ref[...])
+    if gemm == "dot":
+        (x_ref, nrm_m_ref), (xt_ref, xaug_ref, nrm_n_ref) = rows, cols
+        x_lo_ref = xt_lo_ref = xaug_lo_ref = None
+    else:
+        x_ref, x_lo_ref, nrm_m_ref = rows
+        xt_ref, xt_lo_ref, xaug_ref, xaug_lo_ref, nrm_n_ref = cols
+    sq = _sq_tile(x_ref, nrm_m_ref, xt_ref, nrm_n_ref, x_lo_ref, xt_lo_ref)
+    phi = jnp.exp(-sq * inv2h2_ref[0, 0])
+    return prec.weighted_accum(
+        phi, xaug_ref[...], None if xaug_lo_ref is None else xaug_lo_ref[...])
+
+
+@functools.lru_cache(maxsize=None)
+def _score_kernel(gemm: str, slots: int):
+    """Score body over ``slots`` visit slots per grid step.  Sub-slot j of
+    step k is visit slot ``slots·k + j``, masked by the row's count (the
+    whole step under one mask where all its slots are within it) and added
+    in slot order, so the sums are those of one slot a step.  The
+    packed numerator accumulates transposed, one row per plane row, in a
+    (plane_rows, block_m) VMEM scratch, and is written out as
+    (block_m, plane_rows) once, at the last step."""
+    nr, per = _ROW_REFS[gemm], _SLOT_REFS[gemm]
+
     def kernel(cnt_ref, tmap_ref, *refs):
-        del tmap_ref
-        if compensated:
-            (x_hi_ref, x_lo_ref, nrm_m_ref, xt_hi_ref, xt_lo_ref,
-             xaug_hi_ref, xaug_lo_ref, nrm_n_ref, inv2h2_ref,
-             out_ref) = refs
-        else:
-            (x_hi_ref, nrm_m_ref, xt_hi_ref, xaug_hi_ref, nrm_n_ref,
-             inv2h2_ref, out_ref) = refs
-            x_lo_ref = xt_lo_ref = xaug_lo_ref = None
+        del tmap_ref  # consumed by the BlockSpec index maps
+        rows, cols = refs[:nr], refs[nr:nr + per * slots]
+        inv2h2_ref, out_ref, *scratch = refs[nr + per * slots:]
+        acc_ref = scratch[0] if gemm == "packed" else out_ref
         i, k = pl.program_id(0), pl.program_id(1)
 
         @pl.when(k == 0)
         def _init():
-            out_ref[...] = jnp.zeros_like(out_ref)
+            acc_ref[...] = jnp.zeros_like(acc_ref)
 
-        @pl.when(k < cnt_ref[i])
-        def _accumulate():
-            sq = _sq_tile(x_hi_ref, nrm_m_ref, xt_hi_ref, nrm_n_ref,
-                          x_lo_ref, xt_lo_ref)
-            phi = jnp.exp(-sq * inv2h2_ref[0, 0])
-            if compensated:
-                out_ref[...] += prec.weighted_accum(phi, xaug_hi_ref[...],
-                                                    xaug_lo_ref[...])
-            else:
-                out_ref[...] += prec.weighted_accum(phi, xaug_hi_ref[...])
+        def accumulate(j):
+            acc_ref[...] += _score_slot(gemm, rows,
+                                        cols[j * per:(j + 1) * per],
+                                        inv2h2_ref)
+
+        # a step whose slots all lie within the count runs them under one
+        # branch, where the compiler can overlap them: a branch per slot
+        # cost 15% of the kernel on a v5e (PERF.md)
+        full = slots * k + slots <= cnt_ref[i]
+
+        @pl.when(full)
+        def _all():
+            for j in range(slots):
+                accumulate(j)
+
+        if slots > 1:
+            @pl.when(jnp.logical_not(full))
+            def _some():
+                for j in range(slots):
+                    pl.when(slots * k + j < cnt_ref[i])(
+                        functools.partial(accumulate, j))
+
+        if gemm == "packed":
+            @pl.when(k == pl.num_programs(1) - 1)
+            def _write():
+                out_ref[...] = acc_ref[...].T
 
     return kernel
-
-
-def _packed_score_kernel(cnt_ref, tmap_ref, rows_ref, nrm_m_ref, planes_ref,
-                         nrm_n_ref, inv2h2_ref, out_ref, acc_ref):
-    """Score body of the packed f32 path.  The numerator accumulates
-    transposed, one row per plane row, in a (plane_rows, block_m) VMEM
-    scratch, and is written out as (block_m, plane_rows) once, at the last
-    visit slot."""
-    del tmap_ref
-    i, k = pl.program_id(0), pl.program_id(1)
-
-    @pl.when(k == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    @pl.when(k < cnt_ref[i])
-    def _accumulate():
-        sq = _sq_packed(rows_ref, nrm_m_ref, planes_ref, nrm_n_ref)
-        phi = jnp.exp(-sq * inv2h2_ref[0, 0])
-        acc_ref[...] += prec.weighted_accum_packed(phi, planes_ref[...])
-
-    @pl.when(k == pl.num_programs(1) - 1)
-    def _write():
-        out_ref[...] = acc_ref[...].T
-
-
-_SCORE = {"dot": _make_score_kernel(False), "split2": _make_score_kernel(True),
-          "packed": _packed_score_kernel}
 
 
 @functools.partial(
@@ -331,7 +390,10 @@ def flash_score_pallas_pruned(
     """Pruned score statistics S1aug (m, d+1) f32 of the rows against the
     columns; ``packed``: the f32 tier's packed operands.  Without
     ``nrm_cols`` the rows are the columns (the train set against itself);
-    with it, the rows are a contiguous range of the column layout."""
+    with it, the rows are a contiguous range of the column layout.  Each
+    grid step runs :func:`score_slots` visit slots; the tile map is padded
+    to a multiple of them with each row's fill tile, which the counts
+    mask."""
     m, width = x.shape
     n = xt.shape[1]
     assert m % block_m == 0 and n % block_n == 0, (m, n, block_m, block_n)
@@ -344,49 +406,69 @@ def flash_score_pallas_pruned(
         counts.shape, tile_map.shape, mt, max_visits)
     gemm, d = _gemm(packed, x, xt, x_lo)
     assert packed == (xaug is None), "the packed planes replace [X | 1]"
+    slots = score_slots(max_visits, block_m=block_m, block_n=block_n, d=d,
+                        itemsize=2 if gemm == "dot" else 4)
+    pad = -max_visits % slots
+    if pad:
+        tile_map = jnp.concatenate(
+            [tile_map, jnp.broadcast_to(tile_map[:, :1], (mt, pad))], axis=1)
     # the packed numerator accumulates one column per plane row
     out_w = xt.shape[0] if packed else d + 1
 
     row = pl.BlockSpec((block_m, width), lambda i, k, cnt, tm: (i, 0))
     nrm_row = pl.BlockSpec((block_m, 1), lambda i, k, cnt, tm: (i, 0))
-    col = pl.BlockSpec((xt.shape[0], block_n),
-                       lambda i, k, cnt, tm: (0, tm[i, k]))
-    aug = pl.BlockSpec((block_n, d + 1), lambda i, k, cnt, tm: (tm[i, k], 0))
-    nrm_col = pl.BlockSpec((1, block_n), lambda i, k, cnt, tm: (0, tm[i, k]))
     scalar = pl.BlockSpec((1, 1), lambda i, k, cnt, tm: (0, 0))
     out = pl.BlockSpec((block_m, out_w), lambda i, k, cnt, tm: (i, 0))
-    kernel = _SCORE[gemm]
     nrm_bcast = jnp.broadcast_to(nrm.reshape(1, -1), (1, n)) \
         if nrm_cols is None else nrm_cols
+
+    def slot_specs(j):
+        """Sub-slot j's column specs: visit slot ``slots·k + j``."""
+        def at(i, k, cnt, tm):
+            return tm[i, slots * k + j]
+        col = pl.BlockSpec((xt.shape[0], block_n),
+                           lambda i, k, cnt, tm: (0, at(i, k, cnt, tm)))
+        aug = pl.BlockSpec((block_n, d + 1),
+                           lambda i, k, cnt, tm: (at(i, k, cnt, tm), 0))
+        nrm_col = pl.BlockSpec((1, block_n),
+                               lambda i, k, cnt, tm: (0, at(i, k, cnt, tm)))
+        if gemm == "packed":
+            return [col, nrm_col], [xt, nrm_bcast]
+        if gemm == "dot":
+            return [col, aug, nrm_col], [xt, xaug, nrm_bcast]
+        return ([col, col, aug, aug, nrm_col],
+                [xt, xt_lo, xaug, xaug_lo, nrm_bcast])
+
+    col_specs, col_args = [], []
+    for j in range(slots):
+        specs, args = slot_specs(j)
+        col_specs += specs
+        col_args += args
+    kernel = _score_kernel(gemm, slots)
 
     def launch(cnt, tm, x, nrm, x_lo):
         if packed:
             _note_packed("flash_score_pallas_pruned", "gram", "numerator")
-            in_specs = [row, nrm_row, col, nrm_col, scalar]
-            args = (x, nrm, xt, nrm_bcast, inv2h2)
-        elif gemm == "dot":
-            in_specs = [row, nrm_row, col, aug, nrm_col, scalar]
-            args = (x, nrm, xt, xaug, nrm_bcast, inv2h2)
-        else:
-            in_specs = [row, row, nrm_row, col, col, aug, aug, nrm_col,
-                        scalar]
-            args = (x, x_lo, nrm, xt, xt_lo, xaug, xaug_lo, nrm_bcast,
-                    inv2h2)
+        rows_specs, rows = ([row, nrm_row], [x, nrm]) if x_lo is None \
+            else ([row, row, nrm_row], [x, x_lo, nrm])
         scratch = [pltpu.VMEM((out_w, block_m), jnp.float32)] if packed \
             else []
         return pl.pallas_call(
             kernel,
             grid_spec=pltpu.PrefetchScalarGridSpec(
-                num_scalar_prefetch=2, grid=tm.shape, in_specs=in_specs,
+                num_scalar_prefetch=2,
+                grid=(tm.shape[0], tm.shape[1] // slots),
+                in_specs=rows_specs + col_specs + [scalar],
                 out_specs=out, scratch_shapes=scratch),
             out_shape=jax.ShapeDtypeStruct((x.shape[0], out_w), jnp.float32),
             interpret=launch_interpret(interpret),
             compiler_params=compiler_params(),
-        )(cnt, tm, *args)
+        )(cnt, tm, *rows, *col_args, inv2h2)
 
     acc = _by_row_groups(launch, counts, tile_map, (x, nrm, x_lo), block_m,
                          scan_as="flash_score_pallas_pruned")
     return prec.reduce_planes(acc, d) if packed else acc
 
 
-__all__ = ["flash_kde_pallas_pruned", "flash_score_pallas_pruned"]
+__all__ = ["flash_kde_pallas_pruned", "flash_score_pallas_pruned",
+           "score_slots"]

@@ -412,8 +412,13 @@ def score_launch(prep: ScorePrep, tm: spatial.TileMap, h, epsilon: float, *,
                                   prep.xrec, prep.meta_fine, _inv2h2(h),
                                   epsilon, block_m, "score")
         _note_pruned_launch("score", vl, tm)
+    slots = flash_pruned.score_slots(
+        vl.max_visits, block_m=block_m, block_n=block_n, d=d,
+        itemsize=prec.operand_bytes(precision))
     with obs.span("kernels.pruned_score", rows=real_rows,
-                  occupancy=round(vl.occupancy, 4), chip=chip):
+                  occupancy=round(vl.occupancy, 4), chip=chip,
+                  slots_per_step=slots,
+                  grid_steps=len(vl.counts) * -(-vl.max_visits // slots)):
         return flash_pruned.flash_score_pallas_pruned(
             vl.counts, vl.tile_map, prep.x_ops[0], prep.nrm,
             prep.xt_ops[0], prep.xaug_ops[0], _inv2h2(h), prep.x_ops[1],

@@ -215,6 +215,11 @@ def test_kernel_bodies_pack_only_at_the_f32_tier(kind, tier, d):
                         block_n=128, max_visits=2, interpret=True,
                         packed=packed)
     gemms = 1 if kind == "kde" else 2
+    if kind == "score":    # per visit slot of a step, in the branch of
+        slots = flash_pruned.score_slots(      # full steps and the masked one
+            2, block_m=32, block_n=128, d=d,
+            itemsize=prec.operand_bytes(tier))
+        gemms *= slots * (2 if slots > 1 else 1)
     if tier == "bf16x2":               # four products per GEMM
         gemms *= 4
     # packed or bf16: one bf16 pass per GEMM, nothing at HIGHEST

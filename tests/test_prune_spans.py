@@ -149,3 +149,29 @@ def test_every_host_sync_is_counted_once_and_repeats_exactly(data):
     # one bool per (row tile, column tile) of the padded layouts
     assert got[("score", visits)] >= (N // BLOCK_M) * (N // BLOCK_N)
     assert got[("kde", visits)] >= -(-M // BLOCK_M) * (N // BLOCK_N)
+
+
+def test_score_launch_span_counts_its_grid_steps(data, monkeypatch):
+    """``kernels.pruned_score`` carries the visit slots each grid step runs
+    and the launch's grid steps: one per ``slots_per_step`` visit slots of
+    every row tile, all row groups together."""
+    from repro.kernels import flash_pruned
+
+    lists = []
+    compact = spatial.visit_lists
+
+    def visit_lists(keep, **kw):
+        lists.append(compact(keep, **kw))
+        return lists[-1]
+
+    monkeypatch.setattr(spatial, "visit_lists", visit_lists)
+    _sdkde(data, 0.0)
+    vl = lists[0]                             # the score pass's, first
+    mt = vl.tile_map.shape[0]
+    attrs = next(e["attrs"] for e in obs.trace_events()
+                 if e["name"] == "kernels.pruned_score")
+    slots = attrs["slots_per_step"]
+    assert slots == flash_pruned.score_slots(
+        vl.max_visits, block_m=BLOCK_M, block_n=BLOCK_N, d=D, itemsize=4)
+    assert 1 < slots <= vl.max_visits
+    assert attrs["grid_steps"] == mt * -(-vl.max_visits // slots)

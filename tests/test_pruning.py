@@ -342,6 +342,77 @@ def test_row_group_launches_match_one_launch(monkeypatch, kind, tier, d):
         np.testing.assert_array_equal(a, b)
 
 
+#: Visits per row tile of the slot tests: all-sentinel rows (0), a count
+#: that ends mid-step at V = 2 and 4 (5, 3), the whole extent (7, which no
+#: V > 1 divides) and one slot (1).
+_SLOT_COUNTS = (0, 5, 7, 3, 0, 1, 7, 6, 2, 0, 4, 7, 5, 1, 3, 0)
+_SLOT_VISITS = 7
+_ONE_SLOT = {}
+
+
+def _slot_launch(tier, d, slots, monkeypatch):
+    """The score kernel over a hand-made visit list, at ``slots`` visit
+    slots per grid step."""
+    from repro.kernels import flash_pruned
+
+    bm, bn, n = 16, 128, 1024
+    x = _clustered(n, d, seed=70 + d)
+    packed = prec.packs(tier)
+    x_ops, xt_ops, xaug_ops, nrm, _ = ops._score_operands(x, tier,
+                                                         packed=packed)
+    counts = np.array(_SLOT_COUNTS * (n // bm // len(_SLOT_COUNTS)),
+                      np.int32)
+    rng = np.random.default_rng(d)
+    tiles = np.stack([rng.permutation(n // bn)[:_SLOT_VISITS]
+                      for _ in counts]).astype(np.int32)
+    fill = np.where(counts > 0, tiles[:, 0], 0)
+    tiles = np.where(np.arange(_SLOT_VISITS)[None] < counts[:, None], tiles,
+                     fill[:, None])
+    monkeypatch.setattr(flash_pruned, "SCORE_SLOTS_MAX", slots)
+    assert flash_pruned.score_slots(
+        _SLOT_VISITS, block_m=bm, block_n=bn, d=d,
+        itemsize=prec.operand_bytes(tier)) == slots
+    flash_pruned.flash_score_pallas_pruned.clear_cache()  # V: trace time
+    return np.asarray(flash_pruned.flash_score_pallas_pruned(
+        jnp.asarray(counts), jnp.asarray(tiles), x_ops[0], nrm, xt_ops[0],
+        xaug_ops[0], jnp.full((1, 1), 0.4, jnp.float32), x_ops[1], xt_ops[1],
+        xaug_ops[1], block_m=bm, block_n=bn, max_visits=_SLOT_VISITS,
+        interpret=True, packed=packed))
+
+
+@pytest.mark.parametrize("grouped", [False, True],
+                         ids=["one_launch", "row_groups"])
+@pytest.mark.parametrize("slots", [1, 2, 4])
+@pytest.mark.parametrize("tier,d", [("f32", 2), ("f32", 16), ("bf16x2", 4)])
+def test_score_slots_per_step_match_one_slot(monkeypatch, tier, d, slots,
+                                             grouped):
+    """Each grid step of the score kernel runs V visit slots, masked by the
+    row's count (a step within it under one branch) and added in slot
+    order: S1aug is bit for bit that of one
+    slot a step, for counts of 0, counts that end mid-step and an extent
+    that V does not divide, in one launch and in the scanned row groups."""
+    from repro.kernels import flash_pruned
+
+    if (tier, d) not in _ONE_SLOT:
+        _ONE_SLOT[tier, d] = _slot_launch(tier, d, 1, monkeypatch)
+    groups = []
+    if grouped:
+        def scan(f, init, xs):
+            groups.append(len(xs))
+            return lax.scan(f, init, xs)
+
+        # three of the 64 row tiles a group at the padded extent (8
+        # slots): 21 groups in the scan and a last launch of one
+        monkeypatch.setattr(flash_pruned, "SMEM_TILE_MAP_BYTES", 4 * 8 * 3)
+        monkeypatch.setattr(flash_pruned, "lax", SimpleNamespace(
+            scan=scan, dynamic_slice_in_dim=lax.dynamic_slice_in_dim,
+            optimization_barrier=lax.optimization_barrier))
+    got = _slot_launch(tier, d, slots, monkeypatch)
+    assert groups == ([21] if grouped else [])
+    assert np.abs(got).max() > 0
+    np.testing.assert_array_equal(got, _ONE_SLOT[tier, d])
+
+
 @pytest.mark.parametrize("d", [2, 5, 16, 22])
 @pytest.mark.parametrize("kind", ["kde", "laplace", "score"])
 def test_packed_pruned_kernels_match_dense_highest(kind, d, f64,

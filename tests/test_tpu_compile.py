@@ -197,3 +197,35 @@ def test_pruned_kernels_compile_for_one_chips_row_range(kernel, one_chip):
         args += [s((1, SHARD_COLS)), s((1, 1))]
         fn = flash_kde_pallas_pruned
     _compiles_packed_as_counted(fn, args, kw, True)
+
+
+#: The 1M cell's score launch: every column tile of its layout (2165 at
+#: 128 x 512), padded to a multiple of the slots, as row groups of 30
+#: row tiles.
+CELL_VISITS = 2165
+
+
+@pytest.mark.parametrize("tier,tiles,visits,slots", [
+    ("f32", (128, 512), CELL_VISITS, "most"),
+    ("bf16x2", (512, 4096), 128, 1),
+], ids=["f32-128x512", "bf16x2-512x4096"])
+def test_score_kernel_compiles_at_its_slots_per_step(tier, tiles, visits,
+                                                     slots, one_chip):
+    """The score kernel runs the visit slots per grid step that its launch
+    shape gives (``flash_pruned.score_slots``): the most at the 1M cell's
+    128 x 512 packed launch, one at bf16x2's 512 x 4096 tiles."""
+    from repro.kernels import flash_pruned
+
+    bm, bn = tiles
+    got = flash_pruned.score_slots(visits, block_m=bm, block_n=bn, d=D,
+                                   itemsize=prec.operand_bytes(tier))
+    assert got == (flash_pruned.SCORE_SLOTS_MAX if slots == "most"
+                   else slots)
+    packed = _packed("score_pruned", tier)
+    args = _args("score_pruned", tier, "1m", bm, bn, one_chip)
+    n = SIZES["1m"][0]
+    args[1] = jax.ShapeDtypeStruct((n // bm, visits), jnp.int32,
+                                   sharding=one_chip)
+    kw = dict(block_m=bm, block_n=bn, interpret=False, packed=packed,
+              max_visits=visits)
+    _compiles_packed_as_counted(flash_score_pallas_pruned, args, kw, packed)
