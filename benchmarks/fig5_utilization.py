@@ -1,20 +1,14 @@
 """Fig. 5 / Fig. 7 reproduction: utilization from the paper's flop model.
 
-Two parts:
-  1. CPU-measured:   utilization = FLOPs_model(k) / (runtime × peak).  The
-     peak is a rough single-socket CPU estimate — the point is the TREND
-     (utilization rising with n, the compute-bound signature), matching the
-     paper's Fig. 5 shape.
-  2. TPU dry-run:    the three roofline terms for the flash_sdkde_* cells
-     from results/dryrun_single.json (if present) — the v5e equivalent of
-     the paper's utilization bars, derived from the compiled program.
+CPU-measured utilization = FLOPs_model(k) / (runtime × peak).  The peak is
+a rough single-socket CPU estimate — the point is the TREND (utilization
+rising with n, the compute-bound signature), matching the paper's Fig. 5
+shape.
 """
 
 from __future__ import annotations
 
 import argparse
-import json
-import os
 
 import jax
 
@@ -40,18 +34,6 @@ def main(ns=(1024, 2048, 4096, 8192)):
         emit("fig5_cpu", n=n, runtime_ms=round(t * 1e3, 2),
              model_flops=f"{model_flops:.3e}",
              util_pct=round(100 * model_flops / (t * CPU_PEAK_FLOPS), 2))
-
-    for path in ("results/dryrun_single.json", "results/dryrun_multi.json"):
-        if not os.path.exists(path):
-            continue
-        for rec in json.load(open(path)):
-            if rec.get("status") == "ok" and "sdkde" in rec["arch"]:
-                emit("fig5_tpu", arch=rec["arch"], mesh=rec["mesh"],
-                     t_comp_ms=round(rec["t_compute_s"] * 1e3, 2),
-                     t_mem_ms=round(rec["t_memory_s"] * 1e3, 2),
-                     t_coll_ms=round(rec["t_collective_s"] * 1e3, 2),
-                     bound=rec["bound"],
-                     mfu_pct=round(100 * rec["mfu"], 1))
 
 
 if __name__ == "__main__":

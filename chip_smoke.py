@@ -39,6 +39,12 @@ sys.path.insert(0, str(Path(__file__).resolve().parent / "src"))
 TIER_RTOL = {"f32": 1e-5, "bf16": 5e-2, "bf16x2": 5e-4}
 TIER_ATOL_FRAC = {"f32": 1e-6, "bf16": 5e-3, "bf16x2": 1e-5}
 
+#: (train points, queries, dimension) of the paper's two workloads: the
+#: Figure 1 / Table 1 scale (32k train points, n/8 queries), and the
+#: 1M-sample 16-d task evaluated on 131k queries (sections 1 and 7).
+TABLE1 = (32768, 4096, 16)
+PAPER_1M = (1048576, 131072, 16)
+
 
 class SmokeFailure(RuntimeError):
     """A phase's check failed; uncaught, it ends the run nonzero."""
@@ -169,11 +175,9 @@ def phase_table1(seed: int, compiles: Compiles, *, n=None, m=None, d=None,
     import jax
 
     from repro import obs
-    from repro.configs import KDE_WORKLOADS
     from repro.serve import QueryRequest, ServeConfig, ServeEngine
 
-    wl = KDE_WORKLOADS["flash_sdkde_32k"]
-    n, m, d = n or wl.n_train, m or wl.n_test, d or wl.dim
+    n, m, d = n or TABLE1[0], m or TABLE1[1], d or TABLE1[2]
     x, pool = sample(d, n, m, seed)
     sizes = sizes or (1, 37, 300, 1000, m)
     eng = ServeEngine(ServeConfig(backend="pallas", method="sdkde",
@@ -221,12 +225,10 @@ def phase_stream(seed: int, *, n=None, d=None, slide=512, m=256):
     then a verified query against the live set."""
     import jax
 
-    from repro.configs import KDE_WORKLOADS
     from repro.core.mixtures import mixture_for_dim
     from repro.serve import QueryRequest, ServeConfig, ServeEngine
 
-    wl = KDE_WORKLOADS["flash_sdkde_32k"]
-    n, d = n or wl.n_train, d or wl.dim
+    n, d = n or TABLE1[0], d or TABLE1[2]
     x, pool = sample(d, n, m, seed)
     eng = ServeEngine(ServeConfig(backend="pallas", method="sdkde",
                                   block_m="auto", block_n="auto",
@@ -289,11 +291,9 @@ def phase_paper_1m(seed: int, *, n=None, m=None, d=None, verify=1024):
     """Paper §7: a 1M x 16-d SDKDE fit and evaluation on 131k queries
     through the estimator API; the verified rows are spread evenly over
     all queries, so every launch the evaluation splits into is checked."""
-    from repro.configs import KDE_WORKLOADS
     from repro.core.estimator import SDKDE, EstimatorConfig
 
-    wl = KDE_WORKLOADS["flash_sdkde_1m"]
-    n, m, d = n or wl.n_train, m or wl.n_test, d or wl.dim
+    n, m, d = n or PAPER_1M[0], m or PAPER_1M[1], d or PAPER_1M[2]
     x, y = sample(d, n, m, seed)
     est = SDKDE(config=EstimatorConfig(backend="pallas"))
     t0 = time.perf_counter()

@@ -1,8 +1,8 @@
 """Multi-device SD-KDE: the 2-D ring decomposition on a host-device mesh.
 
-Runs the SAME program the flash_sdkde_* dry-run cells lower at 256/512
-chips, on 8 forced host devices, and checks it against the single-device
-reference — the scaled-down multi-pod demonstration.
+Runs ``ring2d_sdkde`` (score rows over ``model``, train columns over
+(pod, data)) on 8 forced host devices, and checks it against the
+single-device reference — the scaled-down multi-pod demonstration.
 
     PYTHONPATH=src python examples/distributed_sdkde.py
 """

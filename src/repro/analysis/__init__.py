@@ -1,6 +1,6 @@
 from repro.analysis.hlo import collective_bytes, hlo_collectives
 from repro.analysis.roofline import RooflineTerms, roofline_from_compiled, HW
-from repro.analysis.flops import model_flops, sdkde_flops, sdkde_bytes
+from repro.analysis.flops import sdkde_flops, sdkde_bytes
 
 __all__ = [
     "collective_bytes",
@@ -8,7 +8,6 @@ __all__ = [
     "RooflineTerms",
     "roofline_from_compiled",
     "HW",
-    "model_flops",
     "sdkde_flops",
     "sdkde_bytes",
 ]

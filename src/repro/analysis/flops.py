@@ -1,4 +1,4 @@
-"""FLOP models: 6·N·D for LMs, and the paper's SD-KDE flop/byte model (§4.1).
+"""FLOP models: the paper's SD-KDE flop/byte model (§4.1).
 
 ``sdkde_flops`` reproduces the paper's tile-aware accounting exactly —
 FLOPs_d(k) = (4d + 12 + d/4 + 3/2)·k² with n_test = k/8, each exp budgeted
@@ -9,21 +9,7 @@ against the paper's 81.5·k² figure for d=16 in tests/test_flop_model.py.
 
 from __future__ import annotations
 
-from repro.models.common import ModelConfig, active_param_count, param_count
-
 EXP_FLOPS = 8  # paper's SFU accounting: 1 exp == 8 FP32 flops
-
-
-# ---------------------------------------------------------------------------
-# LM model FLOPs.
-# ---------------------------------------------------------------------------
-
-
-def model_flops(cfg: ModelConfig, tokens: int, *, training: bool = True) -> float:
-    """MODEL_FLOPS = 6·N·D (training) or 2·N·D (inference); N_active for MoE."""
-    n = active_param_count(cfg)
-    per_token = 6 * n if training else 2 * n
-    return float(per_token) * tokens
 
 
 # ---------------------------------------------------------------------------

@@ -1,16 +1,6 @@
-from repro.data.synthetic import (
-    lm_batch,
-    batch_specs,
-    host_local_batch,
-    PrefetchLoader,
-)
 from repro.data.density import DensityWeighting, density_weights
 
 __all__ = [
-    "lm_batch",
-    "batch_specs",
-    "host_local_batch",
-    "PrefetchLoader",
     "DensityWeighting",
     "density_weights",
 ]

@@ -1,21 +1,5 @@
-"""Distributed runtime: ring-sharded SD-KDE, fault tolerance, elasticity."""
-
-from repro.distributed import ring  # noqa: F401
-from repro.distributed.compression import (  # noqa: F401
-    compress,
-    compressed_psum,
-    decompress,
-    init_residual,
-)
-from repro.distributed.elastic import (  # noqa: F401
-    MeshPlan,
-    make_mesh,
-    plan_mesh,
-    rebatch,
-    reshard_specs,
-)
-from repro.distributed.fault import RestartLoop, Supervisor  # noqa: F401
-from repro.distributed.straggler import (  # noqa: F401
-    DuplicateDispatcher,
-    pick_backup,
-)
+"""Multi-chip SD-KDE: the Flash kernels sharded over every local device
+(``shard``), the XLA rings (``ring``, ``ring2d``), and the replica
+supervisor and mesh planning behind the resilient engine (``fault``,
+``elastic``).  Import the submodule you need; this package re-exports
+nothing."""

@@ -1,20 +1,14 @@
-"""Elastic scaling: mesh planning + state resharding on shrink/grow.
+"""Elastic mesh planning: the largest grid a device count fills.
 
-When the fleet loses (or gains) hosts, the job restarts on a different
-device count.  This module picks the new mesh shape and re-computes every
-sharding for it; checkpoint.restore(shardings=...) then re-places the saved
-state — params, optimizer, data cursor — onto the new mesh.  The TRAINING
-SEMANTICS are preserved by keeping the global batch size fixed and scaling
-the per-device batch (grad-accumulation count absorbs non-divisibility).
+``serve/resilience.py`` re-plans its service mesh with ``plan_mesh``
+when the supervisor fences replicas, so the shrunken fleet keeps a
+well-formed (pod, data, model) grid.
 """
 
 from __future__ import annotations
 
 import dataclasses
-from typing import Dict, Optional, Tuple
-
-import jax
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from typing import Optional, Tuple
 
 
 @dataclasses.dataclass(frozen=True)
@@ -57,52 +51,3 @@ def plan_mesh(
     note = "" if used == n_devices else f"dropping {n_devices - used} devices"
     return dataclasses.replace(plan, note=note)
 
-
-def make_mesh(plan: MeshPlan, devices=None) -> Mesh:
-    devices = devices if devices is not None else jax.devices()
-    n = plan.n_devices
-    import numpy as np
-
-    grid = np.asarray(devices[:n]).reshape(plan.shape)
-    return Mesh(grid, plan.axes)
-
-
-def reshard_specs(
-    pspecs: Dict[str, P], old_mesh_axes: Tuple[str, ...], new_mesh: Mesh
-) -> Dict[str, NamedSharding]:
-    """Map logical PartitionSpecs onto a (possibly smaller) new mesh.
-
-    Axes that disappeared from the mesh (e.g. ``pod`` after a shrink to one
-    pod) are dropped from every spec — those dims become replicated.
-    """
-    live = set(new_mesh.axis_names)
-
-    def fix_entry(e):
-        if e is None:
-            return None
-        if isinstance(e, tuple):
-            kept = tuple(a for a in e if a in live)
-            return kept if kept else None
-        return e if e in live else None
-
-    out = {}
-    for name, spec in pspecs.items():
-        out[name] = NamedSharding(new_mesh, P(*(fix_entry(e) for e in spec)))
-    return out
-
-
-def rebatch(global_batch: int, old_dp: int, new_dp: int,
-            microbatches: int) -> Tuple[int, int, int]:
-    """(per_device_batch, microbatches, new_global) after a dp resize.
-
-    Prefers keeping the global batch exactly (growing the accumulation count
-    until the new dp degree divides); when no exact tiling exists (e.g. 256
-    over 15 hosts), the global batch moves to the NEAREST achievable
-    multiple — training semantics change minimally and deterministically.
-    """
-    for mb in range(microbatches, global_batch + 1):
-        if global_batch % (new_dp * mb) == 0:
-            return global_batch // (new_dp * mb), mb, global_batch
-    mb = microbatches
-    per_dev = max(1, round(global_batch / (new_dp * mb)))
-    return per_dev, mb, per_dev * new_dp * mb

@@ -1,22 +1,17 @@
-"""Fault-tolerance supervisor: heartbeats, failure detection, restart.
+"""Replica health supervisor: heartbeats, failure detection, fencing.
 
-The production posture (1000+ nodes) is checkpoint-restart with elastic
-reshard: every host runs the same SPMD program; a coordinator-side
-``Supervisor`` tracks per-host heartbeats, declares a host dead after
-``timeout`` missed beats, and drives the restart decision:
+``serve/resilience.py`` runs one ``Supervisor`` over its S × R engine
+replicas: every successful attempt heartbeats its replica, a replica
+whose beats stop for ``timeout`` is declared dead, and ``restart_plan``
+decides what to do about it:
 
-  * dead host AND spare capacity   -> restart same-size from checkpoint
-  * dead host AND no spares        -> shrink the mesh (elastic.plan_mesh),
-                                      restore with resharding
-                                      (checkpoint.restore with new shardings)
-  * flapping host (slow heartbeat) -> straggler path, not a failure
+  * dead replicas AND spare capacity -> replace them at the same size
+  * dead replicas AND no spares      -> shrink (``elastic.plan_mesh``)
+  * slow replica (EWMA step time)    -> a straggler, not a failure
 
-On this single-process container the supervisor is exercised by unit tests
-that drive simulated clocks/heartbeats (tests/test_fault.py) and by the
-``launch.train`` driver, which runs a single-host instance of the same
-loop: periodic async checkpoint + automatic restore-on-restart, and a
-simulated failure-injection mode (--inject-failure) that kills and resumes
-the step loop to prove end-to-end restart works.
+A committed decision fences the dead replicas, so a zombie's late beat
+cannot revive one; re-admission hands out a new epoch.  The clock is
+injectable, so tests drive it deterministically.
 """
 
 from __future__ import annotations
@@ -24,8 +19,6 @@ from __future__ import annotations
 import dataclasses
 import time
 from typing import Callable, Dict, Iterable, List, Optional
-
-from repro.fault_injection import InjectedFailure
 
 
 @dataclasses.dataclass
@@ -169,37 +162,3 @@ class Supervisor:
             }
         return {"action": "shrink", "dead": dead, "new_size": live}
 
-
-@dataclasses.dataclass
-class RestartLoop:
-    """Single-host skeleton of the restart-from-checkpoint loop used by
-    launch/train.py: run step_fn until done, checkpointing every
-    ``ckpt_every``; on (simulated or real) failure, restore and continue.
-    """
-
-    step_fn: Callable[[int], None]          # executes step i
-    save_fn: Callable[[int], None]          # checkpoint at step i
-    restore_fn: Callable[[], int]           # -> step to resume from
-    ckpt_every: int = 50
-
-    def run(self, total_steps: int, *, fail_at: Optional[int] = None) -> int:
-        """Returns the number of (re)starts it took."""
-        starts = 0
-        done = 0
-        while done < total_steps:
-            starts += 1
-            start = self.restore_fn()
-            try:
-                for i in range(start, total_steps):
-                    if fail_at is not None and i == fail_at and starts == 1:
-                        raise InjectedFailure("node_failure",
-                                              point="restart_loop")
-                    self.step_fn(i)
-                    done = i + 1
-                    if (i + 1) % self.ckpt_every == 0:
-                        self.save_fn(i + 1)
-            except InjectedFailure:
-                continue   # supervisor restarts us; restore_fn resumes
-            # any other exception — a real bug in step_fn — propagates:
-            # absorbing it here would turn regressions into silent retries
-        return starts

@@ -37,7 +37,7 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core.bandwidth import gaussian_norm_const
 from repro.core.kde import PAD_VALUE, sqdist
@@ -136,7 +136,7 @@ def ring2d_sdkde(
 ) -> jnp.ndarray:
     """Full SD-KDE on the production mesh; returns densities at ``y``.
 
-    Program structure (the flash_sdkde_* dry-run cells):
+    Program structure:
       1. score pass: rows of X over ``model``, X columns over (pod, data)
       2. shift (elementwise, stays model-row-sharded)
       3. KDE pass: rows of Y over ``model``, shifted X columns over
@@ -157,21 +157,6 @@ def ring2d_sdkde(
     )
     h = jnp.asarray(h, jnp.float32)
     return sums / (n_true * gaussian_norm_const(d, 1.0) * h**d)
-
-
-def kde_input_specs(n: int, m: int, d: int, mesh: Mesh):
-    """ShapeDtypeStructs for the dry-run: x column-sharded, y row-sharded."""
-    pod, col_axes = _axes(mesh)
-    return (
-        jax.ShapeDtypeStruct(
-            (n, d), jnp.float32,
-            sharding=NamedSharding(mesh, P(col_axes, None)),
-        ),
-        jax.ShapeDtypeStruct(
-            (m, d), jnp.float32,
-            sharding=NamedSharding(mesh, P("model", None)),
-        ),
-    )
 
 
 def pad_for_mesh(x: jnp.ndarray, mesh: Mesh) -> jnp.ndarray:

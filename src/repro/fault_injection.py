@@ -50,9 +50,9 @@ duplicates running on worker threads are attributed to the replica they
 actually target).
 
 ``InjectedFailure`` is the one exception type every injected fault
-raises; the fault-tolerant layers (``serve/resilience.py``,
-``distributed/fault.py``'s RestartLoop) catch exactly it and re-raise
-everything else — a real bug must never be absorbed as chaos.
+raises; the fault-tolerant layer (``serve/resilience.py``) catches
+exactly it and re-raises everything else — a real bug must never be
+absorbed as chaos.
 """
 
 from __future__ import annotations

@@ -52,32 +52,3 @@ def ref_sdkde_shift(x: jnp.ndarray, h: float, score_h: float | None = None):
     )
     return x.astype(jnp.float32) + 0.5 * h * h * score
 
-
-def ref_selective_scan(xi, dt, b, c, a, h0):
-    """Oracle for kernels/selective_scan.py: plain sequential recurrence.
-
-    h_t = exp(Δ_t A) ⊙ h_{t-1} + (Δ_t x_t)·B_t ;  y_t = C_t · h_t.
-    Shapes: xi/dt (B,S,D), b/c (B,S,N), a (D,N), h0 (B,D,N).
-    Returns (y (B,S,D) f32, h_final (B,D,N) f32).
-    """
-    xi = xi.astype(jnp.float32)
-    dt = dt.astype(jnp.float32)
-    b = b.astype(jnp.float32)
-    c = c.astype(jnp.float32)
-    a = a.astype(jnp.float32)
-
-    def step(h, inputs):
-        xi_t, dt_t, b_t, c_t = inputs
-        decay = jnp.exp(dt_t[:, :, None] * a[None])        # (B,D,N)
-        h = decay * h + (dt_t * xi_t)[:, :, None] * b_t[:, None, :]
-        y = jnp.einsum("bdn,bn->bd", h, c_t)
-        return h, y
-
-    import jax
-
-    h, ys = jax.lax.scan(
-        step, h0.astype(jnp.float32),
-        (xi.swapaxes(0, 1), dt.swapaxes(0, 1),
-         b.swapaxes(0, 1), c.swapaxes(0, 1)),
-    )
-    return ys.swapaxes(0, 1), h

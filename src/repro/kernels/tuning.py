@@ -1,11 +1,10 @@
 """Flash-kernel performance model + block-size hillclimb (paper §4.1/§6.2 on TPU).
 
-The compiled dry-run measures the XLA-GEMM path, where the φ matrix spills
-to HBM between the Gram dot, the exponential, and the S1 GEMM (the measured
-memory-bound baseline of the flash_sdkde_* cells).  The Pallas kernels keep
-φ in VMEM — their HBM traffic is the PAPER'S tile model (§4.1), which this
-module evaluates per (block_m, block_n) under the v5e VMEM budget, exactly
-the launch-parameter hillclimb of §6.2 with TPU constraints instead of
+On the XLA-GEMM path the φ matrix spills to HBM between the Gram dot, the
+exponential, and the S1 GEMM.  The Pallas kernels keep φ in VMEM — their
+HBM traffic is the PAPER'S tile model (§4.1), which this module evaluates
+per (block_m, block_n) under the v5e VMEM budget, exactly the
+launch-parameter hillclimb of §6.2 with TPU constraints instead of
 warps/stages.
 
 Compute is a TWO-resource model — the TPU analogue of the paper's
@@ -129,21 +128,6 @@ def sdkde_device_cost(
     kde = pair_pass_cost(m // model_shards, n // col_shards, d,
                          block_m=block_m, block_n=block_n, out_width=1)
     return score, kde
-
-
-def selective_scan_bytes(bsz: int, s: int, d: int, n: int,
-                         itemsize: int = 2) -> Tuple[float, float]:
-    """(kernel HBM bytes, XLA-path HBM bytes) for the Mamba selective scan.
-
-    Kernel (kernels/selective_scan.py): stream xi/Δ/B/C in, y out — the
-    (S, d, N) state tensor never leaves VMEM.
-    XLA path (models/ssm.py): the associative scan materializes decay and
-    drive (B,S,d,N) f32 and re-reads them ~log passes; we count the
-    minimal 2 tensors × (write + read) — a LOWER bound on its traffic.
-    """
-    kernel = bsz * s * (2 * d * itemsize + 2 * n * itemsize + 4 * d)
-    xla = 2 * 2 * bsz * s * d * n * 4
-    return float(kernel), float(xla)
 
 
 def sweep_blocks(

@@ -1,4 +1,5 @@
-"""Launchers: production mesh, multi-pod dry-run, train and serve drivers."""
+"""Entry points: the density serving driver (``serve_kde``) and the
+persistent compile cache every entry point turns on."""
 
 from __future__ import annotations
 

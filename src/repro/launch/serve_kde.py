@@ -1,7 +1,6 @@
 """KDE query-serving driver: fit once, answer ragged query traffic.
 
-The density analogue of ``repro.launch.serve`` (the LM serving driver):
-registers a dataset with the ``repro.serve`` engine (the one-time quadratic
+Registers a dataset with the ``repro.serve`` engine (the one-time quadratic
 debias pass — "prefill"), then serves a stream of variable-size query
 batches (cheap GEMMs — "decode") and reports throughput, tail latency, and
 shape-bucket cache efficiency.

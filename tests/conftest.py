@@ -1,5 +1,6 @@
 """Shared fixtures.  NOTE: no XLA_FLAGS here — smoke tests and benches must
-see the real single CPU device; only launch/dryrun.py forces 512."""
+see the real single CPU device; a test that needs several host devices
+forces them in a child process of its own."""
 
 import contextlib
 
@@ -79,27 +80,45 @@ def _sums64(x, y, h):
     return np.exp(-scaled), scaled
 
 
+def _by_rows(fn, y, rows=256):
+    """``fn`` over blocks of ``rows`` query rows, concatenated: each row's
+    sums are independent, and a block bounds the (rows, n, d) float64
+    temporaries."""
+    y = np.asarray(y)
+    parts = [fn(y[i:i + rows]) for i in range(0, len(y), rows)]
+    return tuple(np.concatenate(p) for p in zip(*parts))
+
+
 class F64:
     """float64 references of what the kernels' wrappers return."""
 
     @staticmethod
     def kde(x, y, h):
         n, d = np.shape(x)
-        phi, _ = _sums64(x, y, h)
-        return phi.sum(1) / (n * (2 * np.pi) ** (d / 2) * h ** d)
+        (s,) = _by_rows(lambda yb: (_sums64(x, yb, h)[0].sum(1),), y)
+        return s / (n * (2 * np.pi) ** (d / 2) * h ** d)
 
     @staticmethod
     def laplace(x, y, h):
         n, d = np.shape(x)
-        phi, scaled = _sums64(x, y, h)
-        return (phi * (1 + d / 2 - scaled)).sum(1) / (
-            n * (2 * np.pi) ** (d / 2) * h ** d)
+
+        def sums(yb):
+            phi, scaled = _sums64(x, yb, h)
+            return ((phi * (1 + d / 2 - scaled)).sum(1),)
+
+        (s,) = _by_rows(sums, y)
+        return s / (n * (2 * np.pi) ** (d / 2) * h ** d)
 
     @staticmethod
     def score(x, h):
         """(S0, S1) of the score pass, train against itself."""
-        phi, _ = _sums64(x, x, h)
-        return phi.sum(1), phi @ np.asarray(x, np.float64)
+        x64 = np.asarray(x, np.float64)
+
+        def stats(xb):
+            phi, _ = _sums64(x64, xb, h)
+            return phi.sum(1), phi @ x64
+
+        return _by_rows(stats, x64)
 
 
 @pytest.fixture(scope="session")

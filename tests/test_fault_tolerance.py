@@ -1,21 +1,8 @@
-"""Fault-tolerance substrate: supervisor, restart loop, straggler dispatch,
-elastic mesh planning, gradient compression."""
+"""Fault-tolerance substrate of the resilient engine: the replica
+supervisor (heartbeats, stragglers, fencing) and elastic mesh planning."""
 
-import time
-
-import jax
-import jax.numpy as jnp
-import numpy as np
-import pytest
-
-from repro.distributed.compression import (
-    compress,
-    decompress,
-    init_residual,
-)
-from repro.distributed.elastic import plan_mesh, rebatch, reshard_specs
-from repro.distributed.fault import RestartLoop, Supervisor
-from repro.distributed.straggler import DuplicateDispatcher, pick_backup
+from repro.distributed.elastic import plan_mesh
+from repro.distributed.fault import Supervisor
 
 
 # -- supervisor --------------------------------------------------------------
@@ -53,49 +40,6 @@ def test_supervisor_straggler_detection():
     assert sup.fleet_step() == 5
 
 
-def test_restart_loop_resumes_from_checkpoint():
-    executed = []
-    saved = {"step": 0}
-
-    loop = RestartLoop(
-        step_fn=lambda i: executed.append(i),
-        save_fn=lambda s: saved.update(step=s),
-        restore_fn=lambda: saved["step"],
-        ckpt_every=10,
-    )
-    starts = loop.run(50, fail_at=25)
-    assert starts == 2
-    # steps 20..24 re-executed after restart from checkpoint at 20
-    assert executed == list(range(0, 25)) + list(range(20, 50))
-
-
-# -- straggler dispatch -------------------------------------------------------
-
-
-def test_duplicate_dispatch_backup_wins():
-    d = DuplicateDispatcher(deadline=0.05)
-
-    def work(host):
-        if host == 0:
-            time.sleep(0.5)    # straggling primary
-        return host
-
-    result, winner = d.run(work, primary=0, backup=1)
-    assert winner == 1 and result == 1
-    d.close()
-
-
-def test_duplicate_dispatch_primary_fast_path():
-    d = DuplicateDispatcher(deadline=1.0)
-    result, winner = d.run(lambda h: h, primary=0, backup=1)
-    assert winner == 0
-    d.close()
-
-
-def test_pick_backup_fastest():
-    assert pick_backup({0: 5.0, 1: 1.0, 2: 2.0}, straggler=0) == 1
-
-
 # -- elastic -------------------------------------------------------------------
 
 
@@ -112,61 +56,17 @@ def test_plan_mesh_shrink():
     assert p.n_devices <= 250
 
 
-def test_rebatch_exact_when_divisible():
-    per_dev, mb, new_gb = rebatch(256, old_dp=16, new_dp=8, microbatches=8)
-    assert per_dev * 8 * mb == 256 and new_gb == 256
+def test_plan_mesh_awkward_counts():
+    # prime count: model axis folds down to 1, everything becomes data
+    p = plan_mesh(7, model_parallel=16)
+    assert p.shape == (7, 1) and p.n_devices == 7
+    # single device
+    p = plan_mesh(1, model_parallel=16)
+    assert p.n_devices == 1
+    # non-dividing want_pods falls back to a 2-axis mesh
+    p = plan_mesh(256, model_parallel=16, want_pods=3)
+    assert p.axes == ("data", "model")
 
-
-def test_rebatch_nearest_when_impossible():
-    # 15 hosts never tile 256 exactly -> nearest achievable multiple
-    per_dev, mb, new_gb = rebatch(256, old_dp=16, new_dp=15, microbatches=8)
-    assert new_gb == per_dev * 15 * mb
-    assert abs(new_gb - 256) <= 15 * mb // 2 + 1
-
-
-def test_reshard_specs_drops_dead_axes():
-    from jax.sharding import PartitionSpec as P
-    from repro.distributed.elastic import make_mesh
-
-    plan = plan_mesh(1, model_parallel=1)
-    mesh = make_mesh(plan)
-    specs = reshard_specs(
-        {"w": P(("pod", "data"), "model"), "b": P(None, "pod")},
-        ("pod", "data", "model"), mesh,
-    )
-    assert specs["w"].spec == P(("data",), "model")
-    assert specs["b"].spec == P(None, None)
-
-
-# -- compression ----------------------------------------------------------------
-
-
-def test_int8_compression_error_feedback():
-    g = {"w": jnp.array([[0.5, -0.25], [1.0, 0.003]], jnp.float32)}
-    res = init_residual(g)
-    q, s, res1 = compress(g, res)
-    assert q["w"].dtype == jnp.int8
-    out = decompress(q, s)
-    # error feedback: residual + dequantized == original exactly
-    np.testing.assert_allclose(
-        np.asarray(out["w"] + res1["w"]), np.asarray(g["w"]), rtol=1e-6
-    )
-
-
-def test_compression_converges_with_feedback():
-    """Accumulated compressed updates track the true sum (unbiased-ish)."""
-    key = jax.random.PRNGKey(0)
-    true_sum = jnp.zeros((64,))
-    got_sum = jnp.zeros((64,))
-    res = {"g": jnp.zeros((64,))}
-    for i in range(50):
-        g = {"g": jax.random.normal(jax.random.fold_in(key, i), (64,))}
-        q, s, res = compress(g, res)
-        out = decompress(q, s)
-        true_sum = true_sum + g["g"]
-        got_sum = got_sum + out["g"]
-    err = float(jnp.linalg.norm(got_sum - true_sum) / jnp.linalg.norm(true_sum))
-    assert err < 0.02, err
 
 # -- fencing epoch (PR 8) -----------------------------------------------------
 
@@ -223,69 +123,3 @@ def test_restart_plan_fencing_is_idempotent():
     assert sup2.restart_plan()["dead"] == [0, 1]
     assert sup2.fenced() == []
     assert sup2.beat(0, 1) is True
-
-
-# -- restart loop error taxonomy (PR 8) --------------------------------------
-
-
-def test_restart_loop_propagates_real_bugs():
-    """Only InjectedFailure is retried; a genuine step_fn bug must surface."""
-    executed = []
-
-    def step(i):
-        executed.append(i)
-        if i == 3:
-            raise ZeroDivisionError("real bug in step 3")
-
-    loop = RestartLoop(step_fn=step, save_fn=lambda s: None,
-                       restore_fn=lambda: 0, ckpt_every=10)
-    with pytest.raises(ZeroDivisionError, match="real bug"):
-        loop.run(10)
-    assert executed == [0, 1, 2, 3]     # no silent retry loop
-
-
-def test_restart_loop_still_retries_injected_failure():
-    from repro.fault_injection import InjectedFailure  # noqa: F401
-
-    loop = RestartLoop(step_fn=lambda i: None, save_fn=lambda s: None,
-                       restore_fn=lambda: 0, ckpt_every=100)
-    assert loop.run(5, fail_at=2) == 2
-
-
-# -- elastic edge cases (PR 8) ------------------------------------------------
-
-
-def test_rebatch_non_divisible_device_count():
-    # 100 over 7 hosts never tiles exactly: nearest achievable multiple,
-    # with the invariant new_gb == per_dev * dp * mb
-    per_dev, mb, new_gb = rebatch(100, old_dp=4, new_dp=7, microbatches=3)
-    assert per_dev >= 1 and new_gb == per_dev * 7 * mb
-    assert abs(new_gb - 100) <= 7 * mb
-
-
-def test_rebatch_shrink_to_single_host():
-    per_dev, mb, new_gb = rebatch(256, old_dp=16, new_dp=1, microbatches=8)
-    assert new_gb == 256 and per_dev * mb == 256
-
-
-def test_plan_mesh_awkward_counts():
-    # prime count: model axis folds down to 1, everything becomes data
-    p = plan_mesh(7, model_parallel=16)
-    assert p.shape == (7, 1) and p.n_devices == 7
-    # single device
-    p = plan_mesh(1, model_parallel=16)
-    assert p.n_devices == 1
-    # non-dividing want_pods falls back to a 2-axis mesh
-    p = plan_mesh(256, model_parallel=16, want_pods=3)
-    assert p.axes == ("data", "model")
-
-
-def test_reshard_specs_vanished_tuple_axis():
-    from jax.sharding import PartitionSpec as P
-    from repro.distributed.elastic import make_mesh
-
-    plan = plan_mesh(1, model_parallel=1)
-    mesh = make_mesh(plan)
-    # a dim sharded ONLY over vanished axes becomes fully replicated
-    specs = reshard_specs({"w": P(("pod",), None)}, ("pod", "data"), mesh)
-    assert specs["w"].spec == P(None, None)

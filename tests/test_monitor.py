@@ -1,7 +1,6 @@
 """Activation-density OOD monitor: separates in- from out-of-distribution."""
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
 from repro.core.monitor import ActivationMonitor, pool_activations
@@ -31,25 +30,3 @@ def test_monitor_flags_ood():
     s_ood = np.asarray(mon.score(ood)).mean()
     assert s_in > s_ood + 5.0
 
-
-def test_monitor_end_to_end_with_lm():
-    """Wire the monitor to real model activations (reduced config)."""
-    import dataclasses
-
-    from repro.configs import get_arch
-    from repro.data.synthetic import lm_batch
-    from repro.models.common import init_params
-    from repro.models.transformer import forward_hidden
-
-    arch = get_arch("gemma2_2b")
-    cfg = arch.model.reduced(dtype=jnp.float32)
-    params = init_params(cfg, jax.random.PRNGKey(0))
-
-    def acts(batch):
-        h, _ = forward_hidden(params, batch["tokens"], cfg)
-        return pool_activations(h)
-
-    ref = acts(lm_batch(cfg, 0, 0, 32, 16))
-    mon = ActivationMonitor(proj_dim=4, quantile=0.05).fit(ref)
-    scores = mon.score(acts(lm_batch(cfg, 0, 1, 8, 16)))
-    assert np.isfinite(np.asarray(scores)).all()

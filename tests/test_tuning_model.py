@@ -62,14 +62,6 @@ def test_kernel_path_is_vpu_bound_at_1m():
     assert s.t_vpu > 3 * s.t_hbm
 
 
-def test_selective_scan_kernel_byte_advantage():
-    """falcon-mamba prefill: kernel traffic ≥8× below the XLA path."""
-    from repro.kernels.tuning import selective_scan_bytes
-
-    kern, xla = selective_scan_bytes(2, 32768, 8192, 16)
-    assert xla / kern > 8, (kern, xla)
-
-
 def test_best_blocks_returns_feasible_minimum():
     best = best_blocks(65536, 65536, 16, out_width=17)
     assert best.vmem_bytes <= VMEM_BUDGET
