@@ -19,9 +19,10 @@ the rows are sharded, every chip holds every column, and nothing rotates.
               would leave the last chips only sentinel tiles); the sums
               come back in request order.
 
-The pruned passes compact their visit lists on the host, per chip, with
-data-dependent extents, so each chip gets its own launches from the host,
-dispatched asynchronously.  Everything a chip's programs read is put on
+The pruned passes compact their visit lists on each chip, with
+data-dependent extents that the host reads from that chip's counts, so
+each chip gets its own launches from the host, dispatched
+asynchronously.  Everything a chip's programs read is put on
 that chip before any kernel runs, and what the host makes for a launch
 (visit lists, scalars) is made there (``jax.default_device``): a copy from
 the first chip would wait behind the first chip's kernel, and the chips

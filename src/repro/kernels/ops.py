@@ -57,7 +57,7 @@ _STATIC = ("precision", "block_m", "block_n", "interpret")
 PruneArg = Union[str, float]  # "auto" | "off" | epsilon ≥ 0
 
 #: ``prune="auto"`` enables exact pruning only past these sizes — below
-#: them the bounds prepass and host-side visit-list compaction cost more
+#: them the bounds prepass and visit-list compaction cost more
 #: than the skipped tiles were worth.
 PRUNE_AUTO_MIN_COLS = 16384
 PRUNE_AUTO_MIN_TILES = 4
@@ -113,7 +113,7 @@ def _apply_plan(plan, n: int, m: int, d: int, *,
 def _traced(*arrays) -> bool:
     """True when any argument is an abstract tracer (jit/vmap/grad).
 
-    The pruned path host-syncs (visit-list compaction, layout shapes), so
+    The pruned path host-syncs (visit-list extents, layout shapes), so
     under tracing the public wrappers silently fall back to dense — the
     pre-pruning behavior, and the only one that can stay a single jaxpr.
     """
@@ -1044,7 +1044,7 @@ def _pruned_eval_sums(
     path); only real rows enter the query layout.  This is the pruned
     path's one host-sync orchestration: assign queries to the train
     clusters → scatter into a cluster-aligned layout → bounds prepass →
-    compact visit lists (host) → launch → gather back to request order,
+    compact visit lists → launch → gather back to request order,
     each step in its own ``kernels.prune.*`` span.  Callers open the
     ``kernels.prune.pass`` span around it and the train-side prep.
     """
@@ -1131,8 +1131,8 @@ def flash_kde_prepared(
     ``columns`` (prepared with ``clustered=True``, so the tile metadata and
     spatial index are fit-time state) and ``n_real`` = the true query count
     so sentinel padding rows stay out of the row-tile geometry.  The dense
-    path stays jit-traceable; the pruned path host-syncs once per batch to
-    compact its visit lists.
+    path stays jit-traceable; the pruned path host-syncs once per batch for
+    its visit lists' extent.
     """
     prec.validate(precision)
     if _traced(yp):

@@ -47,7 +47,7 @@ error budgets certified per tile):
      tiles is returned as a per-row-tile error certificate (tests assert
      the float64 dropped mass never exceeds it).
 
-The kept tiles are compacted into per-row-tile visit lists
+The kept tiles are compacted, on the device, into per-row-tile visit lists
 (``tile_map[i, k]`` = k-th column tile row block ``i`` must stream), which
 the pruned kernels consume via scalar prefetch — the grid shrinks from
 ``m_tiles × n_tiles`` to ``m_tiles × max_visits``.
@@ -123,7 +123,8 @@ class TileMap(NamedTuple):
 
 
 class VisitLists(NamedTuple):
-    """Host-compacted tile map in the layout the pruned kernels prefetch."""
+    """Compacted tile map in the layout the pruned kernels prefetch, on the
+    keep matrix's device."""
 
     counts: jnp.ndarray      # (mt,) int32 visits per row tile
     tile_map: jnp.ndarray    # (mt, max_visits) int32 column-tile indices
@@ -502,28 +503,48 @@ def tile_map(
     return TileMap(keep, err)
 
 
+@jax.jit
+def _row_counts(keep: jnp.ndarray) -> jnp.ndarray:
+    return jnp.sum(keep, axis=1, dtype=jnp.int32)
+
+
+@functools.partial(jax.jit, static_argnames=("max_visits",))
+def _compact(keep: jnp.ndarray, counts: jnp.ndarray, *,
+             max_visits: int) -> jnp.ndarray:
+    """Each row's kept column tiles in ascending order, then its first kept
+    tile (0 for an empty row) in the slots past its count.  Kept tile j
+    sorts by key j, a skipped one by j + t: the keys are unique, so the sort
+    is the stable sort of the skip flags."""
+    mt, t = keep.shape
+    j = lax.broadcasted_iota(jnp.int32, (mt, t), 1)
+    order = lax.sort(jnp.where(keep, j, j + t), dimension=1)[:, :max_visits]
+    fill = jnp.where(counts > 0, order[:, 0], 0)
+    pad = lax.broadcasted_iota(jnp.int32, order.shape, 1) >= counts[:, None]
+    return jnp.where(pad, fill[:, None], order)
+
+
 def visit_lists(keep, *, bucket_visits: bool = True) -> VisitLists:
-    """Compact a keep matrix into the prefetched visit-list layout.
+    """Compact a keep matrix into the prefetched visit-list layout, on the
+    device that holds it.
 
     This is the one host-sync point of the pruned path: the grid's static
-    ``max_visits`` extent must be a Python int.  ``bucket_visits`` rounds it
-    up to a power of two (capped at n_tiles) so ragged traffic reuses at
-    most log2(n_tiles) compiled grid shapes per launch config; slots past a
-    row's count are masked out in-kernel (they replay the row's first kept
-    tile, keeping the DMA stream warm and valid).
+    ``max_visits`` extent must be a Python int, so the per-row-tile counts
+    (4 bytes a row tile) come to the host; the keep matrix stays on its
+    device.  ``bucket_visits`` rounds the extent up to a power of two
+    (capped at n_tiles) so ragged traffic reuses at most log2(n_tiles)
+    compiled grid shapes per launch config; slots past a row's count are
+    masked out in-kernel (they replay the row's first kept tile, keeping
+    the DMA stream warm and valid).
     """
-    k = to_host(keep)
-    mt, t = k.shape
-    counts = k.sum(axis=1).astype(np.int32)
-    kmax = max(int(counts.max(initial=0)), 1)
+    counts = _row_counts(keep)
+    c = to_host(counts)
+    t = keep.shape[1]
+    kmax = max(int(c.max(initial=0)), 1)
     if bucket_visits and kmax < t:
         kmax = min(t, 1 << max(0, math.ceil(math.log2(kmax))))
-    order = np.argsort(~k, axis=1, kind="stable")[:, :kmax].astype(np.int32)
-    fill = np.where(counts > 0, order[:, 0], 0).astype(np.int32)
-    pad = np.arange(kmax)[None, :] >= counts[:, None]
-    tmap = np.where(pad, fill[:, None], order)
-    occ = float(counts.mean() / t) if t else 1.0
-    return VisitLists(jnp.asarray(counts), jnp.asarray(tmap), int(kmax), occ)
+    occ = float(c.mean() / t) if t else 1.0
+    return VisitLists(counts, _compact(keep, counts, max_visits=kmax),
+                      kmax, occ)
 
 
 def partition_clusters(labels, n_shards: int) -> np.ndarray:

@@ -598,7 +598,7 @@ class ServeEngine:
             eps = ops.resolve_prune(cfg.prune, prep.n_true, prep.block_n)
             if eps is not None and cols.meta is not None:
                 # Pruned path: not a single jit program — the per-batch
-                # bounds prepass host-syncs to compact visit lists, and
+                # bounds prepass host-syncs for the visit lists' extent, and
                 # flash_kde_prepared jit-caches the kernel per bucketed
                 # visit extent underneath.
                 def pruned_fn(yp, n_real):

@@ -26,9 +26,9 @@ KDE_STEPS = ["kernels.prune.columns", "kernels.prune.layout",
              "kernels.prune.operands", "kernels.prune.tile_map",
              "kernels.prune.visit_lists", "kernels.prune.profile",
              "kernels.pruned_eval", "kernels.prune.gather"]
-#: Fetches per flash_sdkde: the score pass pulls its labels, keep matrix
+#: Fetches per flash_sdkde: the score pass pulls its labels, visit counts
 #: and certificate maximum; the kde pass its train labels, query labels,
-#: keep matrix, real-column count and certificate maximum.
+#: visit counts, real-column count and certificate maximum.
 SYNCS = 8
 
 
@@ -49,6 +49,20 @@ def data():
     x = x.at[: N // 2].add(6.0)             # two far clusters: tiles skip
     y = jax.random.normal(ky, (M, D))
     return x, y
+
+
+@pytest.fixture
+def built_lists(monkeypatch):
+    """Every ``VisitLists`` the pruned passes build, in order."""
+    made = []
+    compact = spatial.visit_lists
+
+    def visit_lists(keep, **kw):
+        made.append(compact(keep, **kw))
+        return made[-1]
+
+    monkeypatch.setattr(spatial, "visit_lists", visit_lists)
+    return made
 
 
 def _sdkde(data, prune):
@@ -119,7 +133,8 @@ def _sync_bytes_by_step():
     return out
 
 
-def test_every_host_sync_is_counted_once_and_repeats_exactly(data):
+def test_every_host_sync_is_counted_once_and_repeats_exactly(data,
+                                                            built_lists):
     _sdkde(data, 0.0)                        # compiles, fills the profile
     gained, steps = [], []
     for _ in range(2):
@@ -146,27 +161,22 @@ def test_every_host_sync_is_counted_once_and_repeats_exactly(data):
     assert got[("kde", layout)] == M * 4
     assert got[("score", profile)] == 4          # f32 certificate maximum
     assert got[("kde", profile)] == 4 + 4        # + int32 real-column count
-    # one bool per (row tile, column tile) of the padded layouts
-    assert got[("score", visits)] >= (N // BLOCK_M) * (N // BLOCK_N)
-    assert got[("kde", visits)] >= -(-M // BLOCK_M) * (N // BLOCK_N)
+    # one int32 count per row tile of the padded layouts: the keep matrix
+    # stays on the device
+    score_tiles, kde_tiles = (len(vl.counts) for vl in built_lists[-2:])
+    assert score_tiles >= N // BLOCK_M and kde_tiles >= -(-M // BLOCK_M)
+    assert got[("score", visits)] == 4 * score_tiles
+    assert got[("kde", visits)] == 4 * kde_tiles
 
 
-def test_score_launch_span_counts_its_grid_steps(data, monkeypatch):
+def test_score_launch_span_counts_its_grid_steps(data, built_lists):
     """``kernels.pruned_score`` carries the visit slots each grid step runs
     and the launch's grid steps: one per ``slots_per_step`` visit slots of
     every row tile, all row groups together."""
     from repro.kernels import flash_pruned
 
-    lists = []
-    compact = spatial.visit_lists
-
-    def visit_lists(keep, **kw):
-        lists.append(compact(keep, **kw))
-        return lists[-1]
-
-    monkeypatch.setattr(spatial, "visit_lists", visit_lists)
     _sdkde(data, 0.0)
-    vl = lists[0]                             # the score pass's, first
+    vl = built_lists[0]                       # the score pass's, first
     mt = vl.tile_map.shape[0]
     attrs = next(e["attrs"] for e in obs.trace_events()
                  if e["name"] == "kernels.pruned_score")

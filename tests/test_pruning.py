@@ -108,6 +108,65 @@ def test_visit_lists_layout():
     assert vl.occupancy == pytest.approx(6 / 12)
 
 
+def _host_visit_lists(keep, bucket_visits):
+    """The host compaction the device one replaced: a stable argsort of the
+    skip flags, slots past a row's count filled with its first kept tile."""
+    k = np.asarray(keep)
+    mt, t = k.shape
+    counts = k.sum(axis=1).astype(np.int32)
+    kmax = max(int(counts.max(initial=0)), 1)
+    if bucket_visits and kmax < t:
+        kmax = min(t, 1 << max(0, math.ceil(math.log2(kmax))))
+    order = np.argsort(~k, axis=1, kind="stable")[:, :kmax].astype(np.int32)
+    fill = np.where(counts > 0, order[:, 0], 0).astype(np.int32)
+    pad = np.arange(kmax)[None, :] >= counts[:, None]
+    return counts, np.where(pad, fill[:, None], order), kmax, \
+        float(counts.mean() / t)
+
+
+def _keep(kind, mt, t, seed=0):
+    rng = np.random.default_rng(seed)
+    if kind.startswith("random"):
+        return rng.random((mt, t)) < float(kind.split("_")[1])
+    if kind == "edges":            # empty rows, full rows, one tile, last
+        k = rng.random((mt, t)) < 0.5
+        k[::4] = False
+        k[1::4] = True
+        k[2::8] = False
+        k[2::8, -1] = True
+        return k
+    assert kind == "narrow", kind  # max_visits well below t
+    k = np.zeros((mt, t), bool)
+    for i in range(mt):
+        k[i, rng.choice(t, size=rng.integers(0, 4), replace=False)] = True
+    return k
+
+
+@pytest.mark.parametrize("bucket", [True, False])
+@pytest.mark.parametrize("kind, mt, t", [
+    ("random_0.05", 64, 32), ("random_0.5", 64, 32), ("random_0.95", 64, 32),
+    ("random_0.916", 96, 256), ("edges", 64, 32), ("random_0.5", 1, 16),
+    ("edges", 1, 7), ("random_0.3", 40, 48), ("random_0.7", 17, 100),
+    ("narrow", 50, 64), ("narrow", 33, 37),
+])
+def test_visit_lists_match_the_host_compaction(kind, mt, t, bucket):
+    """The device compaction is the host one, bit for bit, and leaves its
+    arrays on the device."""
+    keep = _keep(kind, mt, t)
+    vl = spatial.visit_lists(jnp.asarray(keep), bucket_visits=bucket)
+    counts, tmap, kmax, occ = _host_visit_lists(keep, bucket)
+    assert isinstance(vl.counts, jax.Array)
+    assert isinstance(vl.tile_map, jax.Array)
+    assert vl.counts.dtype == jnp.int32 and vl.tile_map.dtype == jnp.int32
+    np.testing.assert_array_equal(np.asarray(vl.counts), counts)
+    assert vl.tile_map.shape == tmap.shape
+    np.testing.assert_array_equal(np.asarray(vl.tile_map), tmap)
+    assert vl.max_visits == kmax
+    assert vl.occupancy == occ
+    if kind == "narrow":
+        assert vl.max_visits < t
+
+
 # ---------------------------------------------------------------------------
 # Certificates vs float64 ground truth (adversarial geometries included).
 # ---------------------------------------------------------------------------

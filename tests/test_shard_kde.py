@@ -38,6 +38,7 @@ from repro import obs
 from repro.core import kde as ref
 from repro.core.estimator import SDKDE, EstimatorConfig
 from repro.distributed import shard
+from repro.kernels import spatial
 
 N, D, M, TILES = json.loads(sys.argv[1])
 out = {"devices": len(jax.devices())}
@@ -58,9 +59,25 @@ def rel(got, want):
     return [float(r.max()), float(np.median(r))]
 
 
+visits = []
+_visit_lists = spatial.visit_lists
+
+
+def visit_lists(keep, **kw):
+    vl = _visit_lists(keep, **kw)
+    visits.append({"rows": keep.shape[0],
+                   "on": [str(a.devices()) for a in (keep, vl.counts,
+                                                      vl.tile_map)],
+                   "committed": [vl.counts.committed,
+                                 vl.tile_map.committed]})
+    return vl
+
+
+spatial.visit_lists = visit_lists
 obs.configure(metrics=True, trace=True)
 for precision in ("f32", "bf16x2"):
     obs.clear_trace()
+    visits.clear()
     # every array a chip's programs read is put there explicitly: an
     # implicit copy from the first chip would queue behind its kernels
     with jax.transfer_guard_device_to_device("disallow"):
@@ -77,6 +94,8 @@ for precision in ("f32", "bf16x2"):
                      for a in (est.x_sd, dens)],
     }
     if precision == "f32":
+        out["visits"] = [dict(v, chip=str({jax.devices()[k % 4]}))
+                         for k, v in enumerate(visits[:8])]
         by_id = {e["id"]: e["name"] for e in events}
         out["spans"] = [[e["name"], e["attrs"], by_id.get(e["parent"])]
                         for e in events]
@@ -231,6 +250,20 @@ def test_sharded_passes_have_their_spans(child):
                if n == "distributed.shard.gather"]
     assert len(gathers) == 4 and all(b > 0 for b, _ in gathers)
     assert child["transfers"] > 0
+
+
+def test_each_chip_compacts_its_own_visit_lists(child):
+    """Each chip's visit lists are made and committed on that chip, and
+    the host fetches only their counts: 4 bytes a row tile, never the
+    keep matrix."""
+    visits = child["visits"]
+    assert len(visits) == 8                  # score, then kde, per chip
+    for v in visits:
+        assert v["on"] == [v["chip"]] * 3
+        assert v["committed"] == [True, True]
+    fetched = [a.get("sync_bytes", 0) for n, a, _ in child["spans"]
+               if n == "kernels.prune.visit_lists"]
+    assert fetched == [4 * v["rows"] for v in visits]
 
 
 def test_the_host_steps_sit_under_a_pruned_pass(child):
